@@ -1,5 +1,5 @@
 # emqx_tpu broker image (deploy/docker analog of the reference).
-# CPU JAX by default; swap the jax install for jax[tpu] on TPU hosts.
+# CPU JAX by default; on TPU hosts install the `tpu` extra (`pip install .[tpu]`).
 FROM python:3.12-slim
 
 WORKDIR /opt/emqx_tpu

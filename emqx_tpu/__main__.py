@@ -43,8 +43,22 @@ async def serve(args) -> int:
         config.router.enable_tpu = False
     if args.no_dashboard:
         config.dashboard.enable = False
+    if config.router.enable_tpu:
+        from emqx_tpu.compile_cache import place_compile_cache
+
+        place_compile_cache()
 
     app = BrokerApp(config)
+    fp = app.fingerprint
+    print(
+        "emqx_tpu backend "
+        + (
+            f"{fp['platform']} ({fp['device_kind']}) x{fp['device_count']}"
+            if fp
+            else "none (--no-tpu: CPU trie only)"
+        ),
+        flush=True,
+    )
     await app.start()
     for l in app.listeners.list().values():
         print(

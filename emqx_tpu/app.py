@@ -28,7 +28,6 @@ from emqx_tpu.broker.rewrite import RewriteRule, TopicRewrite
 from emqx_tpu.broker.router import Router
 from emqx_tpu.broker.shared_sub import SharedSub
 from emqx_tpu.config.schema import AppConfig
-from emqx_tpu.ops.matcher import MatcherConfig
 from emqx_tpu.transport.listener import ListenerConfig, Listeners
 from emqx_tpu.utils.node import node_name, set_node_name
 
@@ -177,6 +176,10 @@ class BrokerApp:
         if logfmt._handler is None or c.log != LogConfig():
             logfmt.setup_logging(c.log.level, c.log.formatter, c.log.to_file)
 
+        # imported here, not at module level: connection workers import
+        # this module for build_guard_hooks and must stay off jax
+        from emqx_tpu.ops.matcher import MatcherConfig
+
         self.hooks = Hooks()
         self.router = Router(
             matcher_config=MatcherConfig(
@@ -300,13 +303,18 @@ class BrokerApp:
         self.profiler.trace_dir = c.observe.profile_trace_dir
         self.profiler.max_seconds = float(c.observe.profile_max_seconds)
         self.profiler.max_bytes = int(c.observe.profile_max_bytes)
-        fp = provenance.fingerprint()
-        self.broker.metrics.gauge_set(
-            "provenance.proxy", 1 if fp["proxy"] else 0
-        )
-        self.broker.metrics.gauge_set(
-            "provenance.device.count", fp["device_count"]
-        )
+        # the backend this broker routes on; None under --no-tpu, which
+        # must not open (or need) a device. A backend that fails to
+        # initialise raises here rather than serving from an unnamed one.
+        self.fingerprint = None
+        if c.router.enable_tpu:
+            fp = self.fingerprint = provenance.fingerprint()
+            self.broker.metrics.gauge_set(
+                "provenance.proxy", 1 if fp["proxy"] else 0
+            )
+            self.broker.metrics.gauge_set(
+                "provenance.device.count", fp["device_count"]
+            )
         if c.force_gc.enable:
             from emqx_tpu.transport.congestion import ForcedGC
 
@@ -877,6 +885,7 @@ class BrokerApp:
                     ["warmup/a"] * max(1, c.router.min_tpu_batch),
                 )
             except Exception:
+                self.broker.metrics.inc("device.warmup.failed")
                 logging.getLogger("emqx_tpu").exception(
                     "device route warmup failed; serving with cold kernel"
                 )

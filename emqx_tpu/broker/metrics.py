@@ -324,6 +324,9 @@ declare("degrade.retries", COUNTER,
 declare("degrade.fallback.batches", COUNTER,
         "whole batches served by the CPU trie because the device path "
         "failed or its breaker was open")
+declare("device.warmup.failed", COUNTER,
+        "start-up route-step warm-ups that raised (the broker serves on "
+        "with a cold kernel; a run that must prove the device reads 0)")
 declare("ingest.shed", COUNTER,
         "enqueues refused at the ingest gate (olp overloaded or device "
         "breaker open past the queue bound) — backpressure, not loss")

@@ -19,12 +19,18 @@ the caller process and the router worker pool (emqx_router.erl:188-189).
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence
 
 from emqx_tpu.broker.trie import TopicTrie
 from emqx_tpu.ops import topics as T
-from emqx_tpu.ops.matcher import MatcherConfig
 from emqx_tpu.ops.route_index import RouteIndex
+
+if TYPE_CHECKING:
+    # ops/matcher.py imports jax at module level (its kernels are
+    # jit-decorated), and this module rides the import chain of every
+    # connection-worker process, which must stay off jax: one process
+    # per chip. A Router is only ever BUILT in the process that owns it.
+    from emqx_tpu.ops.matcher import MatcherConfig
 
 
 class Router:
@@ -38,7 +44,11 @@ class Router:
         self._trie = TopicTrie()
         self._index = RouteIndex()
         self._matcher = None  # lazy match-only DeviceRouter
-        self._matcher_config = matcher_config or MatcherConfig()
+        if matcher_config is None:
+            from emqx_tpu.ops.matcher import MatcherConfig
+
+            matcher_config = MatcherConfig()
+        self._matcher_config = matcher_config
         self.min_tpu_batch = min_tpu_batch
         self.enable_tpu = enable_tpu
         # ('dp','tp') jax Mesh, set by the app alongside broker.mesh:

@@ -354,6 +354,16 @@ class MgmtApi:
                 "p99": h.p99 * scale,
             }
 
+        # read live (the mesh.shard.* gauges refresh every 30th tick):
+        # the emptiest shard's fill and the table bytes on each device
+        mesh_live = {}
+        _dev = self.broker._device
+        if self.broker.mesh is not None and hasattr(_dev, "shard_status"):
+            _st = _dev.shard_status()
+            mesh_live = {
+                "shard_fill_min": _st.get("lane_fill_min"),
+                "device_bytes": _st["device_bytes"],
+            }
         routed_dev = m.get("messages.routed.device")
         routed_fb = m.get("messages.routed.device_fallback")
         routed_total = routed_dev + routed_fb
@@ -478,6 +488,7 @@ class MgmtApi:
                 "shard_label": self.broker.shard_label,
                 "shard_count": m.gauge("mesh.shard.count"),
                 "shard_fill_max": m.gauge("mesh.shard.fill"),
+                **mesh_live,
                 "scatter_launches": m.get("mesh.shard.scatter.launches"),
                 "compact_runs": m.get("mesh.shard.compact.runs"),
                 "rebalance_events": m.get("mesh.shard.rebalance"),

@@ -36,9 +36,9 @@ provenance"):
    accessed) harvested per contract kernel per config-matrix row by
    reusing the device-contract audit's harness recipes, rendered as a
    roofline-style estimate (arithmetic intensity vs the detected
-   device's peak). On a CPU proxy the peaks are nominal and the whole
-   block is tagged `proxy: true` — the estimate ranks kernels against
-   each other, it is NOT a number of record.
+   TPU's peak). Off a TPU there are no peaks: rows keep FLOPs, bytes
+   and arithmetic intensity (counts), `attainable_flops`/`bound` stay
+   None and the block is tagged `proxy: true`.
 """
 
 from __future__ import annotations
@@ -73,37 +73,27 @@ DEVICE_PEAKS: Tuple[Tuple[str, Tuple[float, float]], ...] = (
     ("v3", (123e12, 900e9)),
     ("v2", (45e12, 700e9)),
 )
-# nominal single-host CPU peaks: ONLY for ranking kernels relative to
-# each other on a proxy box; tagged proxy wherever rendered
-PROXY_PEAKS: Tuple[float, float] = (1e11, 5e10)
 
 
-def device_peaks() -> Dict[str, Any]:
-    """(peak_flops, peak_bytes_per_s, proxy) for the detected device."""
+def device_peaks() -> Optional[Dict[str, Any]]:
+    """(peak_flops, peak_bytes_per_s) for the detected TPU; None on any
+    other platform (a CPU has no roofline to render here). A TPU kind
+    missing from DEVICE_PEAKS is an error, not a default."""
     fp = provenance.fingerprint()
-    kind = str(fp.get("device_kind", "")).lower()
-    if not fp.get("proxy", True):
-        for sub, peaks in DEVICE_PEAKS:
-            if sub in kind:
-                return {
-                    "peak_flops": peaks[0],
-                    "peak_bytes_per_s": peaks[1],
-                    "proxy": False,
-                    "device_kind": fp.get("device_kind"),
-                }
-        # unknown TPU generation: v4 numbers as a conservative stand-in
-        return {
-            "peak_flops": 275e12,
-            "peak_bytes_per_s": 1228e9,
-            "proxy": False,
-            "device_kind": fp.get("device_kind"),
-        }
-    return {
-        "peak_flops": PROXY_PEAKS[0],
-        "peak_bytes_per_s": PROXY_PEAKS[1],
-        "proxy": True,
-        "device_kind": fp.get("device_kind"),
-    }
+    if fp["proxy"]:
+        return None
+    kind = str(fp["device_kind"]).lower()
+    for sub, peaks in DEVICE_PEAKS:
+        if sub in kind:
+            return {
+                "peak_flops": peaks[0],
+                "peak_bytes_per_s": peaks[1],
+                "device_kind": fp["device_kind"],
+            }
+    raise LookupError(
+        f"no roofline peaks for TPU device_kind {fp['device_kind']!r}: "
+        "add its datasheet numbers to DEVICE_PEAKS"
+    )
 
 
 def record_kernel_launch(
@@ -365,15 +355,12 @@ def harvest_cost(
 
     from emqx_tpu.ops.contract import REGISTRY
     # importing the kernel modules populates the registry (the audit's
-    # own idiom); mesh kernels may be unavailable on exotic backends
+    # own idiom)
     import emqx_tpu.models.router_model  # noqa: F401
     import emqx_tpu.ops.session_table  # noqa: F401
+    import emqx_tpu.parallel.mesh  # noqa: F401
 
     skipped: List[str] = []
-    try:
-        import emqx_tpu.parallel.mesh  # noqa: F401
-    except Exception as e:  # noqa: BLE001 — no shard_map image
-        skipped.append(f"mesh kernels unavailable: {e}")
 
     from tools.analysis.device_contract import (
         _cfg_key,
@@ -408,7 +395,7 @@ def harvest_cost(
         "rows": rows,
         "skipped": skipped,
         "peaks": peaks,
-        "proxy": bool(peaks["proxy"]),
+        "proxy": peaks is None,
     }
 
 
@@ -422,13 +409,11 @@ def _cost_row(name: str, key: str, ca, peaks) -> Dict[str, Any]:
     flops = float(ca.get("flops", 0.0) or 0.0)
     bytes_ = float(ca.get("bytes accessed", 0.0) or 0.0)
     ai = flops / bytes_ if bytes_ > 0 else None
-    peak_f = peaks["peak_flops"]
-    peak_b = peaks["peak_bytes_per_s"]
-    attainable = (
-        min(peak_f, ai * peak_b) if ai is not None else None
-    )
-    bound = None
-    if ai is not None:
+    attainable = bound = None
+    if ai is not None and peaks is not None:
+        peak_f = peaks["peak_flops"]
+        peak_b = peaks["peak_bytes_per_s"]
+        attainable = min(peak_f, ai * peak_b)
         bound = "compute" if ai >= peak_f / peak_b else "memory"
     return {
         "kernel": name,

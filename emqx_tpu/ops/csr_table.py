@@ -106,10 +106,9 @@ def sparse_fanout_slots(csr: Dict, matched, kslot: int, kg: int = 0):
     mirroring the dense path's OR semantics, where a duplicate is
     structurally impossible.
     """
-    import jax
     import jax.numpy as jnp
 
-    from emqx_tpu.ops.matcher import _compact
+    from emqx_tpu.ops.matcher import _compact, _member_mask
 
     if kslot <= 0:
         raise ValueError("sparse fan-out requires kslot > 0")
@@ -147,17 +146,8 @@ def sparse_fanout_slots(csr: Dict, matched, kslot: int, kg: int = 0):
     valid = (pos[None, :] < total[:, None]) & (j < lg)
     src = jnp.clip(og + j, 0, col.shape[0] - 1)
     cand_p = jnp.where(valid, col[src], jnp.int32(-1))  # [B, kg]
-    # hot overlay: pairs whose fid appears in this row's matched set.
-    # lax.scan over the K matched columns keeps peak memory at one
-    # [B, H] mask instead of materializing [B, K, H].
-    H = hfid.shape[0]
-
-    def _memb(acc, mcol):  # mcol: [B] one matched column
-        return acc | (mcol[:, None] == hfid[None, :]), None
-
-    memb, _ = jax.lax.scan(
-        _memb, jnp.zeros((B, H), bool), jnp.swapaxes(matched, 0, 1)
-    )
+    # hot overlay: pairs whose fid appears in this row's matched set
+    memb = _member_mask(matched, hfid)  # [B, H]
     hlive = hfid >= 0  # masks holes AND tombstones (and -1 == -1 ties)
     cand_h = jnp.where(memb & hlive[None, :], hslot[None, :], jnp.int32(-1))
     cand = jnp.concatenate([cand_p, cand_h], axis=1)

@@ -122,6 +122,25 @@ def _compact(cand, width: int):
     return out, over
 
 
+def _member_mask(matched, ids):
+    """bool [B, N]: ids[n] appears in row b's matched set [B, K].
+
+    lax.scan over the K matched columns keeps peak memory at one [B, N]
+    mask instead of materializing [B, K, N]. The initial carry is derived
+    from both operands so that under shard_map it varies over the same
+    mesh axes as the body's output (scan requires equal carry types).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    def _memb(acc, mcol):  # mcol: [B] one matched column
+        return acc | (mcol[:, None] == ids[None, :]), None
+
+    memb0 = (matched[:, :1] == ids[None, :]) & False
+    memb, _ = jax.lax.scan(_memb, memb0, jnp.swapaxes(matched, 0, 1))
+    return memb
+
+
 def _append(matched, mcount, hits, cap: int):
     """Append the >=0 entries of hits [B, H] to matched [B, cap] at mcount."""
     import jax.numpy as jnp

@@ -122,6 +122,8 @@ def semantic_match_step(sem: Dict, q_vecs, matched, topk: int):
     import jax
     import jax.numpy as jnp
 
+    from emqx_tpu.ops.matcher import _member_mask
+
     if topk <= 0:
         raise ValueError("semantic matching requires topk > 0")
     vecs = jnp.concatenate(
@@ -132,7 +134,6 @@ def semantic_match_step(sem: Dict, q_vecs, matched, topk: int):
     ths = jnp.concatenate(
         [sem["sem_thresh"][0], sem["sem_hot_thresh"][0]]
     )
-    B, K = matched.shape
     E = vecs.shape[0]
     q = q_vecs
     if q.dtype != vecs.dtype:
@@ -144,16 +145,8 @@ def semantic_match_step(sem: Dict, q_vecs, matched, topk: int):
     )  # [B, E] f32
     live = slots >= 0
     scoped = fids >= 0
-    # scope membership: entry fid in this row's matched set. lax.scan
-    # over the K matched columns keeps peak memory at one [B, E] mask
-    # instead of materializing [B, K, E] (the CSR hot-overlay idiom).
-
-    def _memb(acc, mcol):  # mcol: [B] one matched column
-        return acc | (mcol[:, None] == fids[None, :]), None
-
-    memb, _ = jax.lax.scan(
-        _memb, jnp.zeros((B, E), bool), jnp.swapaxes(matched, 0, 1)
-    )
+    # scope membership: entry fid in this row's matched set
+    memb = _member_mask(matched, fids)  # [B, E]
     ok = (
         live[None, :]
         & (sims >= ths[None, :])

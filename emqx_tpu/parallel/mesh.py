@@ -24,20 +24,6 @@ from emqx_tpu.models.router_model import (
 )
 from emqx_tpu.ops.contract import device_contract
 
-# -- shard_map compat -------------------------------------------------------
-# jax moved shard_map from jax.experimental to the top level around 0.4.35;
-# this image's 0.4.37 only ships the experimental spelling. Resolve once at
-# import; HAS_SHARD_MAP lets callers (and mesh tests) skip fast on images
-# with neither instead of stalling or dying on AttributeError mid-dispatch.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except Exception:  # pragma: no cover - images without any shard_map
-        _shard_map = None
-
-HAS_SHARD_MAP = _shard_map is not None
-
 # built mesh step programs, registered for the device-watch compile
 # probe (observe/device_watch.py): lru_cache hides its values, so the
 # builders append their jitted fns here (bounded by the caches' maxsize)
@@ -62,17 +48,6 @@ def jit_cache_size() -> int:
         except Exception:
             continue
     return n
-
-
-def shard_map(*args, **kwargs):
-    """`jax.shard_map` under either spelling; RuntimeError when absent."""
-    if _shard_map is None:
-        raise RuntimeError(
-            "this jax installation provides neither jax.shard_map nor "
-            "jax.experimental.shard_map.shard_map; mesh serving is "
-            "unavailable (check emqx_tpu.parallel.mesh.HAS_SHARD_MAP)"
-        )
-    return _shard_map(*args, **kwargs)
 
 
 def make_mesh(
@@ -207,7 +182,7 @@ def _dist_step_fn(
         return _reduce_stats(out)
 
     table_specs = {k: P() for k in table_keys}
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(table_specs, P(None, "tp"), P("dp", None), P("dp")),
@@ -405,7 +380,7 @@ def _dist_shape_step_fn(
         out_specs["sem_count"] = P("dp")
     if rule_progs:
         out_specs["rule_masks"] = P(None, "dp")
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(
@@ -582,7 +557,7 @@ def _dist_fused_step_fn(
         else P(None, "tp")
     )
     sem_specs = {k: P("tp") for k in sem_keys} if with_sem else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(

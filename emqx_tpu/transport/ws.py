@@ -88,6 +88,11 @@ class _WsStream:
             except RuntimeError:
                 pass
 
+    def writelines(self, segs) -> None:
+        # the QoS>0 delivery path hands pre-serialized frame segments
+        # (Connection.send_segments); the buffer joins them anyway
+        self.write(b"".join(segs))
+
     # Upper bound on a single outgoing WS message: a delivery burst must not
     # coalesce into one message bigger than the peer's max_size (the MQTT
     # parser reassembles packets across WS messages either way)

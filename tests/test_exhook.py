@@ -233,7 +233,7 @@ def test_per_hook_metrics_counted():
 def test_wire_compat_service_path_and_layout():
     """The gRPC seam must match the reference exactly so a provider binary
     built against apps/emqx_exhook/priv/protos/exhook.proto attaches
-    unchanged (VERDICT r1 weak#8)."""
+    unchanged."""
     from emqx_tpu.exhook.rpc import METHODS, SERVICE
 
     assert SERVICE == "emqx.exhook.v1.HookProvider"

@@ -1,6 +1,6 @@
 """The mesh-serving seam: a LIVE broker whose DeviceRouter executes the
 SPMD dist step, and a cluster whose forward path rides the device batch
-dispatch (VERDICT r2 weak #7 / SURVEY §2.4 TPU mapping).
+dispatch (SURVEY §2.4 TPU mapping).
 
 Runs on the virtual 8-device CPU mesh from conftest; the same layout the
 driver's dryrun_multichip gate compiles (emqx_broker.erl:278-293 is the

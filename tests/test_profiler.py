@@ -360,10 +360,9 @@ class TestCostHarvest:
             assert r["flops"] >= 0.0
             assert r["bytes_accessed"] >= 0.0
             assert r["config"]
-            if r["arithmetic_intensity"] is not None:
-                assert r["bound"] in ("compute", "memory")
-                assert r["attainable_flops"] > 0.0
-        assert out["proxy"] is True  # CPU run: peaks are placeholders
+            # a CPU has no roofline: counts only, nothing rendered
+            assert r["bound"] is None and r["attainable_flops"] is None
+        assert out["proxy"] is True and out["peaks"] is None
         roof = roofline_summary(out)
         assert set(roof["kernels"]) == names
         assert roofline_summary(None) is None
@@ -534,11 +533,3 @@ class TestBenchTrend:
         assert rc == 1  # 5x the p99 latency IS a regression
         assert not bench_trend.lower_is_better("e2e_serving_msgs_per_s")
         assert bench_trend.lower_is_better("e2e_paced_p99_ms")
-
-    def test_committed_trajectory_passes_check(self):
-        from tools import bench_trend
-
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        rc = bench_trend.main(["--dir", root, "--check",
-                               "--out", os.devnull])
-        assert rc == 0

@@ -247,10 +247,8 @@ def test_compaction_mid_batch_keeps_inflight_snapshot_valid():
 # -- mesh --------------------------------------------------------------------
 
 def _mesh(n=4, tp=2):
-    from emqx_tpu.parallel.mesh import HAS_SHARD_MAP, make_mesh
+    from emqx_tpu.parallel.mesh import make_mesh
 
-    if not HAS_SHARD_MAP:
-        pytest.skip("no shard_map on this image")
     return make_mesh(n, tp=tp)
 
 

@@ -46,11 +46,14 @@ COLLECTIVE_PRIMS = {
     "ppermute", "pshuffle", "psum_scatter", "axis_index",
 }
 # trace-level spellings -> the contract's canonical collective names.
-# `pbroadcast` is deliberately NOT a collective here: shard_map's
-# replication-rule machinery inserts it implicitly (hundreds per trace)
-# and it lowers to a device-local no-op, so it is not a contractual ICI
-# dependency the way a psum is.
-CANON_PRIM = {"psum2": "psum", "all_gather_invariant": "all_gather"}
+# `pvary` is deliberately NOT a collective here: shard_map's varying-axes
+# typing inserts it implicitly (hundreds per trace) and it lowers to a
+# device-local no-op, so it is not a contractual ICI dependency the way
+# a psum is.
+CANON_PRIM = {
+    "psum_invariant": "psum",
+    "all_gather_invariant": "all_gather",
+}
 
 
 @dataclass
@@ -123,10 +126,9 @@ def _iter_jaxprs(jaxpr):
 
 
 def _as_jaxprs(val):
-    import jax.core as jcore
+    from jax.extend import core as jcore
 
-    closed = getattr(jcore, "ClosedJaxpr", None)
-    if closed is not None and isinstance(val, closed):
+    if isinstance(val, jcore.ClosedJaxpr):
         return [val.jaxpr]
     if isinstance(val, jcore.Jaxpr):
         return [val]
@@ -169,13 +171,14 @@ def _trace_summary(closed_jaxpr, out_shapes) -> Dict:
     text = re.sub(r" at 0x[0-9a-fA-F]+", "", str(closed_jaxpr))
     # multi-axis collective params print their axis names in SET order,
     # which follows the per-process string-hash seed — sort them
-    text = re.sub(
-        r"axes=\(([^)]*)\)",
-        lambda m: "axes=(%s)" % ", ".join(
-            sorted(p.strip() for p in m.group(1).split(",") if p.strip())
-        ),
-        text,
-    )
+    # (jax 0.9's shard_map eqn also prints `manual_axes=frozenset({...})`)
+    def _sorted_names(m):
+        return m.group(1) + ", ".join(
+            sorted(p.strip() for p in m.group(2).split(",") if p.strip())
+        ) + m.group(3)
+
+    text = re.sub(r"(axes=\()([^)]*)(\))", _sorted_names, text)
+    text = re.sub(r"(frozenset\(\{)([^}]*)(\}\))", _sorted_names, text)
     return {
         "primitives": dict(sorted(prims.items())),
         "collectives": collectives,
@@ -677,12 +680,9 @@ def run_audit(
         # importing the kernel modules populates the registry
         import emqx_tpu.models.router_model  # noqa: F401
         import emqx_tpu.ops.session_table  # noqa: F401
+        import emqx_tpu.parallel.mesh  # noqa: F401
         from emqx_tpu.ops.contract import REGISTRY
 
-        try:
-            import emqx_tpu.parallel.mesh  # noqa: F401
-        except Exception as e:  # pragma: no cover - no shard_map image
-            report.skipped.append(f"mesh kernels unavailable: {e}")
         registry = REGISTRY
 
     for name, contract in sorted(registry.items()):

@@ -1,18 +1,19 @@
 """Fingerprint-grouped benchmark trend report + regression gate.
 
-Loads the committed BENCH_r*/BENCH_FULL/MULTICHIP_r* trajectory, groups
-every run by its hardware fingerprint (observe/provenance.py), and
+Loads a directory of driver capture files (`BENCH_r*.json` /
+`MULTICHIP_r*.json` wrappers), groups every run by its hardware
+fingerprint (observe/provenance.py), and
 compares each metric ONLY against the most recent earlier run with the
 SAME fingerprint. Cross-fingerprint comparison is rejected outright: a
 throughput delta between a TPU v5p run and a 1-core CPU proxy run is
 not a regression, it is a hardware swap, and the honest answer is "not
 comparable" — not a percentage.
 
-Legacy captures (BENCH_r01..r05 and the pre-provenance BENCH_FULL)
-carry no fingerprint; the loader backfills `fingerprint: null,
-proxy: true` and files them under the `legacy` group, which is never
+Captures that carry no fingerprint are backfilled `fingerprint: null,
+proxy: true` and filed under the `legacy` group, which is never
 comparable to anything (including itself — an unattributed number has
-no provenance to match on).
+no provenance to match on). The tree holds no capture files; the ledger
+(PERF_LEDGER.jsonl) supersedes this tool (ROADMAP D1).
 
 Regression rule: a metric regresses when it moves in its BAD direction
 (lower for throughput/speedup series, higher for latency/footprint
@@ -27,9 +28,6 @@ Usage:
     python -m tools.bench_trend --dir PATH    # trajectory directory
     python -m tools.bench_trend --threshold 0.4
     python -m tools.bench_trend --out trend.md
-
-`tools/ci_gate.sh` runs `--check` after the bench recipes: a sweep that
-silently halved a headline fails the gate even when every test passes.
 """
 
 from __future__ import annotations
@@ -174,7 +172,7 @@ def load_run(path: str) -> Optional[Dict[str, Any]]:
             doc = dict(doc or {})
             doc["fingerprint"] = raw["fingerprint"]
             doc["proxy"] = raw.get("proxy", True)
-    elif "metric" in raw or "detail" in raw:  # BENCH_FULL shape
+    elif "metric" in raw or "detail" in raw:  # a bare bench document
         doc = raw
     if doc is not None:
         run["metrics"] = _harvest_metrics(doc)
@@ -198,9 +196,6 @@ def load_trajectory(root: str) -> List[Dict[str, Any]]:
         glob.glob(os.path.join(root, "BENCH_r*.json"))
         + glob.glob(os.path.join(root, "MULTICHIP_r*.json"))
     )
-    full = os.path.join(root, "BENCH_FULL.json")
-    if os.path.exists(full):
-        paths.append(full)
     runs = [load_run(p) for p in paths]
     runs = [r for r in runs if r is not None]
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # ci_gate.sh — THE single pre-merge command (docs/concurrency.md,
-# docs/static_analysis.md). Five gates, in the order that fails fastest:
+# docs/static_analysis.md). Three gates, in the order that fails fastest:
 #
 #   1. tpu_lint + the consolidated tier-B audit in ONE invocation
 #      (`--audit`): all 16 AST checkers, the device-contract audit
@@ -14,10 +14,9 @@
 #   3. the race suite alone, verbose      (`-m race`) — redundant with (2)
 #      but isolates the concurrency rig's verdict in its own section of
 #      the log, so a race report is never buried in a 500-test dot wall
-#   4. the bench-trend gate               (tools/bench_trend.py --check:
-#      the committed BENCH trajectory, grouped by hardware fingerprint —
-#      fails when a same-fingerprint metric regressed past threshold;
-#      run it again after any bench recipe below refreshes a capture)
+#
+# All three run on the CPU. The chip is proved separately, through the
+# chip tool: `python chip_smoke.py`.
 #
 # Fast mode for the inner loop (pre-push, not pre-merge):
 #
@@ -28,7 +27,8 @@
 #                               # full corpus replay, contracts
 #                               # skipped) + race suite
 #
-# Bench recipes (slow — NOT part of tier-1 or this gate; run when a PR
+# Bench recipes (NOT part of this gate; bench.py runs on a TPU only and
+# exits non-zero elsewhere — run them through the chip tool when a PR
 # touches the paths they measure):
 #
 #   python bench.py --configs chaos_soak    # degradation ladder gate
@@ -42,8 +42,8 @@
 #                                           # 100% load; gates p99@10%
 #                                           # < 5ms, monotone frontier,
 #                                           # bounded control-lane p99
-#                                           # under a storm (~25s CPU —
-#                                           # docs/robustness.md)
+#                                           # under a storm
+#                                           # (docs/robustness.md)
 #   python bench.py churn_storm             # segmented update path at
 #                                           # 10M subs (~3-4 min): gates
 #                                           # >1M inserts/s and <10ms
@@ -72,15 +72,9 @@
 #                                           # device-fused similarity +
 #                                           # rule WHERE masks vs the
 #                                           # post-dispatch host filter
-#                                           # (~40s CPU —
-#                                           # docs/semantic_routing.md)
-#   python bench.py --configs mesh_serving  # scale-out sharded serving:
-#                                           # the four-scenario broker
-#                                           # matrix through the mesh
-#                                           # entry (100M subs on TPU;
-#                                           # 2-shard CPU proxy, ~90s —
-#                                           # docs/scale_out.md)
-#   python bench.py                         # full sweep (BENCH json)
+#                                           # (docs/semantic_routing.md)
+#   python bench.py                         # full sweep (one JSON line;
+#                                           # chiprun_out/bench_full.json)
 #
 # Exit non-zero on the first failing gate.
 set -euo pipefail
@@ -146,8 +140,6 @@ if [ "$FAST" = 1 ]; then
     profile_smoke
     banner "tier-B smoke (bounded replay + full wirecompat corpus)"
     python -m tools.analysis --audit --smoke --checks oplog
-    banner "bench trend gate (fingerprint-grouped)"
-    python -m tools.bench_trend --check > /dev/null
     banner "race suite (racetrack armed)"
     python -m pytest tests/ -q -m race -p no:cacheprovider
     exit 0
@@ -162,8 +154,5 @@ python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors \
 
 banner "race suite (racetrack armed)"
 python -m pytest tests/ -m race -p no:cacheprovider
-
-banner "bench trend gate (fingerprint-grouped)"
-python -m tools.bench_trend --check > /dev/null
 
 banner "ci_gate: all gates green"
