@@ -3314,21 +3314,16 @@ def hotpath_stats(waterfall_view: bool = False) -> None:
         fb = m.get("messages.routed.device_fallback")
         batch_lat = m.histogram("router.device.seconds")
         waterfall = None
-        kernels = None
         if waterfall_view:
             # `--waterfall`: the per-launch stage breakdown (prepare ->
             # queue-wait -> launch -> device-execute -> readback ->
-            # host-dispatch) + per-kernel attribution, the same series
-            # the /metrics/hotpath REST `profile` block serves
-            from emqx_tpu.observe.profiler import (
-                STAGES,
-                kernel_summary,
-            )
+            # host-dispatch), the same series the /metrics/hotpath REST
+            # `profile` block serves
+            from emqx_tpu.observe.profiler import STAGES
 
             waterfall = {
                 s: hist_ms(f"profile.stage.{s}.seconds") for s in STAGES
             }
-            kernels = kernel_summary(m)
         from emqx_tpu.observe.provenance import stamp as _stamp
 
         print(
@@ -3361,7 +3356,6 @@ def hotpath_stats(waterfall_view: bool = False) -> None:
                         "dispatch_fanout": hist_raw("dispatch.fanout"),
                         "span_overhead": span_overhead,
                         "waterfall": waterfall,
-                        "kernels": kernels,
                     },
                 })
             )

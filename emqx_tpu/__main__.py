@@ -27,7 +27,11 @@ def main(argv=None) -> int:
         "--no-dashboard", action="store_true", help="disable the REST API"
     )
     args = ap.parse_args(argv)
-    return asyncio.run(serve(args))
+    # the owner's loop times its own select(): idle, busy and stalls of
+    # the main thread are series (observe/profiler.py LoopBudget)
+    from emqx_tpu.observe.profiler import loop_factory
+
+    return asyncio.run(serve(args), loop_factory=loop_factory)
 
 
 async def serve(args) -> int:

@@ -1194,8 +1194,12 @@ class BrokerApp:
         # the subscriber matrix, so it runs every 30th tick only
         last_shard_launches = 0
         mesh_fill_tick = 0
+        from emqx_tpu.observe import profiler as _prof
+
         while True:
             await asyncio.sleep(1.0)
+            # section `housekeeping`: the whole synchronous tick
+            _prof.begin("housekeeping")
             try:
                 now = time.time()
                 # delayed dues + detached-session deadlines are
@@ -1210,7 +1214,7 @@ class BrokerApp:
                     self.retainer.clear_expired(now)
                     last_retainer_sweep = now
                 if self.sys_mon is not None:
-                    self.sys_mon.check(now, 1.0)
+                    self.sys_mon.check(now, self.profiler.budget)
                 if self.os_mon is not None:
                     self.os_mon.check(now)
                 if self.vm_mon is not None:
@@ -1326,6 +1330,9 @@ class BrokerApp:
             except Exception:
                 # one bad tick must not kill periodic work for the process
                 logging.getLogger("emqx_tpu").exception("housekeeping tick failed")
+            finally:
+                _prof.end()
+                _prof.flush(self.broker.metrics)
 
     def _publish_sys(self, stats: dict) -> None:
         import logging

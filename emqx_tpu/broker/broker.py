@@ -17,7 +17,6 @@ the session's registered deliver callback. Shared-subscription groups
 from __future__ import annotations
 
 import asyncio
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -28,6 +27,7 @@ from emqx_tpu.broker.metrics import Metrics
 from emqx_tpu.broker.router import Router
 from emqx_tpu.broker.shared_sub import SharedSub
 from emqx_tpu.mqtt import packet as pkt
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.observe.spans import TRACE_HEADER
 from emqx_tpu.ops import topics as T
 from emqx_tpu.utils.tracepoints import tp
@@ -362,23 +362,43 @@ class Broker:
         (emqx_connection.erl:125), without which one connection could never
         have more than one message in a batch.
         """
-        rec = self.spans
-        # span head BEFORE the fold: the publish span covers hook time,
-        # and the stamped context header rides into exhook sidecar calls
-        sp = rec.publish_begin(msg) if rec is not None else None
-        rh = self.rule_hook
-        if rh is not None and rh.device_active():
-            ing0 = self.ingest
-            if ing0 is not None and ing0.running:
-                # device-compiled rule WHEREs defer to settle time: the
-                # batch evaluates them inside the serving launch (the
-                # hook-path evaluator skips marked messages)
-                msg.headers["_batch_rules"] = True
-        msg = await self.hooks.arun_fold("message.publish", (), msg)
+        # section `ingest.enqueue`: the message.publish fold (rewrite,
+        # retainer, rules, delayed) and the append to the ingest's lane; a
+        # hook that has to be awaited is awaited outside it
+        _prof.begin("ingest.enqueue")
+        try:
+            rec = self.spans
+            # span head BEFORE the fold: the publish span covers hook
+            # time, and the stamped context header rides into exhook
+            # sidecar calls
+            sp = rec.publish_begin(msg) if rec is not None else None
+            rh = self.rule_hook
+            if rh is not None and rh.device_active():
+                ing0 = self.ingest
+                if ing0 is not None and ing0.running:
+                    # device-compiled rule WHEREs defer to settle time:
+                    # the batch evaluates them inside the serving launch
+                    # (the hook-path evaluator skips marked messages)
+                    msg.headers["_batch_rules"] = True
+            msg, rest = self.hooks.fold_sync("message.publish", (), msg)
+            if rest is None:
+                return self._enqueue_folded(msg, sp)
+        finally:
+            _prof.end()
+        msg = await rest
+        _prof.begin("ingest.enqueue")
+        try:
+            return self._enqueue_folded(msg, sp)
+        finally:
+            _prof.end(0)  # the same message: no second entry
+
+    def _enqueue_folded(self, msg: Optional[Message], sp):
+        """`apublish_enqueue` after the message.publish fold -> delivery
+        count, or the ingest's future."""
         if msg is None or msg.headers.get("allow_publish") is False:
             self.metrics.inc("messages.dropped")
             if sp is not None:
-                rec.finish_span(sp, 0, status="error")
+                self.spans.finish_span(sp, 0, status="error")
             return 0
         ing = self.ingest
         if ing is not None and ing.running:
@@ -387,7 +407,7 @@ class Broker:
             return ing.enqueue(msg)
         n = self._dispatch_routed(msg)
         if sp is not None:
-            rec.finish_span(sp, n)
+            self.spans.finish_span(sp, n)
         return n
 
     def _publish_folded(self, msg: Optional[Message]) -> int:
@@ -551,7 +571,9 @@ class Broker:
         finishes — so callers settling batches in launch (FIFO) order
         preserve MQTT's per-publisher delivery ordering across batches.
         `ready` is a side-effect-free future signalling that the device
-        round-trip finished (pipeline pacing only)."""
+        round-trip finished (pipeline pacing only). The caller's ambient
+        profiler ids (`batch_ids(batch=seq, rows=n)` in the ingest) ride
+        every section of this batch, on this thread and the executor's."""
         loop = asyncio.get_running_loop()
         r = self.router
         deg = self.degrade
@@ -584,9 +606,10 @@ class Broker:
             # (half-open probes re-enter here one batch at a time)
             return _cpu_pending(degraded=True)
         dev = self._device_router()
-        t_prep = time.perf_counter()
+        ids = _prof.ambient_ids()
         try:
-            args = dev.prepare()
+            with _prof.section("prepare") as sec:
+                args = dev.prepare()
         except Exception:  # noqa: BLE001 — no good epoch: degrade
             if deg is None:
                 raise
@@ -594,9 +617,7 @@ class Broker:
             return _cpu_pending(degraded=True)
         # waterfall `prepare` (observe/profiler.py): table snapshot +
         # upload cost this launch paid before any device work
-        self.metrics.observe(
-            "profile.stage.prepare.seconds", time.perf_counter() - t_prep
-        )
+        self.metrics.observe("profile.stage.prepare.seconds", sec.seconds)
         feed = self.retained_feed
         storm = None
         if feed is not None and dev.supports_retained_fusion:
@@ -627,9 +648,16 @@ class Broker:
         hashes = self._client_hashes(msgs)
         embeds = self._embeds(msgs)
         rules = self._rule_batch(msgs)
+
+        def _route(*a):
+            # the executor thread's launch / device_execute / readback
+            # sections carry this batch's ids
+            with _prof.batch_ids(**ids):
+                return dev.route_prepared(*a)
+
         fut = loop.run_in_executor(
             dispatch_pool(),
-            dev.route_prepared,
+            _route,
             args,
             topics,
             hashes,
@@ -670,7 +698,7 @@ class Broker:
                         args2 = dev.prepare()
                         results = await loop.run_in_executor(
                             dispatch_pool(),
-                            dev.route_prepared,
+                            _route,
                             args2,
                             topics,
                             hashes,
@@ -715,13 +743,12 @@ class Broker:
                 )
             # waterfall `host_dispatch`: the settle-time fan-out of this
             # device batch (delivery resolution + writes)
-            t_hd = time.perf_counter()
-            res = self._dispatch_device_results(
-                msgs, results, forward, device_span=dsp
-            )
+            with _prof.section("host_dispatch", **ids) as sec:
+                res = self._dispatch_device_results(
+                    msgs, results, forward, device_span=dsp
+                )
             self.metrics.observe(
-                "profile.stage.host_dispatch.seconds",
-                time.perf_counter() - t_hd,
+                "profile.stage.host_dispatch.seconds", sec.seconds
             )
             return res
 

@@ -28,8 +28,12 @@ from emqx_tpu.broker.message import Message
 from emqx_tpu.broker.session import Session, SessionConfig
 from emqx_tpu.mqtt import packet as pkt
 from emqx_tpu.mqtt.frame import serialize
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.ops import topics as T
 from emqx_tpu.utils.tracepoints import atp, tp
+
+# the subscriber's acknowledgements of QoS1/2 deliveries
+ACKS = frozenset((pkt.PUBACK, pkt.PUBREC, pkt.PUBCOMP))
 
 
 @dataclass
@@ -104,8 +108,22 @@ class Channel:
 
     # -- helpers ----------------------------------------------------------
     def _send(self, p) -> None:
-        self.sink.send_packet(p)
-        self.broker.metrics.inc("packets.sent")
+        _prof.begin("egress.send")
+        try:
+            self.sink.send_packet(p)
+            self.broker.metrics.inc("packets.sent")
+        finally:
+            _prof.end()
+
+    def _send_all(self, packets) -> None:
+        """One write batch: a read chunk's replacement sends."""
+        _prof.begin("egress.send")
+        try:
+            for p in packets:
+                self.sink.send_packet(p)
+            self.broker.metrics.inc("packets.sent", len(packets))
+        finally:
+            _prof.end(len(packets))
 
     def _close(self, reason: str, rc: Optional[int] = None) -> None:
         if rc is not None and self.version == pkt.MQTT_V5 and self.state == "connected":
@@ -152,27 +170,8 @@ class Channel:
             return self._close("protocol_error", pkt.RC_PROTOCOL_ERROR)
         if t == pkt.PUBLISH:
             return await self._in_publish(p)
-        if t == pkt.PUBACK:
-            acked, more = self.session.puback(p.packet_id)
-            if acked is not None:
-                self.hooks.run("message.acked", self._ci_snapshot(), acked)
-                self._delivery_completed(acked)
-            for q in more:
-                self._send(q)
-            return
-        if t == pkt.PUBREC:
-            if self.session.pubrec(p.packet_id):
-                rel = pkt.PubAck(packet_id=p.packet_id)
-                rel.type = pkt.PUBREL
-                self._send(rel)
-            else:
-                rel = pkt.PubAck(
-                    packet_id=p.packet_id,
-                    reason_code=pkt.RC_PACKET_IDENTIFIER_NOT_FOUND,
-                )
-                rel.type = pkt.PUBREL
-                self._send(rel)
-            return
+        if t in ACKS:
+            return self._in_acks((p,))
         if t == pkt.PUBREL:
             ok = self.session.release_rel(p.packet_id)
             comp = pkt.PubAck(
@@ -183,14 +182,6 @@ class Channel:
             )
             comp.type = pkt.PUBCOMP
             self._send(comp)
-            return
-        if t == pkt.PUBCOMP:
-            completed, more = self.session.pubcomp(p.packet_id)
-            if completed is not None:
-                self.hooks.run("message.acked", self._ci_snapshot(), completed)
-                self._delivery_completed(completed)
-            for q in more:
-                self._send(q)
             return
         if t == pkt.SUBSCRIBE:
             return await self._in_subscribe(p)
@@ -205,6 +196,46 @@ class Channel:
             # method is configured; otherwise protocol error
             return await self._in_reauth(p)
         self._close("unexpected_packet")
+
+    def handle_acks(self, acks) -> None:
+        """A read chunk's run of PUBACK / PUBREC / PUBCOMP on a connected
+        channel (`Connection.run` groups them): `handle_in` for each, in
+        one `channel.ack_in` section."""
+        self.broker.metrics.inc("packets.received", len(acks))
+        self._in_acks(acks)
+
+    def _in_acks(self, acks) -> None:
+        # the subscriber's side of QoS1/2 deliveries: the acks, the
+        # session queue's drain, then the replacement sends in one write
+        # batch. One section per run, its entries the ack packets.
+        _prof.begin("channel.ack_in")
+        try:
+            out: List = []
+            for p in acks:
+                t = p.type
+                if t == pkt.PUBREC:
+                    rel = pkt.PubAck(
+                        packet_id=p.packet_id,
+                        reason_code=pkt.RC_SUCCESS
+                        if self.session.pubrec(p.packet_id)
+                        else pkt.RC_PACKET_IDENTIFIER_NOT_FOUND,
+                    )
+                    rel.type = pkt.PUBREL
+                    out.append(rel)
+                    continue
+                done, more = (
+                    self.session.puback(p.packet_id)
+                    if t == pkt.PUBACK
+                    else self.session.pubcomp(p.packet_id)
+                )
+                if done is not None:
+                    self.hooks.run("message.acked", self._ci_snapshot(), done)
+                    self._delivery_completed(done)
+                out.extend(more)
+            if out:
+                self._send_all(out)
+        finally:
+            _prof.end(len(acks))
 
     async def _in_reauth(self, p) -> None:
         method = p.properties.get("Authentication-Method")
@@ -385,6 +416,7 @@ class Channel:
                 return  # kicked while awaiting the router
         session, present = r
         self.session = session
+        session.on_dropped = self._queue_dropped
         if self.version == pkt.MQTT_V5:
             # v5 default expiry is 0 unless the client asks otherwise
             session.config.expiry_interval = p.properties.get(
@@ -442,63 +474,9 @@ class Channel:
 
     # -- PUBLISH ----------------------------------------------------------
     async def _in_publish(self, p: pkt.Publish) -> None:
-        topic = p.topic
-        # MQTT5 topic alias resolution (emqx_channel packet pipeline :567-576)
-        alias = p.properties.get("Topic-Alias") if self.version == pkt.MQTT_V5 else None
-        if alias is not None:
-            if alias == 0 or alias > self.config.caps.max_topic_alias:
-                return self._close("topic_alias_invalid", pkt.RC_TOPIC_ALIAS_INVALID)
-            if topic:
-                self.topic_aliases[alias] = topic
-            else:
-                topic = self.topic_aliases.get(alias)
-                if topic is None:
-                    return self._close(
-                        "unknown_topic_alias", pkt.RC_PROTOCOL_ERROR
-                    )
-        try:
-            T.validate(topic, kind="name")
-        except T.TopicValidationError:
-            return self._close("invalid_topic", pkt.RC_TOPIC_NAME_INVALID)
-        if len(T.words(topic)) > self.config.caps.max_topic_levels:
-            return self._close("too_many_levels", pkt.RC_TOPIC_NAME_INVALID)
-        if p.qos > self.config.caps.max_qos_allowed:
-            return self._close("qos_not_supported", pkt.RC_QOS_NOT_SUPPORTED)
-        if p.retain and not self.config.caps.retain_available:
-            return self._close("retain_disabled", pkt.RC_RETAIN_NOT_SUPPORTED)
-
-        allowed = await self.hooks.arun_fold(
-            "client.authorize", (self._ci_snapshot(), "publish", topic),
-            "allow",
-        )
-        if allowed != "allow":
-            self.broker.metrics.inc("messages.dropped.not_authorized")
-            if allowed == "disconnect":
-                # authz deny_action=disconnect (reference knob): drop the
-                # packet and close the connection
-                return self._close("not_authorized", pkt.RC_NOT_AUTHORIZED)
-            if p.qos == 0:
-                return  # silently drop (emqx default for qos0 deny)
-            ack = pkt.PubAck(
-                packet_id=p.packet_id, reason_code=pkt.RC_NOT_AUTHORIZED
-            )
-            ack.type = pkt.PUBACK if p.qos == 1 else pkt.PUBREC
-            # through the ack queue: earlier pipelined publishes must ack first
-            return self._enqueue_ack(0, lambda n: self._send(ack))
-
-        if self.session is None or self.state != "connected":
-            return  # kicked while awaiting the authorize hook
-        msg = Message(
-            topic=MP.mount(self.mountpoint, topic),
-            payload=p.payload,
-            qos=p.qos,
-            retain=p.retain,
-            from_client=self.client_id,
-            from_username=self.username,
-            properties={
-                k: v for k, v in p.properties.items() if k != "Topic-Alias"
-            },
-        )
+        msg = await self._publish_admit(p)
+        if msg is None:
+            return
         if p.qos == 0:
             r = await self._publish_pipelined(msg)
             if not isinstance(r, int):
@@ -528,6 +506,97 @@ class Channel:
             )
         else:
             self._enqueue_ack(-1, send_rec)  # dup: never no-subscribers rc
+
+    async def _publish_admit(self, p: pkt.Publish) -> Optional[Message]:
+        """The inbound PUBLISH's checks and authorization -> the Message
+        to publish, or None when the packet was answered here (closed,
+        denied, dropped).
+
+        Section `channel.publish_in`: the checks, the authorize fold and
+        the Message, up to the enqueue (whose own section is
+        `ingest.enqueue`; the pipeline cap's wait lies between the two).
+        An authorizer that has to be awaited is awaited outside it."""
+        _prof.begin("channel.publish_in")
+        try:
+            topic = self._publish_topic(p)
+            if topic is None:
+                return None
+            allowed, rest = self.hooks.fold_sync(
+                "client.authorize", (self._ci_snapshot(), "publish", topic),
+                "allow",
+            )
+            if rest is None:
+                return self._publish_message(p, topic, allowed)
+        finally:
+            _prof.end()
+        allowed = await rest
+        _prof.begin("channel.publish_in")
+        try:
+            return self._publish_message(p, topic, allowed)
+        finally:
+            _prof.end(0)  # the same PUBLISH: no second entry
+
+    def _publish_topic(self, p: pkt.Publish) -> Optional[str]:
+        """The PUBLISH's topic once its alias is resolved and the
+        packet's checks passed; None when they closed the channel."""
+        topic = p.topic
+        # MQTT5 topic alias resolution (emqx_channel packet pipeline :567-576)
+        alias = p.properties.get("Topic-Alias") if self.version == pkt.MQTT_V5 else None
+        if alias is not None:
+            if alias == 0 or alias > self.config.caps.max_topic_alias:
+                return self._close("topic_alias_invalid", pkt.RC_TOPIC_ALIAS_INVALID)
+            if topic:
+                self.topic_aliases[alias] = topic
+            else:
+                topic = self.topic_aliases.get(alias)
+                if topic is None:
+                    return self._close(
+                        "unknown_topic_alias", pkt.RC_PROTOCOL_ERROR
+                    )
+        try:
+            T.validate(topic, kind="name")
+        except T.TopicValidationError:
+            return self._close("invalid_topic", pkt.RC_TOPIC_NAME_INVALID)
+        if len(T.words(topic)) > self.config.caps.max_topic_levels:
+            return self._close("too_many_levels", pkt.RC_TOPIC_NAME_INVALID)
+        if p.qos > self.config.caps.max_qos_allowed:
+            return self._close("qos_not_supported", pkt.RC_QOS_NOT_SUPPORTED)
+        if p.retain and not self.config.caps.retain_available:
+            return self._close("retain_disabled", pkt.RC_RETAIN_NOT_SUPPORTED)
+        return topic
+
+    def _publish_message(
+        self, p: pkt.Publish, topic: str, allowed
+    ) -> Optional[Message]:
+        """The authorize fold's verdict -> the Message, or the denial."""
+        if allowed != "allow":
+            self.broker.metrics.inc("messages.dropped.not_authorized")
+            if allowed == "disconnect":
+                # authz deny_action=disconnect (reference knob): drop the
+                # packet and close the connection
+                return self._close("not_authorized", pkt.RC_NOT_AUTHORIZED)
+            if p.qos == 0:
+                return  # silently drop (emqx default for qos0 deny)
+            ack = pkt.PubAck(
+                packet_id=p.packet_id, reason_code=pkt.RC_NOT_AUTHORIZED
+            )
+            ack.type = pkt.PUBACK if p.qos == 1 else pkt.PUBREC
+            # through the ack queue: earlier pipelined publishes must ack first
+            return self._enqueue_ack(0, lambda n: self._send(ack))
+
+        if self.session is None or self.state != "connected":
+            return  # kicked while awaiting the authorize hook
+        return Message(
+            topic=MP.mount(self.mountpoint, topic),
+            payload=p.payload,
+            qos=p.qos,
+            retain=p.retain,
+            from_client=self.client_id,
+            from_username=self.username,
+            properties={
+                k: v for k, v in p.properties.items() if k != "Topic-Alias"
+            },
+        )
 
     # active-N analog (emqx_connection.erl:125 ?ACTIVE_N): how many
     # publishes one channel may have riding the batch window before the
@@ -806,7 +875,9 @@ class Channel:
             # connection-less window (e.g. between takeover begin/end):
             # park in the session queue for replay
             if self.session is not None and msg.qos > 0:
-                self.session.mqueue.in_(msg)
+                dropped = self.session.mqueue.in_(msg)
+                if dropped is not None:
+                    self._queue_dropped(dropped)
             return
         # QoS0 fan-out fast path: serialize ONCE per (version, retain,
         # topic) and write the same bytes to every subscriber socket —
@@ -846,8 +917,12 @@ class Channel:
                     self.version,
                 )
             self.hooks.run("message.delivered", self._ci_snapshot(), msg)
-            sb(buf)
-            self.broker.metrics.inc("packets.sent")
+            _prof.begin("egress.send")
+            try:
+                sb(buf)
+                self.broker.metrics.inc("packets.sent")
+            finally:
+                _prof.end()
             self._delivery_completed(msg)
             return
         out = self.session.deliver(msg, opts)
@@ -880,25 +955,37 @@ class Channel:
             return False
         from emqx_tpu.mqtt import slab_serializer as SS
 
-        fbq = getattr(msg, "_fbq", None)
-        if fbq is None:
-            fbq = {}
-            msg._fbq = fbq
-        key = (self.version, q.qos, q.retain, q.topic)
-        ent = fbq.get(key)
-        if ent is None:
-            tb = q.topic.encode("utf-8")
-            if len(tb) > 0xFFFF:
-                return False  # _send raises the codec's exact error
-            ent = fbq[key] = SS.split_publish(
-                tb, q.payload, q.qos, q.retain, False, self.version,
-                q.properties,
-            )
-        head, tail = ent
-        ws([head, SS.pid_bytes(q.packet_id), tail])
-        self.broker.metrics.inc("packets.sent")
-        self.broker.metrics.inc("dispatch.serialize.frames")
-        return True
+        _prof.begin("egress.send")
+        sent = 0
+        try:
+            fbq = getattr(msg, "_fbq", None)
+            if fbq is None:
+                fbq = {}
+                msg._fbq = fbq
+            key = (self.version, q.qos, q.retain, q.topic)
+            ent = fbq.get(key)
+            if ent is None:
+                tb = q.topic.encode("utf-8")
+                if len(tb) > 0xFFFF:
+                    return False  # _send raises the codec's exact error
+                ent = fbq[key] = SS.split_publish(
+                    tb, q.payload, q.qos, q.retain, False, self.version,
+                    q.properties,
+                )
+            head, tail = ent
+            ws([head, SS.pid_bytes(q.packet_id), tail])
+            self.broker.metrics.inc("packets.sent")
+            self.broker.metrics.inc("dispatch.serialize.frames")
+            sent = 1
+            return True
+        finally:
+            _prof.end(sent)
+
+    def _queue_dropped(self, msg: Message) -> None:
+        """The session's full queue dropped `msg` (MQueue drops the
+        oldest of its lowest priority band)."""
+        self.broker.metrics.inc("session.mqueue.dropped")
+        self.hooks.run("message.dropped", msg, "queue_full")
 
     def _delivery_completed(self, msg: Message) -> None:
         self.hooks.run(

@@ -123,23 +123,50 @@ class Hooks:
             if r is STOP:
                 return
 
-    async def arun_fold(self, name: str, args: tuple, acc: Any) -> Any:
-        """Async `run_fold`: awaits coroutine callbacks along the chain.
+    def fold_sync(self, name: str, args: tuple, acc: Any):
+        """Fold acc through the chain without awaiting -> (acc, rest).
+
+        `rest` is None when every callback answered synchronously (acc
+        is final), else the coroutine that finishes the fold from the
+        first callback whose result was awaitable: a caller that times
+        or locks its synchronous stretch ends it before `await rest`.
         (isawaitable stays per-result: a SYNC callback may still return
         an awaitable it built — only the registration-time coroutine
         check is cached.)"""
-        for _, _, cb, _is_coro in self._table.get(name, ()):
+        chain = self._table.get(name, ())
+        for i, entry in enumerate(chain):
             try:
-                r = cb(*args, acc)
+                r = entry[2](*args, acc)
+            except StopAndReturn as s:
+                return s.value, None
+            if inspect.isawaitable(r):
+                return acc, self._fold_rest(chain, i, r, args, acc)
+            acc, stop = self._fold_step(r, acc)
+            if stop:
+                return acc, None
+        return acc, None
+
+    async def _fold_rest(self, chain, i: int, r, args: tuple, acc: Any):
+        """Finish a fold from callback `i`, whose result `r` is pending."""
+        while True:
+            try:
                 if inspect.isawaitable(r):
                     r = await r
             except StopAndReturn as s:
                 return s.value
-            acc2, stop = self._fold_step(r, acc)
-            if stop:
-                return acc2
-            acc = acc2
-        return acc
+            acc, stop = self._fold_step(r, acc)
+            i += 1
+            if stop or i >= len(chain):
+                return acc
+            try:
+                r = chain[i][2](*args, acc)
+            except StopAndReturn as s:
+                return s.value
+
+    async def arun_fold(self, name: str, args: tuple, acc: Any) -> Any:
+        """Async `run_fold`: awaits coroutine callbacks along the chain."""
+        acc, rest = self.fold_sync(name, args, acc)
+        return acc if rest is None else await rest
 
     def callbacks(self, name: str):
         return list(self._table.get(name, ()))

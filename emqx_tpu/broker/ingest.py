@@ -57,6 +57,7 @@ from emqx_tpu.broker.slo import (
     RUNG_NAMES,
 )
 from emqx_tpu.observe import faults as _faults
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.observe.spans import TRACE_HEADER
 from emqx_tpu.utils.tracepoints import tp
 
@@ -117,10 +118,6 @@ class BatchIngest:
         # anti-starvation bound for the low lane under sustained
         # control/normal pressure (SloController overrides from config)
         self.starvation_s = slo.starvation_s if slo is not None else 0.05
-        # perf_counter stamp of the moment the LAST in-flight dispatch's
-        # device work completed (None = device busy or never launched);
-        # the gap until the next launch is the ingest.device.idle series
-        self._device_done_t: Optional[float] = None
         self.running = False
 
     def start(self) -> None:
@@ -221,6 +218,10 @@ class BatchIngest:
         return await self.enqueue(msg)
 
     def _take_batch(self, now: float, force: bool = False) -> List[Tuple]:
+        with _prof.section("ingest.take", batch=self._seq):
+            return self._assemble(now, force)
+
+    def _assemble(self, now: float, force: bool) -> List[Tuple]:
         """Assemble up to max_batch in lane-priority order. The low lane
         joins unless the SLO ladder defers it (never past its defer age
         bound); a starvation reserve guarantees the low lane slots once
@@ -265,13 +266,11 @@ class BatchIngest:
 
     async def _settle(self, batch) -> None:
         seq, bsp = self._next_seq(batch)
-        await self._finish(
-            seq, batch,
-            self.broker.adispatch_begin(
+        with _prof.batch_ids(batch=seq, rows=len(batch)):
+            pd = self.broker.adispatch_begin(
                 [m for m, _, _, _ in batch], batch_span=bsp
-            ),
-            bsp,
-        )
+            )
+        await self._finish(seq, batch, pd, bsp)
 
     def _next_seq(self, batch):
         """Assign the batch seq + record launch-side telemetry. Returns
@@ -322,6 +321,12 @@ class BatchIngest:
             if rec is not None and bsp is not None:
                 rec.finish(bsp, {"error": str(e)}, status="error")
             return
+        with _prof.section("ingest.finish", batch=seq, rows=len(batch)):
+            self._resolve(seq, batch, results, bsp, rec)
+
+    def _resolve(self, seq: int, batch, results, bsp, rec) -> None:
+        """Settle one dispatched batch: every publisher's future, the
+        settle latencies, the spans."""
         now = time.perf_counter()
         lane_lats: List[List[float]] = [[], [], []]
         for (m, fut, t0, lane), n in zip(batch, results):
@@ -354,12 +359,6 @@ class BatchIngest:
         """Every in-flight dispatch's DEVICE work is done (their host
         fan-out may still be queued behind the FIFO settle)."""
         return all(pd.ready.done() for _, _, pd, _ in self._inflight)
-
-    def _note_device_done(self, _fut=None) -> None:
-        # done-callback on each launch's `ready`: stamp the moment the
-        # pipeline's device side drained (idle-gap accounting)
-        if self._device_idle():
-            self._device_done_t = time.perf_counter()
 
     async def _run(self) -> None:
         while True:
@@ -416,12 +415,6 @@ class BatchIngest:
                     self.metrics.gauge_set(
                         series, len(self._lane_list(lane))
                     )
-                if self._device_done_t is not None:
-                    self.metrics.observe(
-                        "ingest.device.idle.seconds",
-                        time.perf_counter() - self._device_done_t,
-                    )
-                    self._device_done_t = None
                 # LAUNCH now (prepare + executor submit), settle later:
                 # a full next batch's launch overlaps this one's
                 # round-trip. Fan-out happens ONLY at settle
@@ -430,9 +423,10 @@ class BatchIngest:
                 # cross-batch ordering).
                 seq, bsp = self._next_seq(batch)
                 try:
-                    pd = self.broker.adispatch_begin(
-                        [m for m, _, _, _ in batch], batch_span=bsp
-                    )
+                    with _prof.batch_ids(batch=seq, rows=len(batch)):
+                        pd = self.broker.adispatch_begin(
+                            [m for m, _, _, _ in batch], batch_span=bsp
+                        )
                 except Exception as e:  # noqa: BLE001 — flusher survives
                     log.exception("batch launch failed")
                     self.metrics.inc("ingest.launch.errors")
@@ -449,8 +443,6 @@ class BatchIngest:
                         rec.finish(bsp, {"error": str(e)}, status="error")
                 else:
                     self._inflight.append((seq, batch, pd, bsp))
-                    self._device_done_t = None
-                    pd.ready.add_done_callback(self._note_device_done)
                     self.metrics.gauge_set(
                         "ingest.pipeline.depth", len(self._inflight)
                     )

@@ -150,6 +150,16 @@ class Histogram:
             self.sum += float(sum(values))
             self.count += len(values)
 
+    def add(self, total: float, count: int) -> None:
+        """`count` observations summing to `total`, already aggregated by
+        the caller (the section accumulators of observe/profiler.py: a
+        mean and a share are what is read of them). They land in the
+        overflow bucket: such a series' buckets carry no information."""
+        with self._lock:
+            self._counts[-1] += count
+            self.sum += total
+            self.count += count
+
     def percentile(self, q: float) -> float:
         """q in [0, 1]. 0.0 when empty; the last finite bound when the
         quantile lands in the +Inf overflow bucket."""
@@ -248,6 +258,9 @@ class Metrics:
 
     def observe_many(self, name: str, values: Sequence[float]) -> None:
         self._histogram(name).observe_many(values)
+
+    def add(self, name: str, total: float, count: int) -> None:
+        self._histogram(name).add(total, count)
 
     def histogram(self, name: str) -> Optional[Histogram]:
         with self._lock:
@@ -432,10 +445,6 @@ declare("ingest.settle.seconds", HISTOGRAM,
         buckets=LATENCY_BUCKETS, unit="seconds")
 declare("ingest.pipeline.depth", GAUGE,
         "device dispatches in flight after the last launch")
-declare("ingest.device.idle.seconds", HISTOGRAM,
-        "gap between the pipeline's device side draining and the next "
-        "launch (the wall the idle partial-batch launch rule closes)",
-        buckets=LATENCY_BUCKETS, unit="seconds")
 declare("ingest.lane.depth.control", GAUGE,
         "pending control-lane messages (QoS2 flow / $SYS) at launch")
 declare("ingest.lane.depth.normal", GAUGE,
@@ -562,6 +571,9 @@ declare("mesh.shard.reroutes", COUNTER,
 
 # -- device-resident session store (broker/session_store.py,
 # ops/session_table.py; docs/sessions.md) ----------------------------------
+declare("session.mqueue.dropped", COUNTER,
+        "QoS>=1 deliveries a full session queue dropped (MQueue.in_; "
+        "also the message.dropped hook, reason queue_full)")
 declare("session.store.sessions", GAUGE,
         "live session slots registered in the store")
 declare("session.store.inflight", GAUGE,
@@ -627,6 +639,15 @@ declare("device.compile.seconds", HISTOGRAM,
 declare("device.compile.cache_size", GAUGE,
         "summed jit-cache entries across @device_contract kernels and "
         "built mesh step programs (flat in steady state)")
+declare("device.compile.in_launch.count", COUNTER,
+        "backend compiles that ran inside an open `launch` section "
+        "(route-step programs)")
+declare("device.compile.in_readback.count", COUNTER,
+        "backend compiles that ran inside an open `readback` section "
+        "(the per-B dynamic_slice programs)")
+declare("device.hbm.peak.bytes", GAUGE,
+        "allocator peak_bytes_in_use where the backend reports it, else "
+        "the running maximum of device.hbm.bytes")
 declare("device.hbm.bytes", GAUGE,
         "live device memory: allocator bytes_in_use, or summed live "
         "array nbytes on backends without memory stats")
@@ -770,108 +791,50 @@ declare("provenance.proxy", GAUGE,
 declare("provenance.device.count", GAUGE,
         "devices visible to the backend this process measured on")
 
-# -- per-kernel launch attribution (observe/profiler.py) -------------------
-# one seconds+bytes pair per @device_contract registry name: each device
-# launch observes its wall time + readback bytes into EVERY kernel that
-# rode the program (fused launches list all of them), so "what does this
-# kernel cost in production" is answerable per kernel without kernel-side
-# instrumentation. Observation sites compose the names dynamically
-# (f"device.kernel.{name}.seconds"); the declarations below are the
-# MN-checked universe those names must land in.
-declare("device.kernel.route_step.seconds", HISTOGRAM,
-        "launch wall time for programs carrying route_step "
-        "(match-only matcher path)",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.route_step.bytes", HISTOGRAM,
-        "readback bytes attributed to route_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.shape_route_step.seconds", HISTOGRAM,
-        "launch wall time for programs carrying shape_route_step "
-        "(the serving-path flagship)",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.shape_route_step.bytes", HISTOGRAM,
-        "readback bytes attributed to shape_route_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.sparse_shape_route_step.seconds", HISTOGRAM,
-        "launch wall time for the serving program against a CSR "
-        "subscriber table",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.sparse_shape_route_step.bytes", HISTOGRAM,
-        "readback bytes attributed to sparse_shape_route_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.fused_route_retained_step.seconds", HISTOGRAM,
-        "launch wall time for route launches fusing a retained-replay "
-        "storm",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.fused_route_retained_step.bytes", HISTOGRAM,
-        "readback bytes attributed to fused_route_retained_step "
-        "launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.session_ack_step.seconds", HISTOGRAM,
-        "launch wall time for route launches carrying the fused "
-        "session-ack stage",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.session_ack_step.bytes", HISTOGRAM,
-        "readback bytes attributed to session_ack_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.segment_scatter_insert.seconds", HISTOGRAM,
-        "launch wall time of the fused segment delta-scatter "
-        "(update path)",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.segment_scatter_insert.bytes", HISTOGRAM,
-        "readback bytes attributed to segment_scatter_insert launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.compact_fanout_slots.seconds", HISTOGRAM,
-        "launch wall time for programs carrying the dense fan-out "
-        "compaction stage",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.compact_fanout_slots.bytes", HISTOGRAM,
-        "readback bytes attributed to compact_fanout_slots launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.sparse_fanout_slots.seconds", HISTOGRAM,
-        "launch wall time for programs carrying the CSR fan-out "
-        "gather-union stage",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.sparse_fanout_slots.bytes", HISTOGRAM,
-        "readback bytes attributed to sparse_fanout_slots launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.semantic_match_step.seconds", HISTOGRAM,
-        "launch wall time for programs carrying the fused semantic "
-        "similarity + top-k stage",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.semantic_match_step.bytes", HISTOGRAM,
-        "readback bytes attributed to semantic_match_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.dist_step.seconds", HISTOGRAM,
-        "launch wall time for the SPMD match-only mesh program",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.dist_step.bytes", HISTOGRAM,
-        "readback bytes attributed to dist_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.dist_shape_step.seconds", HISTOGRAM,
-        "launch wall time for the SPMD serving mesh program",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.dist_shape_step.bytes", HISTOGRAM,
-        "readback bytes attributed to dist_shape_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.dist_fused_step.seconds", HISTOGRAM,
-        "launch wall time for the SPMD serving program fusing a "
-        "retained storm over the mesh",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.dist_fused_step.bytes", HISTOGRAM,
-        "readback bytes attributed to dist_fused_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.sem_dist_shape_step.seconds", HISTOGRAM,
-        "launch wall time for the SPMD serving program with the "
-        "semantic stage",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.sem_dist_shape_step.bytes", HISTOGRAM,
-        "readback bytes attributed to sem_dist_shape_step launches",
-        buckets=READBACK_BUCKETS)
-declare("device.kernel.sparse_dist_shape_step.seconds", HISTOGRAM,
-        "launch wall time for the SPMD serving program against CSR "
-        "shards",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("device.kernel.sparse_dist_shape_step.bytes", HISTOGRAM,
-        "readback bytes attributed to sparse_dist_shape_step launches",
-        buckets=READBACK_BUCKETS)
+# -- the owner thread's time budget (observe/profiler.py sections) --------
+# The section names are a contract (PERF.md section 3 lists each with the
+# metric that reads it). Sums and counts only (`Histogram.add` at flush:
+# scrape, GET /api/v5/profile, the 1 Hz tick); read as a mean or a share.
+SECTIONS: Tuple[str, ...] = (
+    # the device owner's main thread
+    "ingress.decode",       # parser.feed of a read chunk / a pool worker's PUB slab
+    "channel.publish_in",   # Channel._in_publish up to the enqueue (checks, authz)
+    "ingest.enqueue",       # Broker.apublish_enqueue: message.publish fold + lane append
+    "channel.ack_in",       # a chunk's run of PUBACK / PUBREC / PUBCOMP incl. the drain
+    "egress.send",          # serialise + write: a packet, an ack run's sends, a DLV flush
+    "ingest.take",          # BatchIngest._take_batch
+    "prepare",              # stage: table snapshot + upload
+    "ingest.finish",        # BatchIngest._finish: the per-message fut.set_result loop
+    "host_dispatch",        # stage: settle-time fan-out
+    "housekeeping",         # the 1 Hz tick
+    # the dispatch executor's threads
+    "launch",
+    "device_execute",
+    "readback",
+)
+for _name in SECTIONS:
+    declare(f"profile.section.{_name}.seconds", HISTOGRAM,
+            f"section {_name}: seconds per entry, children included",
+            unit="seconds")
+    declare(f"profile.section.{_name}.self.seconds", HISTOGRAM,
+            f"section {_name}: seconds per entry less its child sections",
+            unit="seconds")
+declare("owner.loop.select.seconds", HISTOGRAM,
+        "the owner loop blocked in select(): idle, waiting for I/O "
+        "(one observation per loop iteration)", unit="seconds")
+declare("owner.loop.run.seconds", HISTOGRAM,
+        "the owner loop between two selects: busy "
+        "(one observation per loop iteration)", unit="seconds")
+declare("owner.loop.other.seconds", HISTOGRAM,
+        "run time of the owner loop that no section names: its run time "
+        "less its thread's sections' self time", unit="seconds")
+declare("owner.loop.stall.seconds", HISTOGRAM,
+        "run phases of the owner loop longer than 0.5 s (one observation "
+        "per stall; each is logged with its section and GC share)",
+        unit="seconds")
+declare("owner.gc.pause.seconds", HISTOGRAM,
+        "python GC passes of the owner process, all generations "
+        "(SysMon's gc hook)", unit="seconds")
+declare("owner.gc.gen2.seconds", HISTOGRAM,
+        "full (generation 2) GC passes of the owner process",
+        unit="seconds")

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from emqx_tpu.broker.inflight import Inflight
 from emqx_tpu.broker.message import Message
@@ -74,6 +74,9 @@ class Session:
             self.store_slot = None
             self.inflight = Inflight(config.max_inflight)
         self.mqueue = MQueue(config.max_mqueue)
+        # called with each message the full queue drops in `deliver` (the
+        # owning channel counts it: session.mqueue.dropped)
+        self.on_dropped: Optional[Callable[[Message], None]] = None
         self.awaiting_rel: Dict[int, float] = {}  # incoming QoS2 packet ids
         self._next_pid = 1
 
@@ -102,7 +105,9 @@ class Session:
         if qos == 0:
             return [self._publish_packet(msg, 0, None)]
         if self.inflight.is_full():
-            self.mqueue.in_(msg)
+            dropped = self.mqueue.in_(msg)
+            if dropped is not None and self.on_dropped is not None:
+                self.on_dropped(dropped)
             return []
         pid = self.alloc_packet_id()
         self.inflight.insert(pid, msg)

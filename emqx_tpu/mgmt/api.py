@@ -91,8 +91,9 @@ ROUTES = [
     ("delete", "/api/v5/faults", "faults_disarm",
      "Disarm fault rules (?site= for one, all otherwise)", "faults"),
     ("get", "/api/v5/profile", "profile_get",
-     "Profiler snapshot: stage waterfall, per-kernel attribution, "
-     "hardware fingerprint, cached roofline (docs/observability.md)",
+     "Profiler snapshot: stage waterfall, the owner thread's section "
+     "table and last stalls, hardware fingerprint, cached roofline "
+     "(docs/observability.md)",
      "profile"),
     ("post", "/api/v5/profile", "profile_arm",
      "Arm a bounded jax.profiler trace capture {duration_s?, "
@@ -334,7 +335,6 @@ class MgmtApi:
         (docs/observability.md). The before/after read for perf PRs."""
         from emqx_tpu.observe import provenance as _provenance
         from emqx_tpu.observe.profiler import (
-            kernel_summary as _kernel_summary,
             roofline_summary as _roofline_summary,
             waterfall as _waterfall,
         )
@@ -551,7 +551,6 @@ class MgmtApi:
             },
             "profile": {
                 "waterfall": _waterfall(m),
-                "kernels": _kernel_summary(m),
                 "capture_armed": _prof.armed if _prof else False,
                 "captures": m.get("profile.captures"),
                 "fingerprint": _provenance.fingerprint_key(),
@@ -978,13 +977,17 @@ class MgmtApi:
     #    observe/provenance.py; docs/observability.md) --------------------
     async def profile_get(self, request):
         from emqx_tpu.observe import provenance
-        from emqx_tpu.observe.profiler import kernel_summary, waterfall
+        from emqx_tpu.observe.profiler import section_table, waterfall
 
         prof = self.app.profiler
         m = self.broker.metrics
         out = prof.snapshot()
         out["waterfall"] = waterfall(m)
-        out["kernels"] = kernel_summary(m)
+        # the owner thread's time budget: sections, loop, last stalls
+        out.update(section_table(m, prof.budget))
+        from emqx_tpu.observe.device_watch import compiles_by_section
+
+        out["compiles_by_section"] = compiles_by_section()
         out["fingerprint"] = provenance.fingerprint()
         cost = prof.cost_cached()
         if cost is not None:
@@ -993,7 +996,9 @@ class MgmtApi:
 
     async def profile_arm(self, request):
         """Arm a bounded trace capture: {duration_s?, max_bytes?} (both
-        clamped against the profiler's configured ceilings), or run the
+        clamped against the profiler's configured ceilings) and
+        {python_tracer?} (frames in the trace; off by default: it about
+        halves the host's rate while armed), or run the
         static cost harvest with {action: 'cost_harvest',
         max_configs_per_kernel?, refresh?} — the harvest compiles every
         contract kernel, so it runs on the executor, not the loop."""
@@ -1028,6 +1033,7 @@ class MgmtApi:
             info = prof.arm(
                 duration_s=body.get("duration_s"),
                 max_bytes=body.get("max_bytes"),
+                python_tracer=bool(body.get("python_tracer", False)),
             )
         except (RuntimeError, ValueError, TypeError) as e:
             return web.json_response(
@@ -1087,6 +1093,10 @@ class MgmtApi:
             extra["mem.usage"] = self.app.os_mon.mem_usage
         if self.app.vm_mon is not None:
             extra["tasks.count"] = self.app.vm_mon.task_count
+        from emqx_tpu.observe.profiler import flush
+
+        # the section accumulators land in the registry at scrape
+        flush(self.broker.metrics)
         body = prometheus_exposition(
             self.broker.metrics.snapshot(),
             extra,

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from emqx_tpu.observe import faults as _faults
-from emqx_tpu.observe.profiler import record_kernel_launch
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.ops.contract import device_contract
 from emqx_tpu.ops.csr_table import CsrSegmentOwner, CsrTable, sparse_fanout_slots
 from emqx_tpu.ops.matcher import batch_match_bytes_impl
@@ -99,32 +99,34 @@ def compact_fanout_slots(bitmaps, kslot: int):
     """
     from emqx_tpu.ops.matcher import _compact
 
-    B, W = bitmaps.shape
-    kw = min(kslot, W)  # a row cannot have more nonzero words than W
-    nz = bitmaps != 0
-    pos = jnp.cumsum(nz.astype(jnp.int32), axis=1) - 1
-    idx = jnp.where(nz & (pos < kw), pos, kw)
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-    widx = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (B, W))
-    pwidx = jnp.full((B, kw), -1, jnp.int32).at[rows, idx].set(
-        widx, mode="drop"
-    )
-    pword = jnp.zeros((B, kw), jnp.uint32).at[rows, idx].set(
-        bitmaps, mode="drop"
-    )
-    # unpacked holes have pword == 0, so every candidate they produce
-    # is already -1 — no extra validity mask needed
-    bit = (
-        pword[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
-    ) & jnp.uint32(1)
-    cand = jnp.where(
-        bit.astype(bool),
-        pwidx[:, :, None] * 32 + jnp.arange(32, dtype=jnp.int32),
-        jnp.int32(-1),
-    ).reshape(B, kw * 32)
-    slots, _ = _compact(cand, kslot)
-    count = jnp.sum(popcount32(bitmaps).astype(jnp.int32), axis=1)
-    return slots, count, count > kslot
+    # a stable name on the device side (the trace's op metadata)
+    with jax.named_scope("fanout_compact"):
+        B, W = bitmaps.shape
+        kw = min(kslot, W)  # a row cannot have more nonzero words than W
+        nz = bitmaps != 0
+        pos = jnp.cumsum(nz.astype(jnp.int32), axis=1) - 1
+        idx = jnp.where(nz & (pos < kw), pos, kw)
+        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+        widx = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (B, W))
+        pwidx = jnp.full((B, kw), -1, jnp.int32).at[rows, idx].set(
+            widx, mode="drop"
+        )
+        pword = jnp.zeros((B, kw), jnp.uint32).at[rows, idx].set(
+            bitmaps, mode="drop"
+        )
+        # unpacked holes have pword == 0, so every candidate they produce
+        # is already -1 — no extra validity mask needed
+        bit = (
+            pword[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
+        ) & jnp.uint32(1)
+        cand = jnp.where(
+            bit.astype(bool),
+            pwidx[:, :, None] * 32 + jnp.arange(32, dtype=jnp.int32),
+            jnp.int32(-1),
+        ).reshape(B, kw * 32)
+        slots, _ = _compact(cand, kslot)
+        count = jnp.sum(popcount32(bitmaps).astype(jnp.int32), axis=1)
+        return slots, count, count > kslot
 
 
 def route_step_impl(
@@ -298,27 +300,31 @@ def shape_route_step_impl(
         # must cover the host placement bound (ShapeIndex._place probes
         # SHAPE_PROBES slots) or cluster-tail entries become invisible
         shape_probes = SHAPE_PROBES
-    h1, h2, nwords, dollar = tok.tokenize_device(
-        bytes_mat, lengths, salt, max_levels
-    )
-    matched = shape_match_device(
-        shape_tables, m_active, h1, h2, nwords, dollar, probes=shape_probes
-    )
-    flags = nwords > max_levels
-    if with_nfa:
-        syms = tok.vocab_lookup_device(nfa_tables, h1, h2, probes)
-        m2, _c2, f2, _causes2 = batch_match_syms(
-            nfa_tables,
-            syms,
-            nwords,
-            dollar,
-            frontier=frontier,
-            max_matches=max_matches,
-            probes=probes,
+    # stable device-side stage names (`jax.named_scope`: op metadata in
+    # a trace, no primitive added): match, csr_gather, fanout_compact,
+    # share_pick
+    with jax.named_scope("match"):
+        h1, h2, nwords, dollar = tok.tokenize_device(
+            bytes_mat, lengths, salt, max_levels
         )
-        matched = jnp.concatenate([matched, m2], axis=1)
-        flags = flags | f2
-    mcount = jnp.sum((matched >= 0).astype(jnp.int32), axis=1)
+        matched = shape_match_device(
+            shape_tables, m_active, h1, h2, nwords, dollar, probes=shape_probes
+        )
+        flags = nwords > max_levels
+        if with_nfa:
+            syms = tok.vocab_lookup_device(nfa_tables, h1, h2, probes)
+            m2, _c2, f2, _causes2 = batch_match_syms(
+                nfa_tables,
+                syms,
+                nwords,
+                dollar,
+                frontier=frontier,
+                max_matches=max_matches,
+                probes=probes,
+            )
+            matched = jnp.concatenate([matched, m2], axis=1)
+            flags = flags | f2
+        mcount = jnp.sum((matched >= 0).astype(jnp.int32), axis=1)
     sparse_out = None
     if isinstance(sub_bitmaps, dict):  # CSR representation
         bitmaps = None
@@ -334,15 +340,16 @@ def shape_route_step_impl(
         bitmaps = None
         fanout_bits = jnp.int32(0)
     if with_groups and group_tables is not None:
-        pick_gid, pick_idx = share_pick_device(
-            group_tables,
-            matched,
-            client_hash,
-            topic_hash,
-            rand,
-            strategy=share_strategy,
-            dp_axis=dp_axis,
-        )
+        with jax.named_scope("share_pick"):
+            pick_gid, pick_idx = share_pick_device(
+                group_tables,
+                matched,
+                client_hash,
+                topic_hash,
+                rand,
+                strategy=share_strategy,
+                dp_axis=dp_axis,
+            )
     else:
         pick_gid = pick_idx = None
     stats = {
@@ -1362,10 +1369,6 @@ class RouteResult(NamedTuple):
     # compiled rule-predicate masks [R, B] bool, in DeviceRuleFilter
     # order (rules/compile.py) — consumed by the settle-time rule fire
     rule_masks: Optional[np.ndarray] = None
-    # @device_contract registry names of every kernel that rode this
-    # launch's program (observe/profiler.py per-kernel attribution:
-    # `device.kernel.<name>.seconds/.bytes`); () on paths nobody times
-    kernels: Tuple[str, ...] = ()
 
 
 class _LazyDenseRows:
@@ -1924,21 +1927,22 @@ class DeviceRouter:
         import time
 
         t0 = time.perf_counter()
-        out = self._route_prepared(
-            args, topics, client_hashes, retained, session, embeds,
-            rules, t_launch0=t0,
-        )
+        # section `launch` (observe/profiler.py) opens here and is closed
+        # by `_readback`, at the readback boundary
+        _prof.begin("launch")
+        try:
+            out = self._route_prepared(
+                args, topics, client_hashes, retained, session, embeds,
+                rules, launch_open=True,
+            )
+        finally:
+            if _prof.current_section() == "launch":
+                _prof.end()  # raised before the readback boundary
         if self.metrics is not None:
             # Histogram.observe is lock-safe: this runs on executor threads
             wall = time.perf_counter() - t0
             self.metrics.observe("router.device.seconds", wall)
             self.metrics.observe("router.batch.size", len(topics))
-            # per-kernel launch attribution (observe/profiler.py): the
-            # whole launch's wall + readback into every contract kernel
-            # that rode the program
-            record_kernel_launch(
-                self.metrics, out.kernels, wall, out.readback_bytes
-            )
             # cumulative link-bandwidth accounting (device_watch.py)
             self.metrics.inc("device.transfer.bytes", out.readback_bytes)
             if out.bitmaps is not None or out.slots is not None:
@@ -1958,7 +1962,7 @@ class DeviceRouter:
 
     def _route_prepared(self, args, topics, client_hashes=None,
                         retained=None, session=None, embeds=None,
-                        rules=None, t_launch0=None):
+                        rules=None, launch_open=False):
         from emqx_tpu.broker.shared_sub import stable_hash
         from emqx_tpu.ops import tokenizer as tok
 
@@ -2047,7 +2051,7 @@ class DeviceRouter:
                 retained=retained, kg=kg,
                 sem_tables=sem_tables, sem_topk=sem_topk, qv=qv,
                 rprogs=rprogs, rfeats=rfeats, rvalid=rvalid,
-                t_launch0=t_launch0,
+                launch_open=launch_open,
             )
         step_kw = dict(
             m_active=m_active,
@@ -2078,8 +2082,7 @@ class DeviceRouter:
             )
             return self._readback(
                 out, B, too_long, with_groups, kslot, session=session,
-                kernels=("shape_route_step", "session_ack_step"),
-                t_launch0=t_launch0,
+                launch_open=launch_open,
             )
         if retained is not None and retained.chunks:
             # one launch, one readback: the storm's chunk-0 match rides
@@ -2110,8 +2113,7 @@ class DeviceRouter:
             return self._readback(
                 out, B, too_long, with_groups, kslot,
                 retained=retained, extra_retained=extra,
-                kernels=("fused_route_retained_step",),
-                t_launch0=t_launch0,
+                launch_open=launch_open,
             )
         step = (
             shape_route_step_donated
@@ -2136,61 +2138,16 @@ class DeviceRouter:
         )
         return self._readback(
             out, B, too_long, with_groups, kslot,
-            kernels=("shape_route_step",), t_launch0=t_launch0,
+            launch_open=launch_open,
         )
 
-    def _readback(  # readback-site
-        self, out, B, too_long, with_groups, kslot, mesh=False,
-        retained=None, extra_retained=None, session=None,
-        kernels=(), t_launch0=None,
+    def _pull(  # readback-site
+        self, out, B, with_groups, kslot, mesh, retained, extra_retained,
+        session,
     ):
-        """Pull one batch's outputs to host -> `RouteResult`.
-
-        This is THE bandwidth boundary the compaction stage exists for:
-        with ``kslot`` on, only the O(matches) compact arrays cross the
-        link, plus one masked second transfer of the dense bitmap rows
-        for the (overflow-flagged) rows the cap could not hold. Dense
-        ``bitmaps`` rows of the full batch transfer only when compaction
-        is off (or for match-only callers, never).
-
-        Everything the batch needs crosses in ONE `jax.device_get` of a
-        trimmed dict (sliced to the live rows): each separate `asarray`
-        pull used to pay its own sync + RTT — eight of them per batch on
-        the group+compact path — where one coalesced transfer pays one.
-        Only the overflow fetch remains a (rare, masked) second
-        transfer, because which rows need it is decided by `slot_count`,
-        which must be on host first.
-
-        ``mesh``: single-device overflow is derived on host from
-        ``slot_count > kslot`` (one fewer array on the link); the mesh
-        kernel's overflow is per-shard (any tp shard over its local cap)
-        and must be read back.
-        """
-        # fault site: a wedged/failed device->host transfer (the other
-        # half of the launch's round trip; same recovery ladder)
-        _faults.hit("device.readback")
-        import time
-
-        # waterfall stages (observe/profiler.py): `launch` = host encode
-        # + kernel enqueue up to here; `device_execute` = program
-        # completion wait; `readback` = the coalesced device_get + host
-        # decode. Per-batch perf_counter reads, nothing per-message.
-        m = self.metrics
-        t_rb0 = time.perf_counter()
-        if m is not None:
-            if t_launch0 is not None:
-                m.observe(
-                    "profile.stage.launch.seconds", t_rb0 - t_launch0
-                )
-            # the program's outputs complete together: waiting on one
-            # output IS the device-execute boundary
-            jax.block_until_ready(out["matched"])
-            t_dev = time.perf_counter()
-            m.observe(
-                "profile.stage.device_execute.seconds", t_dev - t_rb0
-            )
-        else:
-            t_dev = t_rb0
+        """The one coalesced `jax.device_get` of a batch's outputs, each
+        sliced to the live rows (the slices are the per-`B`
+        `dynamic_slice` programs)."""
         pulls = {
             "matched": out["matched"][:B],
             "mcount": out["mcount"][:B],
@@ -2231,12 +2188,65 @@ class DeviceRouter:
             pulls["session_due_count"] = sess["due_count"]
             pulls["session_expired"] = sess["expired"]
             pulls["session_expired_count"] = sess["expired_count"]
-        host = jax.device_get(pulls)
+        return jax.device_get(pulls)
+
+    def _readback(  # readback-site
+        self, out, B, too_long, with_groups, kslot, mesh=False,
+        retained=None, extra_retained=None, session=None,
+        launch_open=False,
+    ):
+        """Pull one batch's outputs to host -> `RouteResult`.
+
+        This is THE bandwidth boundary the compaction stage exists for:
+        with ``kslot`` on, only the O(matches) compact arrays cross the
+        link, plus one masked second transfer of the dense bitmap rows
+        for the (overflow-flagged) rows the cap could not hold. Dense
+        ``bitmaps`` rows of the full batch transfer only when compaction
+        is off (or for match-only callers, never).
+
+        Everything the batch needs crosses in ONE `jax.device_get` of a
+        trimmed dict (sliced to the live rows): each separate `asarray`
+        pull used to pay its own sync + RTT — eight of them per batch on
+        the group+compact path — where one coalesced transfer pays one.
+        Only the overflow fetch remains a (rare, masked) second
+        transfer, because which rows need it is decided by `slot_count`,
+        which must be on host first.
+
+        ``mesh``: single-device overflow is derived on host from
+        ``slot_count > kslot`` (one fewer array on the link); the mesh
+        kernel's overflow is per-shard (any tp shard over its local cap)
+        and must be read back.
+        """
+        # fault site: a wedged/failed device->host transfer (the other
+        # half of the launch's round trip; same recovery ladder)
+        _faults.hit("device.readback")
+        # waterfall stages, each a profiler section of this (executor)
+        # thread: `launch` = host encode + kernel enqueue up to here
+        # (opened by `route_prepared`); `device_execute` = program
+        # completion wait; `readback` = the coalesced device_get + host
+        # decode. Per-batch perf_counter reads, nothing per-message.
+        m = self.metrics
+        if launch_open:
+            launch_s = _prof.end()
+            if m is not None:
+                m.observe("profile.stage.launch.seconds", launch_s)
+        with _prof.section("device_execute") as sec:
+            # the program's outputs complete together: waiting on one
+            # output IS the device-execute boundary
+            jax.block_until_ready(out["matched"])
         if m is not None:
-            m.observe(
-                "profile.stage.readback.seconds",
-                time.perf_counter() - t_dev,
+            m.observe("profile.stage.device_execute.seconds", sec.seconds)
+        _prof.begin("readback")
+        try:
+            host = self._pull(
+                out, B, with_groups, kslot, mesh, retained,
+                extra_retained, session,
             )
+        finally:
+            readback_s = _prof.end()
+        if m is not None:
+            m.observe("profile.stage.readback.seconds", readback_s)
+        sparse_fan = out["bitmaps"] is None and out.get("slots") is not None
         matched = host["matched"]
         sem_count = host.get("sem_count")
         rule_masks = host.get("rule_masks")
@@ -2248,33 +2258,6 @@ class DeviceRouter:
         readback = 0
         for v in host.values():
             readback += v.nbytes
-        # refine the launch's kernel-attribution names from what the
-        # program actually carried: the CSR/semantic/compaction stages
-        # are registered contracts of their own, and the base serving
-        # program traces under a different registry name per table rep
-        kern = list(kernels)
-        if mesh:
-            if "dist_shape_step" in kern:
-                if sparse_fan:
-                    kern[kern.index("dist_shape_step")] = (
-                        "sparse_dist_shape_step"
-                    )
-                elif sem_count is not None:
-                    kern[kern.index("dist_shape_step")] = (
-                        "sem_dist_shape_step"
-                    )
-        else:
-            if sparse_fan:
-                if "shape_route_step" in kern:
-                    kern[kern.index("shape_route_step")] = (
-                        "sparse_shape_route_step"
-                    )
-                kern.append("sparse_fanout_slots")
-            elif kslot and host.get("slots") is not None:
-                kern.append("compact_fanout_slots")
-            if sem_count is not None:
-                kern.append("semantic_match_step")
-        kernels = tuple(kern)
         retained_res = None
         if retained is not None:
             chunks_m = [host["retained"]] + [
@@ -2302,7 +2285,7 @@ class DeviceRouter:
                 matched, mcount, flags, None, picks,
                 readback_bytes=readback, retained=retained_res,
                 session=sess_res, sem_count=sem_count,
-                rule_masks=rule_masks, kernels=kernels,
+                rule_masks=rule_masks,
             )
         if kslot:
             slots = host["slots"]
@@ -2347,7 +2330,7 @@ class DeviceRouter:
                 dense_rows=dense_rows, dense_index=dense_index,
                 readback_bytes=readback, retained=retained_res,
                 session=sess_res, sem_count=sem_count,
-                rule_masks=rule_masks, kernels=kernels,
+                rule_masks=rule_masks,
             )
         # ascontiguousarray: a backend may hand back strided buffers,
         # and the dispatch path reinterprets rows as uint8
@@ -2356,7 +2339,7 @@ class DeviceRouter:
             matched, mcount, flags, bitmaps, picks,
             readback_bytes=readback, retained=retained_res,
             session=sess_res, sem_count=sem_count,
-            rule_masks=rule_masks, kernels=kernels,
+            rule_masks=rule_masks,
         )
 
     # engine capability flag the broker gates storm fusion on: the
@@ -2383,7 +2366,7 @@ class DeviceRouter:
         mat, lens, B, too_long, group_tables=None, ch=None, th=None,
         rand=None, kslot=0, retained=None, kg=0, sem_tables=None,
         sem_topk=0, qv=None, rprogs=(), rfeats=None, rvalid=None,
-        t_launch0=None,
+        launch_open=False,
     ):
         """SPMD serving: the batch rides dist_shape_route_step over the
         device mesh (SURVEY §2.4 TPU mapping; the multi-chip layout the
@@ -2438,7 +2421,7 @@ class DeviceRouter:
         )
         return self._readback(
             out, B, too_long, with_groups, kslot, mesh=True,
-            kernels=("dist_shape_step",), t_launch0=t_launch0,
+            launch_open=launch_open,
         )
 
     @staticmethod
@@ -2601,7 +2584,7 @@ class MeshServingRouter(DeviceRouter):
         mat, lens, B, too_long, group_tables=None, ch=None, th=None,
         rand=None, kslot=0, retained=None, kg=0, sem_tables=None,
         sem_topk=0, qv=None, rprogs=(), rfeats=None, rvalid=None,
-        t_launch0=None,
+        launch_open=False,
     ):
         """SPMD serving with optional fused retained storm: chunk 0 of a
         prepared `StormJob` rides the SAME sharded program + readback
@@ -2614,7 +2597,7 @@ class MeshServingRouter(DeviceRouter):
                 mat, lens, B, too_long, group_tables, ch, th, rand, kslot,
                 kg=kg, sem_tables=sem_tables, sem_topk=sem_topk, qv=qv,
                 rprogs=rprogs, rfeats=rfeats, rvalid=rvalid,
-                t_launch0=t_launch0,
+                launch_open=launch_open,
             )
         from emqx_tpu.parallel.mesh import (
             dist_fused_route_step,
@@ -2676,5 +2659,5 @@ class MeshServingRouter(DeviceRouter):
         return self._readback(
             out, B, too_long, with_groups, kslot, mesh=True,
             retained=retained, extra_retained=extra,
-            kernels=("dist_fused_step",), t_launch0=t_launch0,
+            launch_open=launch_open,
         )

@@ -13,7 +13,11 @@ from the housekeeping tick (`DeviceWatch.poll`):
   where the monitoring API exists — drives the `device.compile.count`
   counter, catching compiles of programs the registry does not know
   about. Steady-state serving should show a FLAT cache size and zero
-  compile-count growth; sustained growth is a retrace storm (a dynamic
+  compile-count growth. The listener runs on the compiling thread, so a
+  compile also counts by the profiler section open there:
+  `device.compile.in_launch.count` (route-step programs) and
+  `device.compile.in_readback.count` (the per-`B` `dynamic_slice`
+  programs of `_readback`). Sustained growth is a retrace storm (a dynamic
   value leaking into a shape/static position — exactly what RT001/RT002
   flag statically) and trips `RetraceStormWatch`
   (emqx_tpu/observe/alarm.py).
@@ -22,6 +26,8 @@ from the housekeeping tick (`DeviceWatch.poll`):
   allocator's `bytes_in_use` when the backend reports memory stats
   (TPU/GPU), else the summed nbytes of live jax arrays (CPU fallback —
   tracks the same table-growth signal, without allocator overheads).
+  `device.hbm.peak.bytes` is the allocator's `peak_bytes_in_use` where
+  it has one, else the running maximum of the live bytes.
 
 - **transfer accounting** (`device.transfer.bytes` counter): cumulative
   device->host readback bytes, incremented at the two readback sites
@@ -33,8 +39,9 @@ from the housekeeping tick (`DeviceWatch.poll`):
 from __future__ import annotations
 
 import threading
-import time
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
+
+from emqx_tpu.observe.profiler import current_section
 
 # -- process-global compile-event accumulator -------------------------------
 # jax.monitoring listeners cannot be unregistered per-instance, so ONE
@@ -44,6 +51,9 @@ from typing import Dict, Optional
 _mon_lock = threading.Lock()
 _mon_compiles = 0  # guarded-by: _mon_lock
 _mon_seconds = 0.0  # guarded-by: _mon_lock
+# compiles by the profiler section open on the compiling thread, or by
+# that thread's name where none is open
+_mon_by_section: Dict[str, int] = {}  # guarded-by: _mon_lock
 _mon_registered = False
 
 # the once-per-backend-compile event in jax's monitoring stream; the
@@ -55,9 +65,11 @@ def _on_event(event: str, duration: float, **_kw) -> None:
     global _mon_compiles, _mon_seconds
     if _COMPILE_EVENT not in event:
         return
+    where = current_section() or "thread:" + threading.current_thread().name
     with _mon_lock:
         _mon_compiles += 1
         _mon_seconds += duration
+        _mon_by_section[where] = _mon_by_section.get(where, 0) + 1
 
 
 def _install_listener() -> bool:
@@ -76,31 +88,47 @@ def _install_listener() -> bool:
 
 def _mon_totals() -> tuple:
     with _mon_lock:
-        return _mon_compiles, _mon_seconds
+        return (
+            _mon_compiles, _mon_seconds,
+            _mon_by_section.get("launch", 0),
+            _mon_by_section.get("readback", 0),
+        )
 
 
-def hbm_bytes() -> int:
-    """Live device memory: allocator stats when the backend exposes them
-    (TPU/GPU `memory_stats()["bytes_in_use"]`), else summed nbytes of
-    live arrays (CPU — same growth signal, no allocator overhead)."""
+def compiles_by_section() -> Dict[str, int]:
+    """Process totals of backend compiles by the section open on the
+    compiling thread (`thread:<name>` where none is): where steady-state
+    compiles come from (`GET /api/v5/profile`)."""
+    with _mon_lock:
+        return dict(_mon_by_section)
+
+
+def hbm_bytes() -> Tuple[int, Optional[int]]:
+    """(live, peak) device memory: allocator stats when the backend
+    exposes them (TPU/GPU `memory_stats()["bytes_in_use"]` and
+    `["peak_bytes_in_use"]`), else summed nbytes of live arrays (CPU —
+    same growth signal, no allocator overhead) and no peak."""
     import jax
 
-    total = 0
-    saw_stats = False
+    total, peak = 0, 0
+    saw_stats = saw_peak = False
     try:
         for d in jax.local_devices():
             stats = d.memory_stats()
             if stats and "bytes_in_use" in stats:
                 total += int(stats["bytes_in_use"])
                 saw_stats = True
+                if "peak_bytes_in_use" in stats:
+                    peak += int(stats["peak_bytes_in_use"])
+                    saw_peak = True
     except Exception:
         saw_stats = False
     if saw_stats:
-        return total
+        return total, (peak if saw_peak else None)
     try:
-        return int(sum(a.nbytes for a in jax.live_arrays()))
+        return int(sum(a.nbytes for a in jax.live_arrays())), None
     except Exception:
-        return 0
+        return 0, None
 
 
 class DeviceWatch:
@@ -119,6 +147,7 @@ class DeviceWatch:
         self._monitoring = _install_listener()
         self._last_cache: Optional[int] = None
         self._mon_cursor = _mon_totals()
+        self._hbm_max = 0
 
     def _contracts(self) -> Dict:
         if self._registry is not None:
@@ -160,10 +189,16 @@ class DeviceWatch:
         )
         self._last_cache = cs
         m.gauge_set("device.compile.cache_size", cs)
-        mon_c, mon_s = _mon_totals()
-        d_compiles = mon_c - self._mon_cursor[0]
-        d_seconds = mon_s - self._mon_cursor[1]
-        self._mon_cursor = (mon_c, mon_s)
+        mon = _mon_totals()
+        d_compiles = mon[0] - self._mon_cursor[0]
+        d_seconds = mon[1] - self._mon_cursor[1]
+        for i, series in (
+            (2, "device.compile.in_launch.count"),
+            (3, "device.compile.in_readback.count"),
+        ):
+            if mon[i] != self._mon_cursor[i]:
+                m.inc(series, mon[i] - self._mon_cursor[i])
+        self._mon_cursor = mon
         if not self._monitoring:
             # no monitoring API on this jax: the registry cache growth is
             # the compile signal (misses non-registered programs)
@@ -177,14 +212,20 @@ class DeviceWatch:
                     "device.compile.seconds",
                     [d_seconds / d_compiles] * d_compiles,
                 )
-        hbm = hbm_bytes()
+        hbm, peak = hbm_bytes()
         m.gauge_set("device.hbm.bytes", hbm)
+        self._hbm_max = max(self._hbm_max, hbm)
+        m.gauge_set(
+            "device.hbm.peak.bytes",
+            peak if peak is not None else self._hbm_max,
+        )
         return {
             "compile_cache_size": cs,
             "compiles": d_compiles,
             "compile_seconds": d_seconds,
             "kernel_compiles": kernel_compiles,
             "hbm_bytes": hbm,
+            "hbm_peak_bytes": m.gauge("device.hbm.peak.bytes"),
         }
 
     def summary(self) -> Dict[str, float]:

@@ -7,8 +7,11 @@ The reference watches its runtime with three processes (SURVEY.md §5.2/§5.3):
 - emqx_vm_mon: process-count watermarks (emqx_vm_mon.erl)
 
 The asyncio/CPython equivalents of the runtime anomalies:
-- event-loop lag (a blocked loop is the moral twin of long_schedule)
-- GC pause spikes (gc callbacks time each collection ~ long_gc)
+- a long run phase of the event loop (a blocked loop is the moral twin
+  of long_schedule; measured by the loop's own LoopBudget,
+  observe/profiler.py)
+- GC pause spikes (gc callbacks time each collection ~ long_gc; the same
+  hook feeds the owner.gc.* series)
 - task count (asyncio tasks are the process analog) and fd count.
 
 All are polled by `check(now)` from the app's housekeeping tick; no threads.
@@ -22,6 +25,7 @@ import os
 import time
 from typing import Optional
 
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.observe.alarm import AlarmManager
 
 
@@ -46,8 +50,6 @@ class SysMon:
         self.long_schedule_ms = long_schedule_ms
         self.long_gc_ms = long_gc_ms
         self.clear_after = clear_after
-        self._expected: Optional[float] = None
-        self._interval: Optional[float] = None
         self._gc_start: Optional[float] = None
         self.max_gc_ms = 0.0
         self._pending_gc_ms: Optional[float] = None
@@ -66,8 +68,10 @@ class SysMon:
         if phase == "start":
             self._gc_start = time.perf_counter()
         elif self._gc_start is not None:
-            ms = (time.perf_counter() - self._gc_start) * 1000.0
+            seconds = time.perf_counter() - self._gc_start
             self._gc_start = None
+            _prof.note_gc(seconds, info.get("generation", 0))
+            ms = seconds * 1000.0
             if ms > self.max_gc_ms:
                 self.max_gc_ms = ms
             if ms > self.long_gc_ms and (
@@ -81,8 +85,11 @@ class SysMon:
             self.alarms.deactivate(name)
         self.alarms.activate(name, details, message)
 
-    def check(self, now: float, tick_interval: float) -> None:
-        """Called each housekeeping tick; lag = how late the tick fired."""
+    def check(self, now: float, budget=None) -> None:
+        """Called each housekeeping tick. `budget` is the loop's
+        LoopBudget: its longest run phase since the last tick is how long
+        the loop kept everything else waiting (None where the loop does
+        not time its select: no long_schedule alarm)."""
         if self._pending_gc_ms is not None:
             ms = self._pending_gc_ms
             self._pending_gc_ms = None
@@ -92,14 +99,14 @@ class SysMon:
                 {"duration_ms": round(ms, 2)},
                 f"gc pause {ms:.1f}ms > {self.long_gc_ms}ms",
             )
-        if self._expected is not None and self._interval == tick_interval:
-            lag_ms = (now - self._expected) * 1000.0
+        if budget is not None:
+            lag_ms = budget.take_longest_run() * 1000.0
             if lag_ms > self.long_schedule_ms:
                 self._last_long_schedule = now
                 self._raise_transient(
                     "long_schedule",
                     {"lag_ms": round(lag_ms, 2)},
-                    f"event loop lagged {lag_ms:.0f}ms behind its timer",
+                    f"event loop ran {lag_ms:.0f}ms without polling I/O",
                 )
         # auto-clear after a quiet period
         if (
@@ -112,8 +119,6 @@ class SysMon:
             and now - self._last_long_schedule > self.clear_after
         ):
             self.alarms.deactivate("long_schedule")
-        self._expected = now + tick_interval
-        self._interval = tick_interval
 
 
 def _meminfo() -> dict:

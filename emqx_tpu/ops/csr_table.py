@@ -106,6 +106,7 @@ def sparse_fanout_slots(csr: Dict, matched, kslot: int, kg: int = 0):
     mirroring the dense path's OR semantics, where a duplicate is
     structurally impossible.
     """
+    import jax
     import jax.numpy as jnp
 
     from emqx_tpu.ops.matcher import _compact, _member_mask
@@ -114,58 +115,60 @@ def sparse_fanout_slots(csr: Dict, matched, kslot: int, kg: int = 0):
         raise ValueError("sparse fan-out requires kslot > 0")
     if kg <= 0:
         kg = 2 * kslot
-    off = csr["csr_off"][0]
-    ln = csr["csr_len"][0]
-    col = csr["csr_slots"][0]
-    hfid = csr["hot_fid"][0]
-    hslot = csr["hot_slot"][0]
-    B, K = matched.shape
-    has = matched >= 0
-    safe = jnp.maximum(matched, 0)
-    fl = jnp.where(has, ln[safe], 0)  # [B, K] allocated region lens
-    fo = off[safe]  # [B, K]
-    starts = jnp.cumsum(fl, axis=1) - fl  # exclusive cumsum
-    total = starts[:, -1] + fl[:, -1]  # [B]
-    pos = jnp.arange(kg, dtype=jnp.int32)
-    # seg[b, p] = rank of the segment containing window position p:
-    # (# of starts <= p) - 1. Zero-length segments tie their successor's
-    # start; the last of a tie run is the one that can contain p, and
-    # the count-of-starts form picks exactly it (searchsorted 'right').
-    seg = (
-        jnp.sum(
-            (starts[:, :, None] <= pos[None, None, :]).astype(jnp.int32),
+    with jax.named_scope("csr_gather"):
+        off = csr["csr_off"][0]
+        ln = csr["csr_len"][0]
+        col = csr["csr_slots"][0]
+        hfid = csr["hot_fid"][0]
+        hslot = csr["hot_slot"][0]
+        B, K = matched.shape
+        has = matched >= 0
+        safe = jnp.maximum(matched, 0)
+        fl = jnp.where(has, ln[safe], 0)  # [B, K] allocated region lens
+        fo = off[safe]  # [B, K]
+        starts = jnp.cumsum(fl, axis=1) - fl  # exclusive cumsum
+        total = starts[:, -1] + fl[:, -1]  # [B]
+        pos = jnp.arange(kg, dtype=jnp.int32)
+        # seg[b, p] = rank of the segment containing window position p:
+        # (# of starts <= p) - 1. Zero-length segments tie their successor's
+        # start; the last of a tie run is the one that can contain p, and
+        # the count-of-starts form picks exactly it (searchsorted 'right').
+        seg = (
+            jnp.sum(
+                (starts[:, :, None] <= pos[None, None, :]).astype(jnp.int32),
+                axis=1,
+            )
+            - 1
+        )
+        seg = jnp.clip(seg, 0, K - 1)
+        sg = jnp.take_along_axis(starts, seg, axis=1)  # [B, kg]
+        lg = jnp.take_along_axis(fl, seg, axis=1)
+        og = jnp.take_along_axis(fo, seg, axis=1)
+        j = pos[None, :] - sg
+        valid = (pos[None, :] < total[:, None]) & (j < lg)
+        src = jnp.clip(og + j, 0, col.shape[0] - 1)
+        cand_p = jnp.where(valid, col[src], jnp.int32(-1))  # [B, kg]
+        # hot overlay: pairs whose fid appears in this row's matched set
+        memb = _member_mask(matched, hfid)  # [B, H]
+        hlive = hfid >= 0  # masks holes AND tombstones (and -1 == -1 ties)
+        cand_h = jnp.where(memb & hlive[None, :], hslot[None, :], jnp.int32(-1))
+        cand = jnp.concatenate([cand_p, cand_h], axis=1)
+        live = jnp.sum((cand >= 0).astype(jnp.int32), axis=1)  # exact unless
+        # the window overflowed (then the host rebuilds the row anyway)
+    with jax.named_scope("fanout_compact"):
+        slots, _ = _compact(cand, kslot)
+        slots = jnp.sort(slots, axis=1)  # -1 pads sort to the front
+        dup = jnp.concatenate(
+            [
+                jnp.zeros((B, 1), bool),
+                (slots[:, 1:] == slots[:, :-1]) & (slots[:, 1:] >= 0),
+            ],
             axis=1,
         )
-        - 1
-    )
-    seg = jnp.clip(seg, 0, K - 1)
-    sg = jnp.take_along_axis(starts, seg, axis=1)  # [B, kg]
-    lg = jnp.take_along_axis(fl, seg, axis=1)
-    og = jnp.take_along_axis(fo, seg, axis=1)
-    j = pos[None, :] - sg
-    valid = (pos[None, :] < total[:, None]) & (j < lg)
-    src = jnp.clip(og + j, 0, col.shape[0] - 1)
-    cand_p = jnp.where(valid, col[src], jnp.int32(-1))  # [B, kg]
-    # hot overlay: pairs whose fid appears in this row's matched set
-    memb = _member_mask(matched, hfid)  # [B, H]
-    hlive = hfid >= 0  # masks holes AND tombstones (and -1 == -1 ties)
-    cand_h = jnp.where(memb & hlive[None, :], hslot[None, :], jnp.int32(-1))
-    cand = jnp.concatenate([cand_p, cand_h], axis=1)
-    live = jnp.sum((cand >= 0).astype(jnp.int32), axis=1)  # exact unless
-    # the window overflowed (then the host rebuilds the row anyway)
-    slots, _ = _compact(cand, kslot)
-    slots = jnp.sort(slots, axis=1)  # -1 pads sort to the front
-    dup = jnp.concatenate(
-        [
-            jnp.zeros((B, 1), bool),
-            (slots[:, 1:] == slots[:, :-1]) & (slots[:, 1:] >= 0),
-        ],
-        axis=1,
-    )
-    slots = jnp.where(dup, jnp.int32(-1), slots)
-    gather_ovf = total > kg
-    count = jnp.where(gather_ovf, jnp.maximum(total, kslot + 1), live)
-    overflow = count > kslot
+        slots = jnp.where(dup, jnp.int32(-1), slots)
+        gather_ovf = total > kg
+        count = jnp.where(gather_ovf, jnp.maximum(total, kslot + 1), live)
+        overflow = count > kslot
     return slots, count, overflow, live
 
 

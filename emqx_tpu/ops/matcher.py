@@ -424,11 +424,6 @@ class TpuMatcher:
         m.observe("matcher.device.seconds", wall_s)
         m.observe("matcher.batch.size", B)
         m.inc("matcher.rows", B)
-        # per-kernel attribution: the match program is the runtime
-        # analog of the audited `route_step` contract's match half
-        from emqx_tpu.observe.profiler import record_kernel_launch
-
-        record_kernel_launch(m, ("route_step",), wall_s)
         fell = int(np.count_nonzero(flags))
         if not fell:
             return

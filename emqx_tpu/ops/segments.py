@@ -316,20 +316,7 @@ class DeviceSegmentManager:
                 idxs[name] = jnp.asarray(ix)
                 vals[name] = jnp.asarray(vv)
             # every touched array updates in ONE device launch
-            t0 = time.perf_counter()
             out = _segment_scatter(flats, idxs, vals)
-            if self.metrics is not None:
-                # launch attribution (observe/profiler.py): the update
-                # path's one fused kernel, keyed by its contract name
-                from emqx_tpu.observe.profiler import (
-                    record_kernel_launch,
-                )
-
-                record_kernel_launch(
-                    self.metrics,
-                    ("segment_scatter_insert",),
-                    time.perf_counter() - t0,
-                )
             self.delta_launches += 1
             for name in flats:
                 new = out[name].reshape(shapes[name])

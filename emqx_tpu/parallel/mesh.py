@@ -223,17 +223,7 @@ def dist_route_step(
         max_matches,
         probes,
     )
-    import time
-
-    from emqx_tpu.broker.metrics import default_metrics
-    from emqx_tpu.observe.profiler import record_kernel_launch
-
-    t0 = time.perf_counter()
-    out = fn(tables, sub_bitmaps, bytes_mat, lengths)
-    record_kernel_launch(
-        default_metrics, ("dist_step",), time.perf_counter() - t0
-    )
-    return out
+    return fn(tables, sub_bitmaps, bytes_mat, lengths)
 
 
 @device_contract(

@@ -13,9 +13,10 @@ import asyncio
 import time
 from typing import Optional
 
-from emqx_tpu.broker.channel import Channel, ChannelConfig
+from emqx_tpu.broker.channel import ACKS, Channel, ChannelConfig
 from emqx_tpu.mqtt import packet as pkt
 from emqx_tpu.mqtt.frame import FrameError, Parser, serialize
+from emqx_tpu.observe import profiler as _prof
 
 
 class Connection:
@@ -109,7 +110,27 @@ class Connection:
                     # (emqx_connection rate-limit pause, :103-120)
                     await self._limited("bytes_in", len(data))
                 try:
-                    for p in self.parser.feed(data):
+                    # one section per read chunk, its packets the entries
+                    _prof.begin("ingress.decode")
+                    pkts = ()
+                    try:
+                        pkts = self.parser.feed(data)
+                    finally:
+                        _prof.end(len(pkts))
+                    i, n = 0, len(pkts)
+                    while i < n:
+                        p = pkts[i]
+                        i += 1
+                        if p.type in ACKS and self.channel.state == "connected":
+                            # the chunk's run of acks: one call, one section
+                            j = i
+                            while j < n and pkts[j].type in ACKS:
+                                j += 1
+                            if self.forced_gc is not None:
+                                self.forced_gc.inc(j - i + 1, 0)
+                            self.channel.handle_acks(pkts[i - 1:j])
+                            i = j
+                            continue
                         if (
                             self.limiters is not None
                             and p.type == pkt.PUBLISH

@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Tuple
 
 from emqx_tpu.broker.message import Message
 from emqx_tpu.mqtt import packet as pkt
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.ops import topics as T
 from emqx_tpu.transport import fabric as F
 
@@ -625,22 +626,30 @@ class WorkerFabric:
         # `writer` is the CONNECTION's stream, not a wid lookup: a stale
         # ack task must die with its (closed) connection, never resolve a
         # respawned worker's identically-numbered batch
-        seq, records = F.unpack_pub_batch(body)
-        results = []
+        # section `ingress.decode`, owner side: the frame's records ->
+        # Messages, one entry per record
+        _prof.begin("ingress.decode")
+        msgs = ()
+        try:
+            seq, records = F.unpack_pub_batch(body)
+            msgs = [
+                Message(
+                    topic=topic,
+                    payload=payload,
+                    qos=qos,
+                    retain=retain,
+                    dup=dup,
+                    from_client=client,
+                    properties=props or {},
+                )
+                for topic, payload, qos, retain, dup, client, props in records
+            ]
+        finally:
+            _prof.end(len(msgs))
         # enqueue INLINE (per-publisher ordering is an MQTT contract);
         # only the confirm-wait runs as a task so the next frame parses
         # while this batch's ingest window flushes
-        for topic, payload, qos, retain, dup, client, props in records:
-            msg = Message(
-                topic=topic,
-                payload=payload,
-                qos=qos,
-                retain=retain,
-                dup=dup,
-                from_client=client,
-                properties=props or {},
-            )
-            results.append(await self.broker.apublish_enqueue(msg))
+        results = [await self.broker.apublish_enqueue(m) for m in msgs]
         if not any(r[2] > 0 for r in records):
             return  # pure-QoS0 batch: the worker holds no PUBACKs on it
         t = asyncio.get_running_loop().create_task(
@@ -657,31 +666,39 @@ class WorkerFabric:
         subscriber needs them (zero-copy ingest, docs/protocol_plane.md)."""
         from emqx_tpu.broker.message import SlabMessage
 
-        slab = F.unpack_pub_slab(body)
-        met = self.broker.metrics
-        met.inc("fabric.slab.pub.frames")
-        if slab.n:
-            met.inc("fabric.slab.pub.records", slab.n)
-            met.inc("ingest.zerocopy.records", slab.n)
-            met.inc(
-                "ingest.zerocopy.deferred.bytes",
-                int(slab.t_len.sum() + slab.p_len.sum()),
-            )
-        flags = slab.flags
-        qos_l = (flags & 3).tolist()
-        retain_l = (flags & 4).astype(bool).tolist()
-        dup_l = (flags & 8).astype(bool).tolist()
-        props_l = (flags & 0x10).astype(bool).tolist()
-        results = []
+        # section `ingress.decode`, owner side: the slab's header scan
+        # and its SlabMessages, one entry per record
+        _prof.begin("ingress.decode")
+        msgs = ()
+        try:
+            slab = F.unpack_pub_slab(body)
+            met = self.broker.metrics
+            met.inc("fabric.slab.pub.frames")
+            if slab.n:
+                met.inc("fabric.slab.pub.records", slab.n)
+                met.inc("ingest.zerocopy.records", slab.n)
+                met.inc(
+                    "ingest.zerocopy.deferred.bytes",
+                    int(slab.t_len.sum() + slab.p_len.sum()),
+                )
+            flags = slab.flags
+            qos_l = (flags & 3).tolist()
+            retain_l = (flags & 4).astype(bool).tolist()
+            dup_l = (flags & 8).astype(bool).tolist()
+            props_l = (flags & 0x10).astype(bool).tolist()
+            msgs = [
+                SlabMessage(
+                    slab, i, qos=qos_l[i], retain=retain_l[i],
+                    dup=dup_l[i], from_client=slab.client(i),
+                    properties=slab.props(i) if props_l[i] else None,
+                )
+                for i in range(slab.n)
+            ]
+        finally:
+            _prof.end(len(msgs))
         # enqueue INLINE (per-publisher ordering), confirm-wait as a task
         # — same contract as the per-record path
-        for i in range(slab.n):
-            msg = SlabMessage(
-                slab, i, qos=qos_l[i], retain=retain_l[i], dup=dup_l[i],
-                from_client=slab.client(i),
-                properties=slab.props(i) if props_l[i] else None,
-            )
-            results.append(await self.broker.apublish_enqueue(msg))
+        results = [await self.broker.apublish_enqueue(m) for m in msgs]
         if not any(qos_l):
             return  # pure-QoS0 batch: the worker holds no PUBACKs on it
         t = asyncio.get_running_loop().create_task(
@@ -792,6 +809,18 @@ class WorkerFabric:
     PARK_CAP = 1000  # per subscriber handle (SessionConfig.max_mqueue)
 
     def _flush(self) -> None:
+        # section `egress.send` for pool connections: the DLV frames of
+        # one loop turn, packed and written; its entries are the deliveries
+        _prof.begin("egress.send")
+        n = 0
+        try:
+            n = self._flush_boxes()
+        finally:
+            _prof.end(max(1, n))
+
+    def _flush_boxes(self) -> int:
+        """-> deliveries (record x target handle) handed to the pipes."""
+        n = 0
         self._flush_scheduled = False
         self._outbox_last.clear()
         self._raw_last.clear()
@@ -818,6 +847,10 @@ class WorkerFabric:
                     if raw_records:
                         self._park(wid, raw_records)
                     continue
+                for _, handles in records:
+                    n += len(handles)
+                for _, handles in raw_records:
+                    n += len(handles)
                 if records:
                     if F.SLAB_WIRE:
                         nf = 0
@@ -843,6 +876,7 @@ class WorkerFabric:
                 # one worker's dead pipe (or a malformed record) must not
                 # lose the OTHER workers' deliveries in this tick
                 self.broker.metrics.inc("fabric.flush.errors")
+        return n
 
     def _park(self, wid: int, records) -> None:
         import collections

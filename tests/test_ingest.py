@@ -417,8 +417,6 @@ async def test_idle_device_launches_partial_batch():
     i_launch1 = events.index(("launch", 1, 3, True))
     i_fanout0 = events.index(("fanout", 0))
     assert i_done0 < i_launch1 < i_fanout0
-    h = ing.metrics.histogram("ingest.device.idle.seconds")
-    assert h is not None and h.count >= 1
 
 
 @async_test

@@ -61,14 +61,27 @@ def test_alarm_history_cap_and_sweep():
 
 # -- monitors --------------------------------------------------------------
 
-def test_sysmon_event_loop_lag():
+def test_sysmon_event_loop_lag(monkeypatch):
+    """`long_schedule` reads the loop's own measure: the longest run
+    phase between two selects since the last tick (LoopBudget)."""
+    from emqx_tpu.observe import profiler
+
     am = AlarmManager()
     sm = SysMon(am, long_schedule_ms=50.0)
-    now = time.time()
-    sm.check(now, 1.0)          # arms expectation: next tick at now+1.0
-    sm.check(now + 1.3, 1.0)    # fired 300ms late -> alarm
+    monkeypatch.setattr(
+        profiler, "_now", iter([0.0, 1.0, 1.02, 2.0, 2.3, 3.0]).__next__
+    )
+    budget = profiler.LoopBudget()
+    budget.enter_select(), budget.exit_select()  # idle 0.0 -> 1.0
+    budget.enter_select(), budget.exit_select()  # ran 20 ms
+    sm.check(time.time(), budget)
+    assert not am.is_active("long_schedule")
+    budget.enter_select(), budget.exit_select()  # ran 300 ms -> alarm
+    sm.check(time.time(), budget)
     assert am.is_active("long_schedule")
+    assert budget.take_longest_run() == 0.0  # the tick took it
     sm.close()
+    budget.closed()  # this thread records every section again
 
 
 def test_osmon_and_vmmon_populate_gauges():

@@ -7,9 +7,8 @@ provenance") names:
   queue-wait -> launch -> device-execute -> readback -> host-dispatch)
   records into its own histogram on the REAL BatchIngest path, and the
   stage means tile the measured enqueue->settle latency;
-- per-kernel cost attribution: `device.kernel.<name>.*` series are
-  keyed to @device_contract REGISTRY names — the route, session-ride,
-  and semantic kernels each show up when their path runs;
+- the fused session stage rides the serving launch (its own counter
+  and the launch's stage series say so);
 - the disarmed profiler is structurally zero (racetrack discipline):
   no capture object, no trace directory, no series, no tick work;
 - the REST arm/capture/disarm lifecycle with a REAL on-disk byte
@@ -27,7 +26,6 @@ import functools
 import json
 import os
 
-import numpy as np
 import pytest
 
 from emqx_tpu.broker.broker import Broker
@@ -44,8 +42,6 @@ from emqx_tpu.observe.profiler import (
     STAGES,
     Profiler,
     harvest_cost,
-    kernel_summary,
-    record_kernel_launch,
     roofline_summary,
     waterfall,
 )
@@ -124,33 +120,56 @@ class TestWaterfall:
         assert stage_sum >= settle_mean * 0.2, (stage_sum, settle_mean)
 
 
-# -- per-kernel attribution keyed to contract names --------------------------
-
-
-class TestKernelAttribution:
-    def test_route_kernels_attributed_under_registry_names(self):
-        b = _mk_broker()
-        _sub_n(b, 8)
-        dr = b._device_router()
-        res = dr.route_prepared(dr.prepare(),
-                                [m.topic for m in _msgs(16)])
-        assert res.kernels, "RouteResult.kernels must name the program"
-        for name in res.kernels:
-            assert name in REGISTRY, name
-        ks = kernel_summary(b.metrics)
-        hit = [k for k in res.kernels if k in ks]
-        assert hit, (res.kernels, sorted(ks))
-        for k in hit:
-            assert ks[k]["launches"] >= 1
-            assert ks[k]["mean_ms"] > 0.0
-        # the route program itself rode the launch
-        assert any(
-            k in ks for k in ("shape_route_step",
-                              "sparse_shape_route_step")
-        ), sorted(ks)
-
     @async_test
-    async def test_session_ride_attributes_session_ack_step(self):
+    async def test_stages_count_per_batch_and_agree_with_their_sections(self):
+        """The five busy stages are opened through the section helper and
+        keep their meaning: one observation per device batch (queue_wait
+        one per message), and the stage's seconds ARE its section's."""
+        from emqx_tpu.observe import profiler as P
+
+        P.flush(Metrics())  # what earlier tests accumulated
+        b = _mk_broker(min_batch=8)
+        _sub_n(b, 8)
+        ing = BatchIngest(b, max_batch=64, window_us=500)
+        b.ingest = ing
+        ing.start()
+        rs = [await b.apublish_enqueue(m) for m in _msgs(256)]
+        await asyncio.gather(*[r for r in rs if not isinstance(r, int)])
+        await ing.stop()
+        m = b.metrics
+        P.flush(m)
+        batches = m.histogram("ingest.batch.size").count
+        device_rows = m.get("messages.routed.device")
+        assert batches >= 1 and device_rows >= 8
+        per_batch = [s for s in STAGES if s != "queue_wait"]
+        counts = {
+            s: m.histogram(f"profile.stage.{s}.seconds").count
+            for s in per_batch
+        }
+        assert len(set(counts.values())) == 1, counts
+        assert 1 <= counts["launch"] <= batches
+        assert m.histogram("profile.stage.queue_wait.seconds").count == 256
+        for s in per_batch:
+            stage = m.histogram(f"profile.stage.{s}.seconds")
+            sec = m.histogram(f"profile.section.{s}.seconds")
+            assert sec is not None and sec.count == stage.count, s
+            assert sec.sum == pytest.approx(stage.sum), s
+        # children nest: the executor's three stages have no children,
+        # host_dispatch's self time is at most its total
+        hd = m.histogram("profile.section.host_dispatch.seconds")
+        hd_self = m.histogram("profile.section.host_dispatch.self.seconds")
+        assert 0.0 < hd_self.sum <= hd.sum
+
+
+# -- the fused session stage still rides the serving launch ------------------
+
+
+class TestSessionRide:
+    @async_test
+    async def test_session_ride_counts_on_the_serving_launch(self):
+        """The session-ack stage fuses into the serving launch: acks
+        never pay a launch of their own (`session.ack.rides`), and the
+        launch's stage series move once per launch with the rider in."""
         b = _mk_broker()
         store = SessionStore(metrics=b.metrics, capacity=256,
                              sweep_slots=64, retry_interval=30.0)
@@ -166,43 +185,9 @@ class TestKernelAttribution:
         for p in sent[:4]:
             sess.puback(p.packet_id)
         await b.adispatch_batch_folded(_msgs(8, qos=1))  # rider batch
-        ks = kernel_summary(b.metrics)
-        assert "session_ack_step" in ks, sorted(ks)
-        assert ks["session_ack_step"]["launches"] >= 1
-
-    def test_semantic_match_attributed(self):
-        from emqx_tpu.broker.semantic import SemanticRouting
-
-        rng = np.random.default_rng(7)
-        dim = 16
-
-        def unit():
-            v = rng.normal(size=dim).astype(np.float32)
-            return v / np.linalg.norm(v)
-
-        b = _mk_broker()
-        b.semantic = SemanticRouting(dim=dim, topk=4, threshold=0.3,
-                                     metrics=b.metrics)
-        opts = pkt.SubOpts(qos=0)
-        b.subscribe("p1", "p1", "a/#", opts, lambda m, o: None)
-        for i in range(4):
-            b.subscribe(f"m{i}", f"m{i}", "a/#", opts,
-                        lambda m, o: None,
-                        embedding=unit(), sem_threshold=0.3)
-        msgs = []
-        for i in range(8):
-            m = Message(topic=f"a/{i}", payload=b"{}",
-                        from_client="pub")
-            m.headers["semantic_embedding"] = unit()
-            msgs.append(m)
-        b.dispatch_batch_folded(msgs)
-        ks = kernel_summary(b.metrics)
-        assert "semantic_match_step" in ks, sorted(ks)
-        assert ks["semantic_match_step"]["launches"] >= 1
-
-    def test_record_kernel_launch_is_metrics_optional(self):
-        # bare-library semantics: no metrics registry, no crash
-        record_kernel_launch(None, ("shape_route_step",), 0.001, 64)
+        assert b.metrics.get("session.ack.rides") >= 1
+        launches = b.metrics.histogram("profile.stage.launch.seconds")
+        assert launches is not None and launches.count == 2
 
 
 # -- disarmed profiler: structurally zero ------------------------------------
@@ -310,12 +295,19 @@ class TestCaptureLifecycle:
                     assert snap["armed"] is False
                     assert snap["fingerprint"]["proxy"] is True
                     assert set(snap["waterfall"]) == set(STAGES)
+                    # the section table rides beside the waterfall
+                    assert isinstance(snap["sections"], dict)
+                    assert set(snap["loop"]) >= {
+                        "select_s", "run_s", "other_s", "stall_s",
+                        "gc_pause_s"}
+                    assert snap["stalls"] == []
                 async with s.post(
                     f"{api}/profile", json={"duration_s": 20.0}
                 ) as r:
                     assert r.status == 201
                     info = await r.json()
                     assert info["dir"].startswith(str(tmp_path))
+                    assert info["python_tracer"] is False  # the default
                 async with s.post(f"{api}/profile", json={}) as r:
                     assert r.status == 400  # already armed
                 # the armed state is visible in the hotpath block too
