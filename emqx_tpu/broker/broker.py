@@ -30,6 +30,7 @@ from emqx_tpu.mqtt import packet as pkt
 from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.observe.spans import TRACE_HEADER
 from emqx_tpu.ops import topics as T
+from emqx_tpu.transport.egress import flush_dirty
 from emqx_tpu.utils.tracepoints import tp
 
 # deliverer: called with (msg, subopts); returns True if accepted
@@ -544,8 +545,10 @@ class Broker:
                     self.hooks.run("message.dropped", m, "no_subscribers")
                     self.metrics.inc("messages.dropped.no_subscribers")
                 out.append(n)
-            return out
-        return [self._dispatch_routed(m, forward) for m in msgs]
+        else:
+            out = [self._dispatch_routed(m, forward) for m in msgs]
+        flush_dirty()  # the batch's write boundary, as on the device path
+        return out
 
     async def adispatch_batch_folded(
         self, msgs: Sequence[Message], forward: bool = True
@@ -935,6 +938,9 @@ class Broker:
         if fell_back:
             self.metrics.inc("messages.routed.device_fallback", fell_back)
         self.metrics.inc("messages.routed.device", len(msgs) - fell_back)
+        # the batch's write boundary: every in-process connection it
+        # touched, one socket write each
+        flush_dirty()
         tp("dispatch.batch", n=len(msgs), fallback=fell_back)
         return out
 

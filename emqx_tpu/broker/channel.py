@@ -116,7 +116,8 @@ class Channel:
             _prof.end()
 
     def _send_all(self, packets) -> None:
-        """One write batch: a read chunk's replacement sends."""
+        """A read chunk's replacement sends: one section; the sink
+        writes them with the chunk's end."""
         _prof.begin("egress.send")
         try:
             for p in packets:
@@ -644,11 +645,17 @@ class Channel:
             self._ack_task = asyncio.ensure_future(self._drain_acks())
 
     async def _drain_acks(self) -> None:
+        # a settled batch resolves many of this publisher's futures at
+        # once: their acks are one socket write, made where the resolved
+        # run ends (before the next unresolved future, or with the queue)
+        flush = getattr(self.sink, "flush", None)
         while self._ack_queue:
             r, send, on_fail = self._ack_queue.popleft()
             if isinstance(r, int):
                 n = r
             else:
+                if flush is not None and not r.done():
+                    flush()
                 try:
                     n = await r
                 except Exception:
@@ -669,6 +676,8 @@ class Channel:
                 send(n)
             except Exception:
                 pass  # transport already torn down
+        if flush is not None:
+            flush()
 
     def _signal_drained(self) -> None:
         if self._ack_drained is not None:
@@ -945,11 +954,11 @@ class Channel:
         """QoS1/2 fan-out fast path: serialize the PUBLISH ONCE per
         (version, qos, retain, topic) as a head/tail pair around the
         packet-id slot (mqtt/slab_serializer.split_publish — bytes
-        identical to frame.serialize) and emit each subscriber's frame
-        as writelines([head, pid, tail]) — the payload is never copied
-        per target. The cache rides the Message like the QoS0 `_fb`
-        cache; retained-store replays are excluded for the same
-        lifetime reason. Returns False to fall back to `_send`."""
+        identical to frame.serialize) and hand each subscriber's frame
+        to the sink as the segments [head, pid, tail] — the payload is
+        never re-serialised per target. The cache rides the Message
+        like the QoS0 `_fb` cache; retained-store replays are excluded
+        for the same lifetime reason. Returns False to fall back to `_send`."""
         ws = getattr(self.sink, "send_segments", None)
         if ws is None or msg.headers.get("retained"):
             return False
@@ -1042,8 +1051,8 @@ class Channel:
         """Batched twin of `_store_resend` for the session store's sweep
         floods: ALL of this channel's due rows serialize in ONE slab
         pass (mqtt/slab_serializer — vectorized headers/varints, frames
-        byte-identical to the per-packet path) and land on the socket as
-        a `writelines` of memoryviews. Returns per-item sent flags (all
+        byte-identical to the per-packet path) and go to the sink as
+        memoryview segments. Returns per-item sent flags (all
         False when the channel can't transmit)."""
         if self.state != "connected" or self.session is None:
             return [False] * len(items)

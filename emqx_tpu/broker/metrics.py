@@ -290,6 +290,10 @@ default_metrics = Metrics()
 
 # packets / messages (emqx_metrics.erl families)
 declare("packets.sent", COUNTER, "MQTT packets written to clients")
+declare("egress.writes", COUNTER,
+        "socket writes by the in-process sink (Connection.flush: one per "
+        "connection per batch boundary); packets.sent over this is how "
+        "many packets one write carries")
 declare("packets.received", COUNTER, "MQTT packets read from clients")
 declare("messages.received", COUNTER, "messages entering dispatch")
 declare("messages.delivered", COUNTER, "deliveries handed to subscribers")
@@ -801,7 +805,7 @@ SECTIONS: Tuple[str, ...] = (
     "channel.publish_in",   # Channel._in_publish up to the enqueue (checks, authz)
     "ingest.enqueue",       # Broker.apublish_enqueue: message.publish fold + lane append
     "channel.ack_in",       # a chunk's run of PUBACK / PUBREC / PUBCOMP incl. the drain
-    "egress.send",          # serialise + write: a packet, an ack run's sends, a DLV flush
+    "egress.send",          # serialise + append (packets); a flush's socket write; a DLV flush
     "ingest.take",          # BatchIngest._take_batch
     "prepare",              # stage: table snapshot + upload
     "ingest.finish",        # BatchIngest._finish: the per-message fut.set_result loop

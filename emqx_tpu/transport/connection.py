@@ -10,6 +10,7 @@ enforced here; an idle pre-CONNECT socket is closed after idle_timeout
 from __future__ import annotations
 
 import asyncio
+import os
 import time
 from typing import Optional
 
@@ -17,6 +18,12 @@ from emqx_tpu.broker.channel import ACKS, Channel, ChannelConfig
 from emqx_tpu.mqtt import packet as pkt
 from emqx_tpu.mqtt.frame import FrameError, Parser, serialize
 from emqx_tpu.observe import profiler as _prof
+from emqx_tpu.transport import egress
+
+try:
+    _IOV_MAX = os.sysconf("SC_IOV_MAX")  # what asyncio's transport slices at
+except (OSError, ValueError, AttributeError):
+    _IOV_MAX = 16
 
 
 class Connection:
@@ -36,6 +43,7 @@ class Connection:
         self.parser = Parser(max_size=config.caps.max_packet_size)
         self.last_rx = time.time()
         self._closing = False
+        self._pending: list = []  # serialised output not yet written
         self._tasks: list = []
         # rate limiting / congestion / forced GC (TransportContext wiring)
         self.limiters = None
@@ -55,38 +63,102 @@ class Connection:
                 self.forced_gc = ctx.make_forced_gc()
 
     # -- sink interface used by the channel -------------------------------
+    # The sink coalesces: a send appends the serialised frame to this
+    # connection's pending list, and a batch boundary hands the list to
+    # the socket in one write (docs/protocol_plane.md "The in-process
+    # sink"). Byte order is the call order: one list, appended in order.
     def send_packet(self, p) -> None:
         if self._closing:
             return
         try:
-            self.writer.write(serialize(p, self.channel.version))
+            frame = serialize(p, self.channel.version)
         except Exception:
             self.close("send_error")
+            return
+        self._append((frame,))
 
     def send_bytes(self, b: bytes) -> None:
         """Pre-serialized frame (the channel's QoS0 fan-out cache:
         serialize once per message, write to every subscriber socket)."""
-        if self._closing:
-            return
-        try:
-            self.writer.write(b)
-        except Exception:
-            self.close("send_error")
+        if not self._closing:
+            self._append((b,))
 
     def send_segments(self, segs) -> None:
         """Pre-serialized frame segments (the batched slab serializer:
-        writelines of memoryviews — shared heads/tails and slab frame
-        views land on the socket without an intermediate join)."""
+        shared heads/tails and slab frame views; large ones land on the
+        socket by `writelines` without an intermediate join)."""
+        if not self._closing:
+            self._append(segs)
+
+    def _append(self, segs) -> None:
+        pend = self._pending
+        if not pend:
+            loop = asyncio._get_running_loop()
+            if loop is None:
+                # driven synchronously: no turn to end, write through
+                # (inside the caller's `egress.send` section)
+                self._write(list(segs))
+                return
+            # the safety net: whatever no boundary flushes first goes
+            # out with the loop's end-of-turn call
+            egress.of(loop).mark(self)
+        pend.extend(segs)
+
+    # a flush joins small frames into one `write` (one send()); past
+    # JOIN_MAX bytes of large segments it hands them over uncopied, in
+    # `writelines` calls the transport can pass to one sendmsg() each
+    # (asyncio slices its buffer at SC_IOV_MAX)
+    JOIN_MAX = 64 * 1024
+    SMALL_SEGMENT = 1024
+    IOV_MAX = _IOV_MAX
+
+    def flush(self) -> None:
+        """Hand everything pending to the socket: one write. Called at
+        the batch boundaries (the end of a read chunk, of a settled
+        batch's fan-out, of the ack drainer's resolved run), by the
+        loop's end-of-turn call and before a close."""
+        pend = self._pending
+        if not pend:
+            return
+        self._pending = []
         if self._closing:
             return
+        # the socket write's time stays in `egress.send`; its entries
+        # are the packets, counted where they were serialised
+        _prof.begin("egress.send")
         try:
-            self.writer.writelines(segs)
+            self._write(pend)
+        finally:
+            _prof.end(0)
+
+    def _write(self, segs: list) -> None:
+        n = len(segs)
+        writes = 1
+        try:
+            if n == 1:
+                self.writer.write(segs[0])
+            else:
+                size = sum(map(len, segs))
+                if size <= self.JOIN_MAX or size < n * self.SMALL_SEGMENT:
+                    self.writer.write(b"".join(segs))
+                else:
+                    writes = 0
+                    for i in range(0, n, self.IOV_MAX):
+                        self.writer.writelines(segs[i:i + self.IOV_MAX])
+                        writes += 1
         except Exception:
             self.close("send_error")
+            return
+        self.channel.broker.metrics.inc("egress.writes", writes)
 
     def close(self, reason: str) -> None:
         if self._closing:
             return
+        # the last packets (a DISCONNECT, a refusing CONNACK) reach the
+        # peer before the FIN: the transport writes its buffer out first
+        self.flush()
+        if self._closing:
+            return  # the flush's own write failed and closed
         self._closing = True
         try:
             self.writer.close()
@@ -179,6 +251,7 @@ class Connection:
         self.last_rx = time.time()
 
     async def _drain(self) -> None:
+        self.flush()
         try:
             await self.writer.drain()
         except ConnectionError:
@@ -211,6 +284,7 @@ class Connection:
                 self.channel.tick()
                 await self._drain()
             if self.congestion is not None:
+                self.flush()  # the alarm reads the transport's buffer
                 self.congestion.check(
                     getattr(self.writer, "transport", None),
                     self.channel.client_id,

@@ -610,6 +610,7 @@ def test_every_new_series_is_declared_and_exported_as_sum_and_count():
     for name, kind in (("device.compile.in_launch.count", COUNTER),
                        ("device.compile.in_readback.count", COUNTER),
                        ("session.mqueue.dropped", COUNTER),
+                       ("egress.writes", COUNTER),
                        ("device.hbm.peak.bytes", GAUGE)):
         assert kind_of(name) == kind, name
     for gone in ("ingest.device.idle.seconds",
@@ -735,7 +736,9 @@ def test_a_chunk_s_acks_are_one_section_and_one_write_batch(
         metrics, monkeypatch):
     """Three PUBACKs in one read chunk: one `ingress.decode` (three
     packets), one `channel.ack_in` (three acks) and inside it one
-    `egress.send` for the three replacement PUBLISHes."""
+    `egress.send` for the three replacement PUBLISHes (serialised and
+    appended: three entries); the chunk's end is one more `egress.send`
+    with no entries, the one socket write that carries all three."""
     from emqx_tpu.broker.channel import ChannelConfig
     from emqx_tpu.broker.cm import ChannelManager
     from emqx_tpu.mqtt.frame import serialize
@@ -754,7 +757,9 @@ def test_a_chunk_s_acks_are_one_section_and_one_write_batch(
     for i in range(6):  # three in flight, three queued behind them
         ch.handle_deliver(
             Message(topic="t/1", payload=b"%d" % i, qos=1), pkt.SubOpts(qos=1))
+    # no loop runs yet: the sink writes through, one write per packet
     assert len(writer.writes) == 3 and len(ch.session.mqueue) == 3
+    assert b.metrics.get("egress.writes") == 3
     P.flush(Metrics())  # the set-up's own sends are not this test's
     opened = []
     real_begin = P.begin
@@ -762,19 +767,29 @@ def test_a_chunk_s_acks_are_one_section_and_one_write_batch(
         P, "begin", lambda name, **ids: (opened.append(name),
                                          real_begin(name, **ids))[1])
     asyncio.run(conn.run())
-    assert opened[:3] == ["ingress.decode", "channel.ack_in", "egress.send"]
-    assert opened.count("channel.ack_in") == 1
-    assert opened.count("egress.send") == 1
-    assert len(writer.writes) == 6 and len(ch.session.mqueue) == 0
+    assert opened == ["ingress.decode", "channel.ack_in", "egress.send",
+                      "egress.send"]
+    assert len(ch.session.mqueue) == 0
+    # one socket write for the chunk, holding the three frames in order
+    assert len(writer.writes) == 4
+    assert b.metrics.get("egress.writes") == 4
+    assert writer.writes[3] == b"".join(
+        serialize(pkt.Publish(topic="t/1", payload=b"%d" % i, qos=1,
+                              packet_id=pid), pkt.MQTT_V4)
+        for i, pid in ((3, 4), (4, 5), (5, 6)))
     assert b.metrics.get("packets.received") == 3
     P.flush(metrics)
     assert hist(metrics, "profile.section.ingress.decode.seconds")[1] == 3
     assert hist(metrics, "profile.section.channel.ack_in.seconds")[1] == 3
+    # `egress.send` entries are packets: the flush adds time, no entry
     assert hist(metrics, "profile.section.egress.send.seconds")[1] == 3
+    assert b.metrics.get("packets.sent") == 6
     ack_s, _ = hist(metrics, "profile.section.channel.ack_in.seconds")
     ack_self, _ = hist(metrics, "profile.section.channel.ack_in.self.seconds")
     send_s, _ = hist(metrics, "profile.section.egress.send.seconds")
-    assert ack_self == pytest.approx(ack_s - send_s)
+    # the serialising `egress.send` is `channel.ack_in`'s child, the
+    # socket write at the chunk's end is not
+    assert 0 < ack_s - ack_self < send_s
 
 
 def _puback(pid):
