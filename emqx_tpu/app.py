@@ -782,6 +782,7 @@ class BrokerApp:
                 node_name(),
                 self.cluster_bus,
                 broker=self.broker,
+                forward_mode=c.cluster.rpc_mode,
                 loop=asyncio.get_running_loop(),
             )
             self.broker.cluster = self.cluster_node

@@ -393,6 +393,32 @@ class Broker:
         finally:
             _prof.end(0)  # the same message: no second entry
 
+    def after_forward_confirms(self, fn) -> bool:
+        """`cluster.rpc_mode: sync` (the reference's `[rpc, mode]`): run
+        `fn()` on the running loop once every cross-node forward handed
+        off since the last call was confirmed by its destination node,
+        i.e. dispatched there. False, and `fn` is left to the caller,
+        when nothing is waited for (no cluster, `async`, no remote
+        subscriber, or confirmed already). Nothing blocks here: the
+        confirmations resolve on the forward lanes' threads."""
+        c = self.cluster
+        pending = c.take_confirms() if c is not None else ()
+        if not pending:
+            return False
+        loop = asyncio.get_running_loop()
+        left = [len(pending)]
+
+        def step():
+            left[0] -= 1
+            if not left[0]:
+                fn()
+
+        for f in pending:
+            f.add_done_callback(
+                lambda _f: loop.call_soon_threadsafe(step)
+            )
+        return True
+
     def _enqueue_folded(self, msg: Optional[Message], sp):
         """`apublish_enqueue` after the message.publish fold -> delivery
         count, or the ingest's future."""
@@ -409,6 +435,15 @@ class Broker:
         n = self._dispatch_routed(msg)
         if sp is not None:
             self.spans.finish_span(sp, n)
+        if self.cluster is not None:
+            # rpc_mode sync: the count (and the PUBACK behind it) waits
+            # for the forwards' confirmation
+            box: list = []
+            if self.after_forward_confirms(
+                lambda: box[0].done() or box[0].set_result(n)
+            ):
+                box.append(asyncio.get_running_loop().create_future())
+                return box[0]
         return n
 
     def _publish_folded(self, msg: Optional[Message]) -> int:

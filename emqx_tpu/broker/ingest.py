@@ -321,8 +321,16 @@ class BatchIngest:
             if rec is not None and bsp is not None:
                 rec.finish(bsp, {"error": str(e)}, status="error")
             return
-        with _prof.section("ingest.finish", batch=seq, rows=len(batch)):
-            self._resolve(seq, batch, results, bsp, rec)
+        def resolve():
+            with _prof.section("ingest.finish", batch=seq, rows=len(batch)):
+                self._resolve(seq, batch, results, bsp, rec)
+
+        # cluster.rpc_mode sync: the publishers' futures (their PUBACKs)
+        # resolve once the batch's forwards are confirmed; the flusher
+        # goes on to the next batch meanwhile
+        wait = getattr(self.broker, "after_forward_confirms", None)
+        if wait is None or not wait(resolve):
+            resolve()
 
     def _resolve(self, seq: int, batch, results, bsp, rec) -> None:
         """Settle one dispatched batch: every publisher's future, the

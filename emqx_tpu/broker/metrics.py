@@ -377,6 +377,31 @@ declare("cluster.send.dead_letter", COUNTER,
         "cluster sends given up after deadline/retry budget (the "
         "bounded dead-letter count)")
 
+# the cluster's forward lanes and route replication (cluster/node.py)
+declare("cluster.forward.batches", COUNTER,
+        "per-destination batches handed to the forward lanes")
+declare("cluster.forward.messages", COUNTER,
+        "messages handed to the forward lanes (one per message and "
+        "destination node)")
+declare("cluster.forward.retries", COUNTER,
+        "forward calls sent again: the connection broke or the peer "
+        "could not be reached while alive by membership; or a group "
+        "rerouted to a dead node's successor")
+declare("cluster.forward.duplicates", COUNTER,
+        "repeated forward batches the receiver answered with the first "
+        "one's result, without a second dispatch")
+declare("cluster.forward.unconfirmed", GAUGE,
+        "messages handed to the forward lanes and not yet confirmed by "
+        "their destination node, all peers")
+declare("cluster.forward.confirm.seconds", HISTOGRAM,
+        "hand-off of a forward batch to its destination's confirmation "
+        "(the dispatch there), per batch",
+        buckets=LATENCY_BUCKETS, unit="seconds")
+declare("cluster.route.batches", COUNTER,
+        "`route` v2 apply_batch calls received from peers")
+declare("cluster.route.ops", COUNTER,
+        "(op, filter) pairs received in those batches")
+
 # worker fabric (transport/workers.py)
 declare("fabric.sess.crash_parked", COUNTER)
 declare("fabric.sess.resumes", COUNTER)
@@ -811,6 +836,9 @@ SECTIONS: Tuple[str, ...] = (
     "ingest.finish",        # BatchIngest._finish: the per-message fut.set_result loop
     "host_dispatch",        # stage: settle-time fan-out
     "housekeeping",         # the 1 Hz tick
+    "cluster.forward.out",  # ClusterNode.forward_batch_remote: replica match, grouping, hand-off (entries: messages forwarded)
+    "cluster.forward.in",   # the receiving half of a forward up to its dispatch (entries: messages)
+    "cluster.route.apply",  # one applied slice of a peer's route batch
     # the dispatch executor's threads
     "launch",
     "device_execute",

@@ -22,7 +22,12 @@ from emqx_tpu.cluster.transport import LocalBus
 MembershipCallback = Callable[[str, str], None]  # (event, node)
 
 HEARTBEAT_INTERVAL = 1.0
-FAILURE_TIMEOUT = 3.0
+# A node under load stops the world for seconds at a time (a full GC pass
+# over a million subscriptions takes 3-4 s, PERF.md): no thread of it acks
+# a heartbeat meanwhile. Declaring it down purges its routes from every
+# replica, so the timeout has to outlast what a live node does. (The
+# reference's detector, the distribution's net_ticktime, defaults to 60 s.)
+FAILURE_TIMEOUT = 10.0
 
 
 class Membership:
@@ -41,6 +46,9 @@ class Membership:
         self._last_seen: Dict[str, float] = {}  # guarded-by: _lock
         self._alive: Dict[str, bool] = {node: True}  # guarded-by: _lock
         self._callbacks: List[MembershipCallback] = []
+        # when `expire` last ran, on the real clock: a gap says THIS
+        # process stood still, and its peers' acks with it
+        self._expired_at: Optional[float] = None  # guarded-by: _lock
 
     # -- ekka:monitor(membership) parity ----------------------------------
     def monitor(self, callback: MembershipCallback) -> None:
@@ -137,8 +145,22 @@ class Membership:
 
     def expire(self) -> None:
         now = self._clock()
+        real = time.monotonic()
         downs = []
         with self._lock:
+            stood = 0.0
+            if self._expired_at is not None and self._clock is time.monotonic:
+                # (a test's logical clock stands outside real time.) Called
+                # every HEARTBEAT_INTERVAL on a live app: what is over that
+                # was this process stopped (a GC pass, a starved thread),
+                # when no ack could be read: no evidence against a peer
+                stood = max(
+                    0.0, real - self._expired_at - 2 * HEARTBEAT_INTERVAL
+                )
+            self._expired_at = real
+            if stood:
+                for p in self._last_seen:
+                    self._last_seen[p] += stood
             for p, seen in list(self._last_seen.items()):
                 if self._alive.get(p) and now - seen > FAILURE_TIMEOUT:
                     self._alive[p] = False

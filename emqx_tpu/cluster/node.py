@@ -20,7 +20,13 @@ analog of the reference's slave-node CT harness
 
 from __future__ import annotations
 
+import asyncio
+import collections
+import concurrent.futures
+import logging
+import os
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -31,9 +37,52 @@ from emqx_tpu.cluster.cluster_rpc import ClusterRpcLog
 from emqx_tpu.cluster.membership import Membership
 from emqx_tpu.cluster.route_sync import ClusterRouteTable, ShardOwnership
 from emqx_tpu.cluster.rpc import Rpc, RpcError
+from emqx_tpu.cluster.tcp_transport import CH_FORWARD, CH_ROUTE
 from emqx_tpu.cluster.transport import LocalBus
 from emqx_tpu.mqtt import packet as pkt
+from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.ops import topics as T
+
+log = logging.getLogger("emqx_tpu.cluster")
+
+ROUTE_BATCH_MAX = 4096  # (op, filter) pairs one `route` v2 apply_batch carries
+ROUTE_SLICE_S = 0.005  # what one applied slice should take of the loop
+ROUTE_SLICE_MIN, ROUTE_SLICE_MAX = 64, 4096
+FWD_GROUP_MAX = 8192  # messages one forward call carries (queued batches joined)
+LANE_WORKERS = 32  # lanes draining at once (two per peer: forwards, routes)
+
+
+class _Lane:
+    """One ordered queue to one peer (forwards, or route ops), drained by
+    at most one worker at a time: a slow peer delays only its own lane."""
+
+    __slots__ = ("items", "lock", "running", "seq")
+
+    def __init__(self) -> None:
+        self.items: collections.deque = collections.deque()
+        self.lock = threading.Lock()
+        self.running = False
+        self.seq = 0  # forwards: the last sequence number shipped
+
+
+class _Confirm:
+    """The forwards one dispatched batch handed off (`rpc_mode: sync`):
+    `future` resolves once every destination node confirmed its own, or
+    gave it up with the node down."""
+
+    __slots__ = ("future", "_left", "_lock")
+
+    def __init__(self, n: int) -> None:
+        self.future: concurrent.futures.Future = concurrent.futures.Future()
+        self._left = n
+        self._lock = threading.Lock()
+
+    def one_done(self) -> None:
+        with self._lock:
+            self._left -= 1
+            done = self._left == 0
+        if done:
+            self.future.set_result(None)
 
 
 class ClusterNode:
@@ -62,14 +111,31 @@ class ClusterNode:
             if loop is not None
             else None
         )
-        # forwards get their OWN ordered worker: a slow receiver (cold
-        # jit compile holds the confirmed reply up to ~40s) must not
-        # stall route replication / shared-group / drain traffic
+        # the lanes' workers (forwards and route batches, one ordered
+        # lane per peer and kind, `_lane_put`): a slow receiver (a cold
+        # jit compile holds a confirmed reply up to ~40s, a GC pass ~3s)
+        # delays its own lane and nothing else. Without a loop (library
+        # mode) a lane drains on the caller's thread.
         self._fwd_pool = (
-            ThreadPoolExecutor(max_workers=1, thread_name_prefix=f"fwd-{name}")
+            ThreadPoolExecutor(
+                max_workers=LANE_WORKERS, thread_name_prefix=f"lane-{name}"
+            )
             if loop is not None
             else None
         )
+        self._lanes: Dict[Tuple[str, str], _Lane] = {}  # guarded-by: _lanes_lock
+        self._lanes_lock = threading.Lock()
+        self._leaving = False  # guarded-by: _lanes_lock
+        # what a forward is known by at its destination: this node's name
+        # and this incarnation (a restarted node counts from 1 again)
+        self._epoch = f"{os.getpid()}.{time.time_ns()}"
+        # origin node -> {"epoch", "done": highest sequence applied,
+        # "result": the last group's, "running": (first, future) | None}
+        self._fwd_in: Dict[str, dict] = {}  # guarded-by: _fwd_in_lock
+        self._fwd_in_lock = threading.Lock()
+        self._confirms: List[_Confirm] = []  # not yet taken by a settle
+        self._unconfirmed = 0  # guarded-by: _lanes_lock
+        self._route_slice = 256  # ops per applied slice, steered to ROUTE_SLICE_S
         self.broker = broker or Broker()
         self.routes = ClusterRouteTable(name)
         # mesh-slice ownership (scale-out serving): which node serves
@@ -170,6 +236,17 @@ class ClusterNode:
                 "forward_batch": self._proto_forward_batch,
             },
         )
+        # v2: forwards known by (origin, incarnation, lane sequence), so
+        # that a repeated group is answered and not dispatched again
+        self.rpc.registry.register(
+            "broker",
+            2,
+            {
+                "forward": self._proto_forward,
+                "forward_batch": self._proto_forward_batch,
+                "forward_lane": self._proto_forward_lane,
+            },
+        )
         self.rpc.registry.register(
             "route",
             1,
@@ -177,6 +254,17 @@ class ClusterNode:
                 "add_route": self.routes.add_route,
                 "delete_route": self.routes.delete_route,
                 "dump": self.routes.dump,
+            },
+        )
+        # v2: one call carries a few thousand ordered (op, filter) pairs
+        self.rpc.registry.register(
+            "route",
+            2,
+            {
+                "add_route": self.routes.add_route,
+                "delete_route": self.routes.delete_route,
+                "dump": self.routes.dump,
+                "apply_batch": self._proto_route_apply_batch,
             },
         )
         self.rpc.registry.register(
@@ -310,11 +398,10 @@ class ClusterNode:
             return False
         # pull the seed's route replica (mria replicant catch-up)
         self.routes.load(self.rpc.call(seed, "route", "dump"))
-        # push our own local routes to everyone
-        mine = [(f, ns) for f, ns in self.routes.dump() if self.name in ns]
+        # push our own local routes to everyone, batched like live ones
+        mine = self.routes.local_filters()
         for peer in self.membership.peers():
-            for f, _ in mine:
-                self.rpc.cast(peer, "route", "add_route", f, self.name, key=f)
+            self._lane_put("route", peer, *(("add", f) for f in mine))
         # config log catch-up
         entries = self.rpc.call(seed, "conf", "entries_after", self.conf_log.cursor)
         self.conf_log.catch_up_from([tuple(e) for e in entries])
@@ -416,10 +503,12 @@ class ClusterNode:
         # loop-side submit racing this drain (leave runs on the default
         # executor during a rolling-upgrade handoff) gets a RuntimeError
         # that `_pool_submit` drops — never a torn None dereference
+        with self._lanes_lock:
+            self._leaving = True  # a lane stops retrying a peer
         if self._repl_pool is not None:
             self._repl_pool.shutdown(wait=True)  # flush pending replication
         if self._fwd_pool is not None:
-            self._fwd_pool.shutdown(wait=True)  # flush in-flight forwards
+            self._fwd_pool.shutdown(wait=True)  # flush the lanes
         self.membership.leave()
         self.rpc.stop()
         self.bus.detach(self.name)
@@ -453,35 +542,148 @@ class ClusterNode:
 
     def _replicate_add(self, filter_: str) -> None:
         self.routes.add_route(filter_, self.name)
-        self._replicate("add_route", filter_)
+        self._replicate("add", filter_)
 
     def _replicate_delete(self, filter_: str) -> None:
         self.routes.delete_route(filter_, self.name)
-        self._replicate("delete_route", filter_)
+        self._replicate("delete", filter_)
 
-    def _replicate(self, method: str, filter_: str) -> None:
-        """Wildcards replicate transactionally (maybe_trans,
-        emqx_router.erl:118-121 — a torn trie edge breaks matching);
-        exact topics ride ordered casts. In app mode both ship through
-        the replication worker so the event loop never blocks on a peer
-        round-trip (ordering preserved: one worker, FIFO submits)."""
-        peers = self.membership.peers()
+    def _replicate(self, op: str, filter_: str) -> None:
+        """A local route change goes, in order, into every peer's route
+        lane; the lane ships what has gathered as one `route` v2
+        `apply_batch` per drain (per filter to a v1 peer: wildcards as
+        confirmed calls, maybe_trans, emqx_router.erl:118-121, exact
+        topics as ordered casts). The event loop never waits for a peer;
+        without a loop the lane drains right here, so a library caller
+        finds the peers' replicas written when `subscribe` returns."""
+        item = (op, filter_)
+        for p in self.membership.peers():
+            self._lane_put("route", p, item)
 
-        def one(p):
-            if T.wildcard(filter_):
-                try:
-                    self.rpc.call(p, "route", method, filter_, self.name)
-                except RpcError:
-                    pass  # peer down: membership GC will reconcile
-            else:
-                self.rpc.cast(p, "route", method, filter_, self.name, key=filter_)
-
-        if self._repl_pool is not None:
-            for p in peers:
-                self._pool_submit(self._repl_pool, one, p)
+    # -- the lanes -----------------------------------------------------------
+    def _lane_put(self, kind: str, peer: str, *items) -> None:
+        key = (kind, peer)
+        lane = self._lanes.get(key)  # lint: disable=LK001
+        if lane is None:
+            with self._lanes_lock:
+                lane = self._lanes.setdefault(key, _Lane())
+        with lane.lock:
+            lane.items.extend(items)
+            start = not lane.running
+            lane.running = True
+        if not start:
+            return
+        if self._fwd_pool is None:
+            self._lane_run(kind, peer, lane)
         else:
-            for p in peers:
-                one(p)
+            self._pool_submit(self._fwd_pool, self._lane_run, kind, peer, lane)
+
+    def _lane_run(self, kind: str, peer: str, lane: _Lane) -> None:
+        while True:
+            with lane.lock:
+                if not lane.items:
+                    lane.running = False
+                    return
+                if kind == "route":
+                    group = [
+                        lane.items.popleft()
+                        for _ in range(min(len(lane.items), ROUTE_BATCH_MAX))
+                    ]
+                else:  # whole batches, joined up to FWD_GROUP_MAX messages
+                    group = [lane.items.popleft()]
+                    n = len(group[0][0])
+                    while lane.items and n + len(
+                        lane.items[0][0]
+                    ) <= FWD_GROUP_MAX:
+                        group.append(lane.items.popleft())
+                        n += len(group[-1][0])
+                    first = lane.seq + 1
+                    lane.seq += len(group)
+            try:
+                if kind == "route":
+                    self._ship_routes(peer, group)
+                else:
+                    self._ship_forwards(peer, first, group)
+            except Exception:  # noqa: BLE001 — a lane outlives a bad group
+                log.exception("%s lane to %s: group dropped", kind, peer)
+
+    def _lane_call(self, peer: str, send, retried: Optional[str] = None):
+        """One confirmed call of a lane, `send(patient)`. The reply of a
+        request that went out is waited for while the peer is alive by
+        `Membership` (no second send while the first may still be
+        applied); a connection
+        that broke, or a peer that cannot be reached, is tried again with
+        backoff, for as long as the peer is alive. Only `node_down` (or
+        leaving, or library mode, whose caller cannot wait) gives up:
+        RpcError."""
+        def patient() -> bool:
+            return (
+                not self._leaving  # lint: disable=LK001
+                and self.membership.is_alive(peer)
+            )
+
+        delay = 0.05
+        while True:
+            try:
+                return send(patient)
+            except RpcError:
+                if self._fwd_pool is None or not patient():
+                    raise
+                if retried is not None:
+                    self.broker.metrics.inc(retried)
+                time.sleep(delay)
+                delay = min(delay * 2.0, 1.0)
+
+    def _ship_routes(self, peer: str, ops: List[Tuple[str, str]]) -> None:
+        def send(patient):
+            if self.rpc.supported_version(peer, "route") >= 2:
+                return self.rpc.call_on(
+                    peer, "route", "apply_batch", (self.name, ops),
+                    CH_ROUTE, patient,
+                )
+            for op, filter_ in ops:  # a v1 peer: filter by filter
+                rpc_send = (
+                    self.rpc.call if T.wildcard(filter_) else self.rpc.cast
+                )
+                if op == "add":
+                    rpc_send(peer, "route", "add_route", filter_, self.name)
+                else:
+                    rpc_send(
+                        peer, "route", "delete_route", filter_, self.name
+                    )
+
+        try:
+            self._lane_call(peer, send)
+        except RpcError:
+            pass  # peer down: membership GC will reconcile
+
+    def _proto_route_apply_batch(self, node: str, ops) -> object:
+        """Inbound `route` v2: `node`'s ordered (op, filter) pairs. On a
+        live app the batch is applied in slices of the event loop, each
+        one take of the replica's lock and about ROUTE_SLICE_S long, with
+        the loop's other work (SUBSCRIBEs, publishes) between two."""
+        m = self.broker.metrics
+        m.inc("cluster.route.batches")
+        m.inc("cluster.route.ops", len(ops))
+        if self._loop is not None and not self._loop.is_closed():
+            return self._aapply_routes(node, ops)
+        with _prof.section("cluster.route.apply"):
+            self.routes.apply_batch(ops, node)
+        return len(ops)
+
+    async def _aapply_routes(self, node: str, ops) -> int:
+        i = 0
+        while i < len(ops):
+            n = self._route_slice
+            with _prof.section("cluster.route.apply") as sec:
+                self.routes.apply_batch(ops[i:i + n], node)
+            i += n
+            if sec.seconds > 2.0 * ROUTE_SLICE_S:
+                self._route_slice = max(ROUTE_SLICE_MIN, n // 2)
+            elif sec.seconds < 0.5 * ROUTE_SLICE_S:
+                self._route_slice = min(ROUTE_SLICE_MAX, n * 2)
+            await asyncio.sleep(0)
+        return len(ops)
 
     # -- mesh-shard ownership (scale-out serving) --------------------------
     def attach_mesh_slice(
@@ -746,59 +948,130 @@ class ClusterNode:
         """Forward already-locally-dispatched messages to their REMOTE
         route owners — the publish half the app's broker delegates here
         when cluster mode is on (local dispatch stays on the device batch
-        path; this adds one forward_batch per destination node).
+        path; this adds one batch per destination node).
         Returns per-message remote destination counts.
 
-        Batches carrying any QoS>0 message use a confirmed rpc.call
-        (at-least-once, matching _dispatch_dests' per-message semantics);
-        pure-QoS0 batches ride casts. In app mode the calls go through
-        the replication worker so the event loop never blocks on a peer
-        round-trip; failures count in messages.forward.failed."""
-        all_dests = self.routes.match_dests_batch([m.topic for m in msgs])
-        out = [0] * len(msgs)
-        per_node: Dict[str, List[Tuple[Message, List[str]]]] = {}
-        confirm: Dict[str, bool] = {}
-        for i, (m, dests) in enumerate(zip(msgs, all_dests)):
-            for node, filters in dests.items():
-                # a dest whose owner died reroutes to the shard's
-                # rendezvous successor; a successor that is US needs no
-                # forward (local dispatch already ran on this batch)
-                node = self._live_dest(node)
-                if node == self.name:
-                    continue
-                per_node.setdefault(node, []).append((m, filters))
-                if m.qos > 0:
-                    confirm[node] = True
-                out[i] += 1
+        Every batch goes into its destination's forward lane and leaves
+        as a confirmed call known by (this node, its incarnation, the
+        lane's sequence number): the receiver dispatches a group once and
+        answers a repeat with the first one's result. The event loop
+        never waits for a peer. With `forward_mode` "sync" (config
+        `cluster.rpc_mode`, the reference's `[rpc, mode]`) the batch's
+        confirmation is kept for the settle that takes it
+        (`take_confirms`): the publishers' PUBACKs wait for it. A batch
+        is given up only with its destination down: it then goes to the
+        shard's successor, or counts in messages.forward.failed."""
+        _prof.begin("cluster.forward.out")
+        handed = 0
+        try:
+            all_dests = self.routes.match_dests_batch(
+                [m.topic for m in msgs]
+            )
+            out = [0] * len(msgs)
+            per_node: Dict[str, List[Message]] = {}
+            for i, (m, dests) in enumerate(zip(msgs, all_dests)):
+                for node in dests:
+                    # a dest whose owner died reroutes to the shard's
+                    # rendezvous successor; a successor that is US needs
+                    # no forward (local dispatch already ran on this batch)
+                    node = self._live_dest(node)
+                    if node == self.name:
+                        continue
+                    per_node.setdefault(node, []).append(m)
+                    out[i] += 1
+            if not per_node:
+                return out
 
-        # span-context propagation is free — the `traceparent` header
-        # rides inside the pickled Message — but the hop itself is worth
-        # a span: record where each sampled trace LEFT this node
-        rec = getattr(self.broker, "spans", None)
-        if rec is not None:
+            # span-context propagation is free — the `traceparent` header
+            # rides inside the pickled Message — but the hop itself is
+            # worth a span: record where each sampled trace LEFT this node
+            rec = getattr(self.broker, "spans", None)
+            if rec is not None:
+                for node, batch in per_node.items():
+                    for m in batch:
+                        rec.forward(m, node)
+
+            handed = sum(out)
+            metrics = self.broker.metrics
+            metrics.inc("cluster.forward.batches", len(per_node))
+            metrics.inc("cluster.forward.messages", handed)
+            self._unconfirmed_add(handed)
+            confirm = (
+                _Confirm(len(per_node))
+                if self.forward_mode == "sync"
+                else None
+            )
+            now = time.perf_counter()
             for node, batch in per_node.items():
-                for m, _fs in batch:
-                    rec.forward(m, node)
+                self._lane_put("fwd", node, (batch, now, confirm))
+            if confirm is not None and not confirm.future.done():
+                if len(self._confirms) >= 64:  # nobody settles: prune
+                    self._confirms = [
+                        c for c in self._confirms if not c.future.done()
+                    ]
+                self._confirms.append(confirm)
+            return out
+        finally:
+            _prof.end(handed)
 
-        def send(node, batch):
-            if confirm.get(node) or self.forward_mode == "sync":
-                try:
-                    self.rpc.call(node, "broker", "forward_batch", batch)
-                except RpcError:
-                    self.broker.metrics.inc(
-                        "messages.forward.failed", len(batch)
-                    )
-            else:
-                self.rpc.cast(
-                    node, "broker", "forward_batch", batch, key=node
+    def take_confirms(self) -> List["concurrent.futures.Future"]:
+        """The unresolved confirmations of the forwards handed off since
+        the last take (`rpc_mode: sync`; the settle of an ingest batch
+        resolves its publishers once these are done)."""
+        if not self._confirms:
+            return []
+        taken, self._confirms = self._confirms, []
+        return [c.future for c in taken if not c.future.done()]
+
+    def _unconfirmed_add(self, n: int) -> None:
+        with self._lanes_lock:
+            self._unconfirmed += n
+            now = self._unconfirmed
+        self.broker.metrics.gauge_set("cluster.forward.unconfirmed", now)
+
+    def _ship_forwards(self, peer: str, first: int, group) -> None:
+        """One group of a forward lane: `group` is [(msgs, t_handoff,
+        confirm)], numbered `first`... in the lane's sequence."""
+        metrics = self.broker.metrics
+        n = sum(len(msgs) for msgs, _, _ in group)
+        ok = False
+        args = (self.name, self._epoch, first,
+                [msgs for msgs, _, _ in group])
+
+        def send(patient):
+            # (the version handshake is part of what is tried again: an
+            # alive peer whose loop is too busy to announce is no dead one)
+            if self.rpc.supported_version(peer, "broker") >= 2:
+                return self.rpc.call_on(
+                    peer, "broker", "forward_lane", args, CH_FORWARD, patient
+                )
+            for msgs, _, _ in group:  # a v1 peer: no sequence numbers
+                self.rpc.call(
+                    peer, "broker", "forward_batch", [(m, ()) for m in msgs]
                 )
 
-        for node, batch in per_node.items():
-            if self._fwd_pool is not None:
-                self._pool_submit(self._fwd_pool, send, node, batch)
-            else:
-                send(node, batch)
-        return out
+        try:
+            self._lane_call(peer, send, retried="cluster.forward.retries")
+            ok = True
+        except RpcError:
+            # the destination is down (or, in library mode, did not
+            # answer the one attempt): its shard's successor, else lost
+            alt = self._live_dest(peer)
+            if alt != peer and alt != self.name:
+                metrics.inc("cluster.forward.retries")
+                self._lane_put("fwd", alt, *group)
+                return
+            metrics.inc("messages.forward.failed", n)
+        except Exception:  # noqa: BLE001 — the remote dispatch raised
+            log.exception("forward to %s failed there", peer)
+            metrics.inc("messages.forward.failed", n)
+        now = time.perf_counter()
+        for _, t0, confirm in group:
+            if ok:
+                metrics.observe("cluster.forward.confirm.seconds", now - t0)
+            if confirm is not None:
+                confirm.one_done()
+        self._unconfirmed_add(-n)
 
     def _dispatch_dests(self, msg: Message, dests: Dict[str, List[str]]) -> int:
         n = 0
@@ -852,6 +1125,75 @@ class ClusterNode:
     async def _afwd(self, msgs) -> int:
         res = await self.broker.adispatch_batch_folded(msgs, forward=False)
         return sum(res)
+
+    def _proto_forward_lane(self, origin: str, epoch: str, first: int,
+                            batches) -> object:
+        """Inbound `broker` v2: the batches `first`, `first`+1, ... of
+        `origin`'s forward lane to this node. Applied exactly once: a
+        group that was applied is answered with its result, one that is
+        being applied is waited for, and neither is dispatched again
+        (`cluster.forward.duplicates`). The reply leaves after the
+        dispatch, so it is the confirmation `rpc_mode: sync` waits for."""
+        with _prof.section("cluster.forward.in") as sec:
+            msgs: List[Message] = []
+            last = first + len(batches) - 1
+            with self._fwd_in_lock:
+                st = self._fwd_in.get(origin)
+                if st is None or st["epoch"] != epoch:
+                    st = self._fwd_in[origin] = {
+                        "epoch": epoch, "done": 0, "result": 0,
+                        "running": None,
+                    }
+                running = st["running"]
+                if running is not None and running[0] == first:
+                    fut, repeat = running[1], True  # still being applied
+                elif last <= st["done"]:
+                    fut, repeat = None, True  # applied: its result
+                else:
+                    fut, repeat = concurrent.futures.Future(), False
+                    st["running"] = (first, fut)
+                    msgs = [m for b in batches for m in b]
+                result = st["result"]
+            sec.n = len(msgs)
+        on_loop = self._loop is not None and not self._loop.is_closed()
+        if repeat:
+            self.broker.metrics.inc(
+                "cluster.forward.duplicates", len(batches)
+            )
+            if fut is None:
+                return result
+            return self._await_fwd(fut) if on_loop else fut.result()
+        if on_loop:
+            return self._afwd_lane(st, last, fut, msgs)
+        try:
+            n = sum(self.broker.dispatch_batch_folded(msgs, forward=False))
+        except BaseException as e:
+            self._fwd_failed(st, fut, e)
+            raise
+        return self._fwd_done(st, last, fut, n)
+
+    @staticmethod
+    async def _await_fwd(fut) -> int:
+        return await asyncio.wrap_future(fut)
+
+    def _fwd_failed(self, st: dict, fut, exc) -> None:
+        with self._fwd_in_lock:
+            st["running"] = None  # not applied: a repeat dispatches
+        fut.set_exception(exc)
+
+    def _fwd_done(self, st: dict, last: int, fut, n: int) -> int:
+        with self._fwd_in_lock:
+            st["done"], st["result"], st["running"] = last, n, None
+        fut.set_result(n)
+        return n
+
+    async def _afwd_lane(self, st: dict, last: int, fut, msgs) -> int:
+        try:
+            n = await self._afwd(msgs)
+        except BaseException as e:
+            self._fwd_failed(st, fut, e)
+            raise
+        return self._fwd_done(st, last, fut, n)
 
     # -- channel registry (emqx_cm_registry parity) ------------------------
     def register_channel(self, client_id: str, sid: str) -> None:
@@ -1180,9 +1522,16 @@ class ClusterNode:
         s["channels.count"] = len(self._channels)
         return s
 
-    def flush(self) -> None:
+    def flush(self, timeout: float = 10.0) -> None:
         """Drain async forwards/replication (test determinism)."""
         self.rpc.flush()
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            with self._lanes_lock:
+                lanes = list(self._lanes.values())
+            if not any(lane.running or lane.items for lane in lanes):
+                return
+            time.sleep(0.005)
 
 
 def make_cluster(

@@ -12,18 +12,20 @@ Consistency split (mria parity, emqx_router.erl:111-125):
   peer to ack before returning, because a half-replicated trie edge breaks
   matching (maybe_trans, emqx_router.erl:118-121).
 
-TPU note: the internal `Router` compiles this cluster-wide filter set into
-the NFA tables, so one device kernel yields dests for a whole batch of
-publishes; bitmaps of *local* subscribers are applied on each owner node.
+The replica is matched on the host (a trie walk per topic of a batch, on
+the sending node); the device match and the bitmaps of *local* subscribers
+are applied on each owner node, where the forwarded batch rides the
+broker's own route step.
 """
 
 from __future__ import annotations
 
 import threading
 import zlib
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
-from emqx_tpu.broker.router import Router
+from emqx_tpu.broker.trie import TopicTrie
+from emqx_tpu.ops import topics as T
 
 
 class ShardOwnership:
@@ -165,33 +167,75 @@ class ShardOwnership:
 
 
 class ClusterRouteTable:
-    """One node's replica of the global route table."""
+    """One node's replica of the global route table.
 
-    def __init__(self, node: str, router: Optional[Router] = None) -> None:
+    Host-side only: exact filters are keys of `_dests` itself, wildcard
+    filters also enter a plain `TopicTrie`. (The replica never matches on
+    a device — the broker's own router does, on the receiving node — so it
+    keeps no `RouteIndex`: building one cost 85 % of every replicated
+    add.) A filter's owners are stored as one node name, or a tuple of
+    names where several nodes subscribe it: strings and tuples of strings
+    are no work for the cyclic GC, a million sets are."""
+
+    def __init__(self, node: str) -> None:
         self.node = node
-        self._router = router or Router(enable_tpu=False)
-        # filter -> nodes having >=1 local subscriber on it
-        self._dests: Dict[str, Set[str]] = {}  # guarded-by: _lock
+        self._trie = TopicTrie()
+        # filter -> owner node name | tuple of owner names
+        self._dests: Dict[str, Union[str, Tuple[str, ...]]] = {}  # guarded-by: _lock
+        self._routes = 0  # (filter, node) pairs held  guarded-by: _lock
         self._lock = threading.Lock()
 
     # -- replica writes (applied locally AND via RPC from peers) ----------
+    def _add(self, filter_: str, node: str) -> None:  # holds-lock: _lock
+        cur = self._dests.get(filter_)
+        if cur is None:
+            self._dests[filter_] = node
+            if T.wildcard(filter_):
+                self._trie.insert(filter_)
+        elif cur == node or (type(cur) is tuple and node in cur):
+            return
+        else:
+            self._dests[filter_] = (
+                cur + (node,) if type(cur) is tuple else (cur, node)
+            )
+        self._routes += 1
+
+    def _delete(self, filter_: str, node: str) -> None:  # holds-lock: _lock
+        cur = self._dests.get(filter_)
+        if cur is None:
+            return
+        if type(cur) is tuple:
+            if node not in cur:
+                return
+            rest = tuple(n for n in cur if n != node)
+            self._dests[filter_] = rest[0] if len(rest) == 1 else rest
+        elif cur == node:
+            del self._dests[filter_]
+            if T.wildcard(filter_):
+                self._trie.delete(filter_)
+        else:
+            return
+        self._routes -= 1
+
     def add_route(self, filter_: str, node: str) -> None:
         with self._lock:
-            dests = self._dests.get(filter_)
-            if dests is None:
-                dests = self._dests[filter_] = set()
-                self._router.add_route(filter_)
-            dests.add(node)
+            self._add(filter_, node)
 
     def delete_route(self, filter_: str, node: str) -> None:
         with self._lock:
-            dests = self._dests.get(filter_)
-            if dests is None:
-                return
-            dests.discard(node)
-            if not dests:
-                del self._dests[filter_]
-                self._router.delete_route(filter_)
+            self._delete(filter_, node)
+
+    def apply_batch(self, ops: Sequence[Tuple[str, str]], node: str) -> None:
+        """Ordered `(op, filter)` pairs of one origin node (`op` is "add"
+        or "delete"), under one take of the lock: the `route` v2 wire
+        form, one slice of a replicated batch."""
+        add, delete = self._add, self._delete
+        with self._lock:
+            for op, filter_ in ops:
+                if op == "add":
+                    add(filter_, node)
+                else:
+                    delete(filter_, node)
 
     def cleanup_node(self, node: str) -> int:
         """Purge all routes owned by a dead node (emqx_router_helper:135-148).
@@ -200,52 +244,69 @@ class ClusterRouteTable:
         surviving node runs the mnesia transaction; here every node purges
         its own replica, which is the equivalent end state.
         """
-        removed = 0
         with self._lock:
-            for filter_ in list(self._dests):
-                dests = self._dests[filter_]
-                if node in dests:
-                    dests.discard(node)
-                    removed += 1
-                    if not dests:
-                        del self._dests[filter_]
-                        self._router.delete_route(filter_)
-        return removed
+            before = self._routes
+            for filter_, cur in list(self._dests.items()):
+                if cur == node or (type(cur) is tuple and node in cur):
+                    self._delete(filter_, node)
+            return before - self._routes
 
     # -- bootstrap (mria replica catch-up on join) -------------------------
+    @staticmethod
+    def _nodes(cur) -> Tuple[str, ...]:
+        return cur if type(cur) is tuple else (cur,)
+
     def dump(self) -> List[tuple]:
         with self._lock:
-            return [(f, sorted(ns)) for f, ns in self._dests.items()]
+            return [
+                (f, sorted(self._nodes(ns))) for f, ns in self._dests.items()
+            ]
 
     def load(self, dump: List[tuple]) -> None:
-        for filter_, nodes in dump:
-            for n in nodes:
-                self.add_route(filter_, n)
+        with self._lock:
+            for filter_, nodes in dump:
+                for n in nodes:
+                    self._add(filter_, n)
+
+    def local_filters(self) -> List[str]:
+        """The filters this node itself owns (what a joiner pushes)."""
+        me = self.node
+        with self._lock:
+            return [
+                f for f, cur in self._dests.items()
+                if cur == me or (type(cur) is tuple and me in cur)
+            ]
 
     # -- reads -------------------------------------------------------------
+    def _match(self, topic: str) -> Dict[str, List[str]]:  # holds-lock: _lock
+        out: Dict[str, List[str]] = {}
+        dests = self._dests
+        filters = self._trie.match(topic)
+        if topic in dests and not T.wildcard(topic):
+            filters.append(topic)
+        for f in filters:
+            cur = dests.get(f)
+            if cur is None:
+                continue
+            for n in cur if type(cur) is tuple else (cur,):
+                got = out.get(n)
+                if got is None:
+                    out[n] = [f]
+                else:
+                    got.append(f)
+        return out
+
     def match_dests(self, topic: str) -> Dict[str, List[str]]:
         """topic -> {node: [matched filters]} (emqx_router:match_routes)."""
-        out: Dict[str, List[str]] = {}
         with self._lock:
-            for f in self._router.match(topic):
-                for n in self._dests.get(f, ()):
-                    out.setdefault(n, []).append(f)
-        return out
+            return self._match(topic)
 
     def match_dests_batch(
         self, topics: List[str]
     ) -> List[Dict[str, List[str]]]:
-        """Batch form: one TPU/NFA match for all topics, then dest joins."""
+        """Batch form: every topic under one take of the lock."""
         with self._lock:
-            matches = self._router.match_batch(topics)
-            out = []
-            for filters in matches:
-                d: Dict[str, List[str]] = {}
-                for f in filters:
-                    for n in self._dests.get(f, ()):
-                        d.setdefault(n, []).append(f)
-                out.append(d)
-        return out
+            return [self._match(t) for t in topics]
 
     def has_route(self, filter_: str) -> bool:
         with self._lock:
@@ -254,12 +315,16 @@ class ClusterRouteTable:
     def routes(self) -> List[tuple]:
         with self._lock:
             return [
-                (f, n) for f, ns in self._dests.items() for n in sorted(ns)
+                (f, n)
+                for f, ns in self._dests.items()
+                for n in sorted(self._nodes(ns))
             ]
 
     def stats(self) -> Dict[str, int]:
+        """Running counts: the harness polls this while a million routes
+        replicate onto the loop that answers it."""
         with self._lock:
             return {
-                "routes.count": sum(len(ns) for ns in self._dests.values()),
+                "routes.count": self._routes,
                 "topics.count": len(self._dests),
             }

@@ -106,13 +106,24 @@ class Rpc:
 
     # -- caller side (emqx_rpc.erl:22-30 parity) ---------------------------
     def call(self, peer: str, api: str, method: str, *args) -> Any:
+        return self.call_on(peer, api, method, args)
+
+    def call_on(
+        self, peer: str, api: str, method: str, args: tuple,
+        channel="", patient=None,
+    ) -> Any:
+        """`call` on a socket of the caller's choice (`channel`, see
+        tcp_transport.CH_*: a lane's traffic does not queue behind the
+        rest) and, with `patient`, waiting for a live peer's late reply
+        instead of sending the request again."""
         if peer == self.node:
             v = max(self.registry.versions(api))
             return self.registry.lookup(api, v, method)(*args)
         v = self.supported_version(peer, api)
         try:
             r = self._bus.send(
-                self.node, peer, ("rpc", "call", api, v, method, args)
+                self.node, peer, ("rpc", "call", api, v, method, args),
+                channel, patient,
             )
         except NodeUnreachable as e:
             raise RpcError(str(e)) from e

@@ -18,7 +18,16 @@ Design:
   ports identically);
 - `send` is a synchronous call with timeout -> NodeUnreachable on connect
   failure, broken pipe, or deadline; one reconnect attempt per send covers
-  peer restarts (gen_rpc {badtcp,...} -> error semantics);
+  peer restarts (gen_rpc {badtcp,...} -> error semantics). A `patient`
+  send (the forward and route-replication lanes of cluster/node.py) keeps
+  waiting for its reply while the connection stands and the caller's
+  predicate holds: on TCP a request that was written is answered unless
+  the connection dies, so a reply that is late is never a reason to send
+  the request again (and have it applied twice);
+- membership frames ride a socket of their own (`CH_MEMBERSHIP`): the
+  accepting side serves one connection's frames in order, and a heartbeat
+  queued behind a call that waits for the peer's event loop would read as
+  a dead node;
 - inbound handler runs sequentially per connection, preserving per-channel
   FIFO; replies carry either a value or a pickled exception message that
   re-raises as RemoteCallError at the caller.
@@ -42,6 +51,9 @@ Handler = Callable[[str, object], Optional[object]]
 
 _LEN = struct.Struct(">I")
 MAX_FRAME = 64 * 1024 * 1024
+# integer channel keys pick a socket outright (hash(n) == n): the default
+# key "" is socket 0
+CH_FORWARD, CH_ROUTE, CH_MEMBERSHIP = 1, 2, 3
 
 
 class RemoteCallError(Exception):
@@ -119,7 +131,9 @@ class _PeerConn:
         for ent in pending.values():
             ent[0].set()  # waiters see alive=False / no value
 
-    def call(self, payload: object, timeout: float) -> object:
+    def call(self, payload: object, timeout: float, patient=None) -> object:
+        """`patient`: a predicate; while it holds and this connection
+        stands, a reply later than `timeout` is waited for, not given up."""
         ev = threading.Event()
         ent = [ev, None, None]
         with self.lock:
@@ -131,7 +145,10 @@ class _PeerConn:
         except OSError as e:
             self.close()
             raise NodeUnreachable(f"{self.bus.node} -> {self.dst}: {e}")
-        if not ev.wait(timeout) or ent[1] is None:
+        while not ev.wait(timeout) and patient is not None:
+            if not self.alive or not patient():
+                break
+        if not ev.is_set() or ent[1] is None:
             with self.lock:
                 self._pending.pop(rid, None)
             if not self.alive:
@@ -223,9 +240,15 @@ class TcpBus:
             c.close()
 
     def send(
-        self, src: str, dst: str, payload: object, channel_key: str = ""
+        self, src: str, dst: str, payload: object, channel_key="",
+        patient=None,
     ) -> object:
         """Confirmed send with deadline + bounded retry/backoff.
+
+        `patient` (a predicate, see `_PeerConn.call`): the reply of a
+        request that went out is waited for as long as the connection
+        stands and the predicate holds, outside the deadline; connect
+        failures and broken connections still take the retry ladder.
 
         Runs on forward/replication worker threads (never the event
         loop), so the backoff sleeps are plain `time.sleep`. A breaker
@@ -251,7 +274,9 @@ class TcpBus:
         while True:
             try:
                 # fault site: an injected partition/drop exercises the
-                # same retry + dead-letter ladder as a real one
+                # same retry + dead-letter ladder as a real one; `corrupt`
+                # loses the REPLY of a request the peer has applied (the
+                # case the forward lanes' exactly-once rule exists for)
                 act = _faults.hit("cluster.forward")
                 if act == "drop":
                     raise FaultError("cluster.forward")
@@ -261,8 +286,10 @@ class TcpBus:
                         f"{self.node} -> {dst}: send deadline exceeded"
                     )
                 result = self._conn_for(dst, channel_key).call(
-                    payload, min(self.timeout, budget)
+                    payload, min(self.timeout, budget), patient
                 )
+                if act == "corrupt":
+                    raise FaultError("cluster.forward")
                 if br is not None:
                     br.record_success()
                 return result
@@ -287,8 +314,12 @@ class TcpBus:
                 delay = min(delay * 2.0, self.timeout)
 
     def cast(
-        self, src: str, dst: str, payload: object, channel_key: str = ""
+        self, src: str, dst: str, payload: object, channel_key=""
     ) -> bool:
+        if not channel_key and type(payload) is tuple and payload[:1] == (
+            "membership",
+        ):
+            channel_key = CH_MEMBERSHIP
         try:
             if _faults.hit("cluster.forward") == "drop":
                 return False  # casts are lossy by contract
@@ -298,7 +329,7 @@ class TcpBus:
             return False
 
     # -- internals ----------------------------------------------------------
-    def _conn_for(self, dst: str, channel_key: str) -> _PeerConn:
+    def _conn_for(self, dst: str, channel_key) -> _PeerConn:
         with self._lock:
             addr = self._peers.get(dst)
         if addr is None:

@@ -65,8 +65,13 @@ class LocalBus:
             )
 
     # -- send paths --------------------------------------------------------
-    def send(self, src: str, dst: str, payload: object) -> object:
-        """Synchronous request/response (gen_rpc call). Returns handler result."""
+    def send(
+        self, src: str, dst: str, payload: object, channel_key="",
+        patient=None,
+    ) -> object:
+        """Synchronous request/response (gen_rpc call). Returns handler
+        result. `channel_key` / `patient` are the TCP bus's (which socket;
+        how long to wait for a live peer): in process the call is direct."""
         with self._lock:
             handler = self._handlers.get(dst)
             cut = (min(src, dst), max(src, dst)) in self._cut

@@ -59,6 +59,12 @@ class ClusterConfig:
     send_retries: int = 2
     send_backoff_ms: float = 50.0
     send_deadline_s: float = 0.0
+    # the reference's `[rpc, mode]` (emqx_rpc.erl, emqx_broker.erl:283-288):
+    # "async" acknowledges a publish after its local dispatch, with its
+    # forwards under way; "sync" holds the PUBACK / PUBREC of a QoS>0
+    # publish until every destination node confirmed its forward, i.e.
+    # dispatched it: the pushback of a cluster (docs/scale_out.md)
+    rpc_mode: str = "async"
     # scale-out sharded serving (docs/scale_out.md): this node's slice
     # of the global subscriber-lane space, [index, total]. With
     # router.mesh_shape set, the node advertises the slice on join
@@ -879,6 +885,11 @@ def _validate(cfg: AppConfig) -> None:
         raise ConfigError("slo.alarm_threshold must be in (0, 1]")
     if cfg.cluster.send_retries < 0:
         raise ConfigError("cluster.send_retries must be >= 0")
+    if cfg.cluster.rpc_mode not in ("async", "sync"):
+        raise ConfigError(
+            f"cluster.rpc_mode must be 'async' or 'sync', "
+            f"got {cfg.cluster.rpc_mode!r}"
+        )
     ss = cfg.cluster.shard_slice
     if (
         len(ss) != 2

@@ -2141,32 +2141,47 @@ class DeviceRouter:
             launch_open=launch_open,
         )
 
+    # a launch's outputs are sliced on the device to the live rows rounded
+    # up to this fraction of the launch's bucket, and trimmed on the host
+    PULL_STEPS = 8
+
     def _pull(  # readback-site
         self, out, B, with_groups, kslot, mesh, retained, extra_retained,
         session,
     ):
         """The one coalesced `jax.device_get` of a batch's outputs, each
-        sliced to the live rows (the slices are the per-`B`
-        `dynamic_slice` programs)."""
-        pulls = {
-            "matched": out["matched"][:B],
-            "mcount": out["mcount"][:B],
-            "flags": out["flags"][:B],
-        }
+        sliced to the live rows.
+
+        A slice of static length is a program of its own, compiled at its
+        first use (~0.1 s): slicing to `B` itself met a new program for
+        every batch size a bucket ever saw, all through a run (a cluster
+        node's forwarded batches have any size). So the device slices to
+        `B` rounded up to an eighth of the bucket (at most PULL_STEPS
+        programs per bucket and output, at most an eighth more rows on the
+        link) and the host trims the rest, which costs a view."""
+        cap = out["matched"].shape[0]
+        step = max(1, cap // self.PULL_STEPS)
+        n = min(cap, -(-B // step) * step)
+        rows = ["matched", "mcount", "flags"]
         if with_groups:
-            pulls["pick_gid"] = out["pick_gid"][:B]
-            pulls["pick_idx"] = out["pick_idx"][:B]
+            rows += ["pick_gid", "pick_idx"]
         # sparse (CSR) fan-out: compact outputs exist with NO dense
         # bitmap matrix behind them — overflow rows rebuild on host
         sparse_fan = out["bitmaps"] is None and out.get("slots") is not None
         if out["bitmaps"] is not None or sparse_fan:
             if kslot:
-                pulls["slots"] = out["slots"][:B]
-                pulls["slot_count"] = out["slot_count"][:B]
+                rows += ["slots", "slot_count"]
                 if mesh:
-                    pulls["overflow"] = out["overflow"][:B]
+                    rows.append("overflow")
             else:
-                pulls["bitmaps"] = out["bitmaps"][:B]
+                rows.append("bitmaps")
+        if out.get("sem_count") is not None:
+            # the semantic winners are already unioned into `slots`;
+            # only the O(B) qualifying count crosses separately
+            rows.append("sem_count")
+        pulls = {k: out[k][:n] for k in rows}
+        if out.get("rule_masks") is not None:
+            pulls["rule_masks"] = out["rule_masks"][:, :n]
         if retained is not None:
             # the fused storm's chunk-0 match matrix rides the SAME
             # coalesced transfer as the route outputs; extra chunks
@@ -2174,12 +2189,6 @@ class DeviceRouter:
             pulls["retained"] = out["retained"]
             for j, m in enumerate(extra_retained or ()):
                 pulls[f"retained_{j + 1}"] = m
-        if out.get("sem_count") is not None:
-            # the semantic winners are already unioned into `slots`;
-            # only the O(B) qualifying count crosses separately
-            pulls["sem_count"] = out["sem_count"][:B]
-        if out.get("rule_masks") is not None:
-            pulls["rule_masks"] = out["rule_masks"][:, :B]
         if session is not None and session.sweep_k:
             # the session sweep's compact lists join the one device_get;
             # the updated table arrays themselves NEVER cross the link
@@ -2188,7 +2197,13 @@ class DeviceRouter:
             pulls["session_due_count"] = sess["due_count"]
             pulls["session_expired"] = sess["expired"]
             pulls["session_expired_count"] = sess["expired_count"]
-        return jax.device_get(pulls)
+        host = jax.device_get(pulls)
+        if n != B:
+            for k in rows:
+                host[k] = host[k][:B]
+            if "rule_masks" in host:
+                host["rule_masks"] = host["rule_masks"][:, :B]
+        return host
 
     def _readback(  # readback-site
         self, out, B, too_long, with_groups, kslot, mesh=False,

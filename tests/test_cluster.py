@@ -372,7 +372,9 @@ def test_shard_slice_survives_join_bootstrap():
         assert c.join(a.name)
         assert c.shards.owner("s0/3") == a.name
         assert c.shards.owner("s1/3") == b.name
-        # and the earlier nodes learned the late slice
+        # and the earlier nodes learned the late slice (its advertisement
+        # is an asynchronous cast: drained before the look)
+        c.flush()
         assert a.shards.owner("s2/3") == "late@cluster"
         c.rpc.stop()
     finally:

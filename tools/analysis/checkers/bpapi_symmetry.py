@@ -45,7 +45,7 @@ from tools.analysis.checkers.wire_common import (
     toplevel_assigns,
 )
 
-RPC_METHODS = frozenset({"call", "cast", "multicall"})
+RPC_METHODS = frozenset({"call", "call_on", "cast", "multicall"})
 
 # call names that put a tuple on the cluster wire
 TUPLE_BOUNDARY = frozenset({
