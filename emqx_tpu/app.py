@@ -435,6 +435,7 @@ class BrokerApp:
         from emqx_tpu.observe.alarm import AlarmManager, FallbackRateWatch
         from emqx_tpu.observe.event_message import EventMessage
         from emqx_tpu.observe.exporters import StatsdExporter
+        from emqx_tpu.observe.gc_policy import GcPolicy
         from emqx_tpu.observe.monitors import OsMon, SysMon, VmMon
         from emqx_tpu.observe.slow_subs import SlowSubs
         from emqx_tpu.observe.topic_metrics import TopicMetrics
@@ -461,6 +462,9 @@ class BrokerApp:
             else None
         )
         self.sys_mon = SysMon(self.alarms) if ob.sys_mon_enable else None
+        # the collector policy of this process: installed by `start`,
+        # driven by the housekeeping tick
+        self.gc_policy = GcPolicy(self.broker.metrics)
         self.os_mon = OsMon(self.alarms) if ob.os_mon_enable else None
         self.vm_mon = VmMon(self.alarms) if ob.vm_mon_enable else None
         self.slow_subs = SlowSubs(
@@ -981,6 +985,7 @@ class BrokerApp:
             ),
         )
         self.telemetry.start()
+        self.gc_policy.install()
         self._tasks = [
             asyncio.ensure_future(self._housekeeping()),
             asyncio.ensure_future(self._sys_heartbeat()),
@@ -1167,6 +1172,7 @@ class BrokerApp:
             self.durable_state.flush()
         if self.sys_mon is not None:
             self.sys_mon.close()
+        self.gc_policy.restore()
         if self.exhook is not None:
             self.exhook.shutdown()
         # external auth backends hold lazily-created HTTP sessions
@@ -1182,6 +1188,19 @@ class BrokerApp:
         if self.spans is not None:
             self.spans.close()  # flush the OTLP file exporter buffer
         self.trace.close()
+
+    def _heap_items(self) -> int:
+        """What the long-lived heap holds, in items: the collector policy
+        freezes when some arrived (observe/gc_policy.py)."""
+        n = (
+            self.broker.subscription_count()
+            + self.cm.channel_count()
+            + self.cm.detached_count()
+            + len(self.retainer)
+        )
+        if self.cluster_node is not None:
+            n += self.cluster_node.routes.stats()["routes.count"]
+        return n
 
     async def _housekeeping(self) -> None:
         import logging
@@ -1216,6 +1235,9 @@ class BrokerApp:
                     last_retainer_sweep = now
                 if self.sys_mon is not None:
                     self.sys_mon.check(now, self.profiler.budget)
+                self.gc_policy.tick(
+                    self._heap_items(), self.broker.released
+                )
                 if self.os_mon is not None:
                     self.os_mon.check(now)
                 if self.vm_mon is not None:

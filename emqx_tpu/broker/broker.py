@@ -135,6 +135,9 @@ class Broker:
         # RECOMPUTE sum(len(entry)) per subscribe/unsubscribe, turning a
         # million-connection subscribe storm into O(N^2) gauge upkeep
         self._plain_subs = 0
+        # subscriptions removed and sessions dropped, ever: what may have
+        # left garbage among frozen objects (observe/gc_policy.py thaws on it)
+        self.released = 0
         # $share groups mirrored as device lane segments so the kernel
         # resolves the member pick too (emqx_shared_sub.erl:234-285)
         self.grouptab = GroupTable()
@@ -287,12 +290,15 @@ class Broker:
                     # a member leaving shifts indices: re-derive the pin
                     # from the sid so it stays on the same live member
                     self.grouptab.repin(gid, g.members.keys(), g.sticky_sid)
+            if removed:
+                self.released += 1
             return removed
         entry = self._subs.get(real)
         if not entry or sid not in entry:
             return False
         sub = entry.pop(sid)
         self._plain_subs -= 1
+        self.released += 1
         if sub.slot >= 0:
             if sub.semantic and self.semantic is not None:
                 self.semantic.detach(sub.slot)
@@ -1204,5 +1210,6 @@ class Broker:
 
     def drop_session_subs(self, sid: str, filters: Sequence[str]) -> None:
         """Bulk cleanup when a session dies (emqx_broker_helper pmon parity)."""
+        self.released += 1
         for f in list(filters):
             self.unsubscribe(sid, f)

@@ -65,6 +65,31 @@ class ChannelConfig:
     enhanced_auth: Dict[str, object] = field(default_factory=dict)
 
 
+class _ClosedSink:
+    """What a channel's `sink` is once its transport has closed: every
+    send is dropped, as the closed transport dropped it."""
+
+    _closing = True
+
+    def send_packet(self, p) -> None:
+        pass
+
+    def send_bytes(self, b: bytes) -> None:
+        pass
+
+    def send_segments(self, segs) -> None:
+        pass
+
+    def flush(self) -> None:
+        pass
+
+    def close(self, reason: str) -> None:
+        pass
+
+
+_CLOSED_SINK = _ClosedSink()
+
+
 class Channel:
     def __init__(
         self,
@@ -131,6 +156,13 @@ class Channel:
             self._send(pkt.Disconnect(reason_code=rc))
         self.disconnect_reason = reason
         self.sink.close(reason)
+
+    def release_sink(self) -> None:
+        """The transport is closed for good. The channel may outlive it
+        (a detached session's deliverers hold the channel), the transport
+        must not: channel <-> sink is a cycle, and a cycle among frozen
+        objects is reclaimed by a thaw pass alone (observe/gc_policy.py)."""
+        self.sink = _CLOSED_SINK
 
     def client_info(self) -> Dict:
         return {

@@ -144,6 +144,7 @@ class ChannelManager:
         if ent is not None:
             sess, _ = ent
             self.broker.drop_session_subs(cid, list(sess.subscriptions))
+            sess.on_dropped = None  # as in on_channel_closed
             if self.session_store is not None:
                 self.session_store.drop_session(cid)
             self.broker.hooks.run("session.discarded", cid)
@@ -176,6 +177,11 @@ class ChannelManager:
             self.broker.hooks.run("session.detached", cid)
         else:
             self.broker.drop_session_subs(cid, list(sess.subscriptions))
+            # the session ends here: its callback into the channel is the
+            # back edge of a cycle (channel -> session -> channel), and a
+            # cycle among frozen objects is reclaimed by a thaw pass alone
+            # (observe/gc_policy.py). Cut, both die with their last reference
+            sess.on_dropped = None
             if store is not None:
                 store.drop_session(cid)
             self.broker.hooks.run("session.terminated", cid, reason)

@@ -870,3 +870,13 @@ declare("owner.gc.pause.seconds", HISTOGRAM,
 declare("owner.gc.gen2.seconds", HISTOGRAM,
         "full (generation 2) GC passes of the owner process",
         unit="seconds")
+declare("owner.gc.freezes", COUNTER,
+        "times the collector policy moved what arrived out of the "
+        "generations (observe/gc_policy.py: growth of the table or the "
+        "session set, seen by the housekeeping tick)")
+declare("owner.gc.thaws", COUNTER,
+        "thaw passes of the collector policy (unfreeze, full collection, "
+        "freeze): releases passed a quarter of the frozen items")
+declare("owner.gc.frozen.objects", GAUGE,
+        "objects the collector policy holds outside the generations, as "
+        "counted when they were frozen (set at every tick)")

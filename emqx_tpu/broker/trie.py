@@ -116,37 +116,43 @@ class TopicTrie:
 
     def match(self, topic: str) -> List[str]:
         """All stored filters matching `topic` (exact filters included)."""
-        ws = T.words(topic)
         acc: List[str] = []
-        dollar = topic.startswith("$")
+        # `_walk` is a method and not a closure over `ws` / `acc`: a nested
+        # function that calls itself is a reference cycle (function -> cell
+        # -> function), eight tracked objects only the collector reclaims,
+        # once per match: a cluster's sender matches every message here
+        self._walk(
+            self._root, 0, [], True, T.words(topic), topic.startswith("$"), acc
+        )
+        return acc
 
-        def walk(node: _Node, i: int, prefix: List[str], root_level: bool) -> None:
-            if i == len(ws):
-                if node.terminal:
-                    acc.append("/".join(prefix))
-                hchild = node.children.get("#")
-                if hchild is not None and hchild.terminal and not (root_level and dollar):
-                    acc.append("/".join(prefix + ["#"]))
-                return
+    def _walk(
+        self, node: _Node, i: int, prefix: List[str], root_level: bool,
+        ws: List[str], dollar: bool, acc: List[str],
+    ) -> None:
+        if i == len(ws):
+            if node.terminal:
+                acc.append("/".join(prefix))
             hchild = node.children.get("#")
             if hchild is not None and hchild.terminal and not (root_level and dollar):
                 acc.append("/".join(prefix + ["#"]))
-            w = ws[i]
-            # children named '+'/'#' are wildcard branches, not literals: a
-            # literal '+'/'#' character in a (malformed) topic must not take
-            # them as an exact-word step (the reference cannot confuse the
-            # two: its wildcard branch keys are atoms, topic words binaries)
-            lit = node.children.get(w) if w not in ("+", "#") else None
-            if lit is not None:
-                prefix.append(w)
-                walk(lit, i + 1, prefix, False)
+            return
+        hchild = node.children.get("#")
+        if hchild is not None and hchild.terminal and not (root_level and dollar):
+            acc.append("/".join(prefix + ["#"]))
+        w = ws[i]
+        # children named '+'/'#' are wildcard branches, not literals: a
+        # literal '+'/'#' character in a (malformed) topic must not take
+        # them as an exact-word step (the reference cannot confuse the
+        # two: its wildcard branch keys are atoms, topic words binaries)
+        lit = node.children.get(w) if w not in ("+", "#") else None
+        if lit is not None:
+            prefix.append(w)
+            self._walk(lit, i + 1, prefix, False, ws, dollar, acc)
+            prefix.pop()
+        if not (root_level and dollar):
+            plus = node.children.get("+")
+            if plus is not None:
+                prefix.append("+")
+                self._walk(plus, i + 1, prefix, False, ws, dollar, acc)
                 prefix.pop()
-            if not (root_level and dollar):
-                plus = node.children.get("+")
-                if plus is not None:
-                    prefix.append("+")
-                    walk(plus, i + 1, prefix, False)
-                    prefix.pop()
-
-        walk(self._root, 0, [], True)
-        return acc

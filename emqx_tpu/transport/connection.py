@@ -231,7 +231,10 @@ class Connection:
                 await self.writer.wait_closed()
             except Exception:
                 pass
-            await self.channel.on_sock_closed()
+            try:
+                await self.channel.on_sock_closed()
+            finally:
+                self.channel.release_sink()
 
     async def _limited(self, type_: str, n: float) -> None:
         """Charge the limiter and pause for the returned interval.
