@@ -42,8 +42,8 @@ _dispatch_pool_inst = None
 def dispatch_pool():
     """Process-wide executor for device route launches (one device per
     process). BOUNDED and dedicated: the default asyncio executor is
-    shared with every other run_in_executor caller (config writes, DNS,
-    bench driver plumbing), so device launches could queue behind
+    shared with every other run_in_executor caller (config writes,
+    DNS), so device launches could queue behind
     unrelated blocking work — and an unbounded shared queue is exactly
     the backlog shape the r02/r04 bench notes flagged. Two workers are
     the double-buffer: batch N+1's tokenize/launch phase runs on the

@@ -350,8 +350,8 @@ class BatchIngest:
         )
         for lane, lats in enumerate(lane_lats):
             if lats:
-                # per-lane tails: the chaos/bench gates assert the
-                # control lane stays bounded while the low lane storms
+                # per-lane tails: the chaos tests assert the control
+                # lane stays bounded while the low lane storms
                 self.metrics.observe_many(LANE_SETTLE_SERIES[lane], lats)
         if rec is not None and bsp is not None:
             rec.finish(bsp)

@@ -495,25 +495,6 @@ declare("ingest.launch.errors", COUNTER,
 declare("ingest.dispatch.errors", COUNTER,
         "batch dispatches that raised at settle time")
 
-declare("matcher.rows", COUNTER, "topic rows offered to TpuMatcher")
-declare("matcher.batch.size", HISTOGRAM, buckets=SIZE_BUCKETS)
-declare("matcher.device.seconds", HISTOGRAM,
-        "TpuMatcher device match wall time (launch + readback)",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("matcher.sync.seconds", HISTOGRAM,
-        "DeviceDeltaSync upload time (full or delta)",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("matcher.fallback.rows", COUNTER,
-        "rows flagged to the CPU trie (any cause)")
-declare("matcher.fallback.rows.too_deep", COUNTER,
-        "rows whose topic exceeds MatcherConfig.max_levels")
-declare("matcher.fallback.rows.frontier_overflow", COUNTER,
-        "rows whose NFA frontier overflowed MatcherConfig.frontier")
-declare("matcher.fallback.rows.match_overflow", COUNTER,
-        "rows with more matches than MatcherConfig.max_matches")
-declare("matcher.fallback.rows.too_long", COUNTER,
-        "rows whose topic exceeds MatcherConfig.max_bytes")
-
 declare("router.batch.size", HISTOGRAM,
         "topic rows per serving-path device batch", buckets=SIZE_BUCKETS)
 declare("router.device.seconds", HISTOGRAM,

@@ -9,15 +9,14 @@ everything host-side around it:
   ``POST /api/v5/semantic/filters`` (mgmt/api.py); per-message query
   embeddings ride PUBLISH user properties the same way, with
   ``msg.headers["semantic_embedding"]`` as the copy-free internal path
-  (bench drivers, bridges);
+  (bridges);
 - **binding**: an entry binds to the subscription's fan-out SLOT
   (`Broker._slot_subs`) and optionally its topic-filter fid — semantic
   hits come back from the device as ordinary slot recipients, so
   dispatch needs zero new fan-out machinery;
 - **host twin** (`host_route`): the authoritative numpy evaluator —
   the degrade target for CPU-fallback batches and single-message
-  paths, and the reference the differential tests (and the
-  `semantic_vs_host_filter_x` bench headline) compare against.
+  paths, and the reference the differential tests compare against.
 
 Delivery semantics: a subscription WITH an embedding filter delivers
 when its topic scope matches AND similarity clears the threshold

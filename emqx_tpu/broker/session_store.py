@@ -182,41 +182,6 @@ class SessionStore:
     def slot_of(self, client_id: str) -> Optional[int]:
         return self._slots.get(client_id)
 
-    def bulk_attach(self, client_ids) -> np.ndarray:
-        """Vectorized slot registration for mass loads (bench/restore
-        tooling): appends fresh slots in one pass (free list untouched)."""
-        base = len(self._slot_cid)
-        new = [c for c in client_ids if c not in self._slots]
-        self._slots.update({c: base + i for i, c in enumerate(new)})
-        self._slot_cid.extend(new)
-        if self.metrics is not None:
-            self.metrics.gauge_set(
-                "session.store.sessions", len(self._slots)
-            )
-        return np.asarray(
-            [self._slots[c] for c in client_ids], np.int64
-        )
-
-    def bulk_load(self, client_ids, msgs, pids=None) -> np.ndarray:
-        """Mass inflight load (the session_storm bench's build phase):
-        one QoS1 publish-phase row per client, placed vectorized with
-        ONE epoch bump. Returns the placed row ids."""
-        slots = self.bulk_attach(client_ids)
-        mids = np.asarray([self._put_msg(m) for m in msgs], np.int64)
-        n = len(slots)
-        pids = (
-            np.asarray(pids, np.int64)
-            if pids is not None
-            else np.ones(n, np.int64)
-        )
-        now = self.now_ds()
-        rows = self.table.bulk_insert(
-            slots, pids, np.full(n, ST_PUBLISH, np.int64),
-            np.full(n, now, np.int64), mids,
-        )
-        self._gauges()
-        return rows
-
     def make_inflight(self, slot: int, max_size: int) -> StoreInflight:
         return StoreInflight(self, slot, max_size)
 

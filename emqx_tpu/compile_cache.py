@@ -3,7 +3,7 @@
 Every server start compiles the route step once per pow2 ingest bucket
 and per program variant; without a persistent cache each start pays all
 of it again. One rule, applied by every process that owns a device
-(`python -m emqx_tpu`, bench.py's children): if the operator placed the
+(`python -m emqx_tpu`): if the operator placed the
 cache with ``JAX_COMPILATION_CACHE_DIR``, jax reads that variable itself
 and nothing is set in code; otherwise the cache sits at ONE fixed path
 inside the checkout. The directory is part of the cache key, so a path

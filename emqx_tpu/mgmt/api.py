@@ -422,22 +422,6 @@ class MgmtApi:
                 if slo is not None
                 else None
             ),
-            "matcher": {
-                "device_ms": hist("matcher.device.seconds", 1e3),
-                "sync_ms": hist("matcher.sync.seconds", 1e3),
-                "batch_size": hist("matcher.batch.size"),
-                "rows": m.get("matcher.rows"),
-                "fallback_rows": m.get("matcher.fallback.rows"),
-                "fallback_by_cause": {
-                    cause: m.get(f"matcher.fallback.rows.{cause}")
-                    for cause in (
-                        "too_deep",
-                        "frontier_overflow",
-                        "match_overflow",
-                        "too_long",
-                    )
-                },
-            },
             "router": {
                 "device_ms": hist("router.device.seconds", 1e3),
                 "sync_ms": hist("router.sync.seconds", 1e3),

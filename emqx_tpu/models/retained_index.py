@@ -242,7 +242,7 @@ class DeviceRetainedIndex:
         return True
 
     def bulk_add(self, topics: List[str]) -> int:
-        """Vectorized initial load (restore / bench); returns count added.
+        """Vectorized initial load (restore); returns count added.
         Topics must fit the device budget (raises otherwise — callers
         pre-filter, the same contract `add` enforces per topic)."""
         from emqx_tpu.ops.tokenizer import encode_topics

@@ -32,7 +32,6 @@ from emqx_tpu.observe import faults as _faults
 from emqx_tpu.observe import profiler as _prof
 from emqx_tpu.ops.contract import device_contract
 from emqx_tpu.ops.csr_table import CsrSegmentOwner, CsrTable, sparse_fanout_slots
-from emqx_tpu.ops.matcher import batch_match_bytes_impl
 from emqx_tpu.ops.nfa import _next_pow2
 from emqx_tpu.ops.semantic_table import (
     SemanticSegmentOwner,
@@ -127,101 +126,6 @@ def compact_fanout_slots(bitmaps, kslot: int):
         slots, _ = _compact(cand, kslot)
         count = jnp.sum(popcount32(bitmaps).astype(jnp.int32), axis=1)
         return slots, count, count > kslot
-
-
-def route_step_impl(
-    tables: Dict,
-    sub_bitmaps,
-    bytes_mat,
-    lengths,
-    *,
-    salt: int,
-    max_levels: int = 16,
-    frontier: int = 32,
-    max_matches: int = 64,
-    probes: int = 8,
-    kslot: int = 0,
-    kg: int = 0,
-):
-    """Full forward step: tokenize + match + fanout + stats. Jittable.
-
-    Returns dict with matched [B,K], mcount [B], flags [B], bitmaps [B,W],
-    stats {routed, matches, fanout_bits}. With ``kslot > 0`` the output
-    additionally carries the sparse fan-out compaction
-    (`compact_fanout_slots`): slots [B, kslot], slot_count [B],
-    overflow [B].
-
-    ``sub_bitmaps`` may instead be a CSR table dict (ops/csr_table.py
-    array set): the fan-out half then runs `sparse_fanout_slots` —
-    memory O(total subscriptions) instead of O(Fcap * W) — emitting the
-    same compact contract directly (``kg`` bounds the gather window;
-    0 = 2 * kslot). The dense trace is unchanged either way.
-    """
-    # cause breakdown is unused on this path (XLA dead-code-eliminates it);
-    # the serving path folds all causes into one fallback flag per row
-    matched, mcount, flags, _causes = batch_match_bytes_impl(
-        tables,
-        bytes_mat,
-        lengths,
-        salt=salt,
-        max_levels=max_levels,
-        frontier=frontier,
-        max_matches=max_matches,
-        probes=probes,
-    )
-    if isinstance(sub_bitmaps, dict):  # CSR representation
-        slots, scount, sovf, live = sparse_fanout_slots(
-            sub_bitmaps, matched, kslot=kslot, kg=kg
-        )
-        stats = {
-            "routed": jnp.sum((mcount > 0).astype(jnp.int32)),
-            "matches": jnp.sum(mcount),
-            "fanout_bits": jnp.sum(live),
-        }
-        return {
-            "matched": matched,
-            "mcount": mcount,
-            "flags": flags,
-            "bitmaps": None,
-            "stats": stats,
-            "slots": slots,
-            "slot_count": scount,
-            "overflow": sovf,
-        }
-    bitmaps = fanout_bitmaps(sub_bitmaps, matched)
-    stats = {
-        "routed": jnp.sum((mcount > 0).astype(jnp.int32)),
-        "matches": jnp.sum(mcount),
-        "fanout_bits": jnp.sum(popcount32(bitmaps).astype(jnp.int32)),
-    }
-    out = {
-        "matched": matched,
-        "mcount": mcount,
-        "flags": flags,
-        "bitmaps": bitmaps,
-        "stats": stats,
-    }
-    if kslot > 0:
-        slots, scount, sovf = compact_fanout_slots(bitmaps, kslot)
-        out["slots"] = slots
-        out["slot_count"] = scount
-        out["overflow"] = sovf
-    return out
-
-
-route_step = device_contract(
-    "route_step",
-    # single-device program: no collectives may appear, and the compact
-    # outputs stay O(B*kslot) regardless of bitmap width
-    collectives=(),
-    out_bounds={
-        "slots": lambda cfg: cfg["B"] * cfg["kslot"] * 4,
-        "slot_count": lambda cfg: cfg["B"] * 4,
-    },
-)(partial(jax.jit, static_argnames=(
-    "salt", "max_levels", "frontier", "max_matches", "probes", "kslot",
-    "kg",
-))(route_step_impl))
 
 
 def shape_route_step_impl(
@@ -1702,10 +1606,9 @@ class DeviceRouter:
     def _trim_jit_cache(self) -> None:
         """Bound the serving jits' compiled-program caches: every table
         growth / config transition compiles a fresh program keyed on the
-        new shapes, and a long-lived process (bench sweeps every config
-        in ONE process now) must not accumulate every program it ever
-        served. Runs only on dirty prepares — the clean path never
-        recompiles."""
+        new shapes, and a long-lived broker must not accumulate every
+        program it ever served. Runs only on dirty prepares — the clean
+        path never recompiles."""
         lim = getattr(self.config, "jit_cache_max", 0)
         if lim <= 0:
             return
@@ -1713,7 +1616,6 @@ class DeviceRouter:
             shape_route_step,
             shape_route_step_donated,
             fused_route_retained_step,
-            route_step,
         ):
             try:
                 size = fn._cache_size()

@@ -14,8 +14,7 @@ per-field `struct.pack`, bytearray growth. Two batched shapes replace it
   slab view when available). Frame i is `memoryview(slab)[offs[i]:
   offs[i+1]]` — callers hand the views to `writelines`-style sinks
   without ever joining. This is the session-store redelivery flood's
-  serializer (`SessionStore._redeliver` -> `Channel._store_resend_batch`)
-  and the bench's codec-path microbench subject.
+  serializer (`SessionStore._redeliver` -> `Channel._store_resend_batch`).
 
 - `split_publish`: ONE message fanned to many targets whose frames
   differ only in the 2-byte packet id: returns (head, tail) so each

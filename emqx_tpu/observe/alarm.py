@@ -125,7 +125,7 @@ class FallbackRateWatch:
     the CPU trie (frontier/match caps too small for the live workload, or
     topics deeper/longer than the compiled budgets) — the broker still
     answers correctly, but at per-message CPU cost. This watch reads the
-    flight-recorder counters (broker serving path + TpuMatcher), computes
+    flight-recorder counters of the broker serving path, computes
     the fallback rate over a sliding window, and (de)activates one alarm
     against the configured threshold.
 
@@ -154,14 +154,8 @@ class FallbackRateWatch:
 
     def _totals(self) -> tuple:
         m = self.metrics
-        fallback = m.get("messages.routed.device_fallback") + m.get(
-            "matcher.fallback.rows"
-        )
-        total = (
-            m.get("messages.routed.device")
-            + m.get("messages.routed.device_fallback")
-            + m.get("matcher.rows")
-        )
+        fallback = m.get("messages.routed.device_fallback")
+        total = m.get("messages.routed.device") + fallback
         return fallback, total
 
     def check(self, now: Optional[float] = None) -> Optional[float]:

@@ -5,7 +5,7 @@ this module OBSERVES them on the live broker. Three signals, all polled
 from the housekeeping tick (`DeviceWatch.poll`):
 
 - **compiles vs cache hits**: every `@device_contract`-registered jit
-  entry point (route_step, shape_route_step, the mesh step builders)
+  entry point (shape_route_step, the mesh step builders)
   exposes its jit cache size; the summed size is the
   `device.compile.cache_size` gauge and its growth is a compile. A
   process-wide `jax.monitoring` duration listener additionally captures
@@ -30,8 +30,8 @@ from the housekeeping tick (`DeviceWatch.poll`):
   it has one, else the running maximum of the live bytes.
 
 - **transfer accounting** (`device.transfer.bytes` counter): cumulative
-  device->host readback bytes, incremented at the two readback sites
-  (DeviceRouter._readback, TpuMatcher.match_batch) next to the per-batch
+  device->host readback bytes, incremented at the readback site
+  (DeviceRouter._readback) next to the per-batch
   `dispatch.readback.bytes` histogram. The counter's RATE is the
   sustained link bandwidth the broker consumes.
 """

@@ -4,8 +4,8 @@ PR 6 made the device-resident pipeline fast; every fast path it added is
 also a new way to die — a failed `tpu-dispatch` launch, a torn
 delta-sync, a wedged readback, a dropped cluster forward. This module
 makes those failures *injectable* so the degradation ladder
-(broker/degrade.py) is proven by tests and chaos soaks
-(`bench.py chaos_soak`), not by production incidents.
+(broker/degrade.py) is proven by tests (`tests/test_degrade.py`), not
+by production incidents.
 
 Model: a registry of named fault SITES, each a single `faults.hit(site)`
 call on the real code path. A site with no armed rule costs ONE dict

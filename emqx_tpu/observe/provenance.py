@@ -5,13 +5,10 @@ measurement names the device it ran on. One dict — platform, device
 kind/count, host cores, jax/jaxlib versions, git sha, clock source —
 computed once per process and stamped into
 
-- every per-config JSON line bench.py's children emit,
 - the management REST hotpath summary (`profile.provenance`),
 - span resource attributes (observe/spans.py OTLP envelope),
 
 with ``proxy: true`` whenever the detected platform is not a TPU.
-`tools/bench_trend.py` groups runs by `fingerprint_key()` and refuses
-cross-fingerprint comparisons.
 
 jax is imported lazily inside `fingerprint()`: importing this module
 opens no backend, and only a process that owns the device calls it.
@@ -25,10 +22,10 @@ import subprocess
 import time
 from typing import Any, Dict, Optional
 
-# the fields two runs must share to be COMPARABLE (bench_trend's
-# grouping key). git sha is deliberately excluded — comparing across
-# commits on the same hardware is the whole point of a trend report —
-# and so is the clock source (informational, not a perf axis).
+# the fields two runs must share to be COMPARABLE (`fingerprint_key`).
+# git sha is deliberately excluded — comparing across commits on the
+# same hardware is the whole point — and so is the clock source
+# (informational, not a perf axis).
 KEY_FIELDS = (
     "platform",
     "device_kind",
@@ -82,9 +79,8 @@ def fingerprint(refresh: bool = False) -> Dict[str, Any]:
     """The process-wide hardware fingerprint (computed once, cached).
 
     Returns a fresh dict each call (callers stamp it into JSON docs they
-    then mutate). ``proxy`` is True on any non-TPU backend — the flag
-    bench.py threads into every emitter so dashboards and the trend
-    gate can refuse to headline a CPU number.
+    then mutate). ``proxy`` is True on any non-TPU backend, so that a
+    dashboard can refuse to headline a CPU number.
     """
     global _CACHE
     if _CACHE is None or refresh:
@@ -119,20 +115,10 @@ def is_proxy() -> bool:
 
 def fingerprint_key(fp: Optional[Dict[str, Any]] = None) -> str:
     """Stable comparability key over KEY_FIELDS. Two runs with different
-    keys must never be compared (bench_trend rejects the pair)."""
+    keys must never be compared."""
     if fp is None:
         fp = fingerprint()
     return "|".join(str(fp.get(k, "")) for k in KEY_FIELDS)
-
-
-def stamp(doc: Dict[str, Any]) -> Dict[str, Any]:
-    """Stamp a JSON-bound dict in place: fingerprint + top-level proxy
-    flag (the flag rides at top level so a grep of any BENCH JSON
-    answers "is this a number of record?" without walking the nest)."""
-    fp = fingerprint()
-    doc["fingerprint"] = fp
-    doc["proxy"] = bool(fp["proxy"])
-    return doc
 
 
 def resource_attrs() -> Dict[str, Any]:

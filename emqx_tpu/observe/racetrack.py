@@ -5,8 +5,8 @@ The CX checker (tools/analysis) proves cross-context discipline
 statically; this module catches what static analysis cannot see —
 container mutations, discipline that holds the wrong lock, annotations
 that lie at runtime. It is the dynamic half of the PR 8 concurrency rig,
-armed in the `race`-marked test suite and under `bench.py chaos_soak`,
-never in production steady state.
+armed in the `race`-marked test suite, never in production steady
+state.
 
 Model (Eraser refined with vector clocks, FastTrack-lite):
 

@@ -206,8 +206,8 @@ class CsrTable:
     # so its size is a compute knob, not just memory
     HOT_ABSORB_MAX = 1 << 17
     # serve-time absorb bound (`maybe_absorb`, called from the dirty
-    # prepare): a subscribe storm with no background compactor (bench
-    # drivers, embedded brokers) must not hand the kernel a 100k-entry
+    # prepare): a subscribe storm with no background compactor
+    # (embedded brokers) must not hand the kernel a 100k-entry
     # hot scan — past this, the prepare folds hot into packed once
     # (epoch bump) before snapshotting. The background compactor keeps
     # hot far below this on a live broker.

@@ -25,20 +25,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 
-from emqx_tpu.ops import tokenizer as tok
 from emqx_tpu.ops.nfa import (
     EDGE_H_MUL_NODE,
     EDGE_H_MUL_SYM,
     EDGE_H_SHIFT,
     MAX_PROBES,
-    NfaBuilder,
-    NfaTables,
-    _next_pow2,
 )
 
 
@@ -48,7 +42,7 @@ class MatcherConfig:
     frontier: int = 32  # max simultaneous NFA states per topic
     max_matches: int = 64  # max matched filters per topic
     # open-addressing probe bound; must cover the build-time bound
-    # (nfa.MAX_PROBES) or lookups would silently miss — TpuMatcher clamps.
+    # (nfa.MAX_PROBES) or lookups would silently miss — DeviceRouter clamps.
     probes: int = MAX_PROBES
     max_bytes: int = 256  # topic byte budget for the device tokenizer
     # sparse fan-out compaction (router_model.compact_fanout_slots):
@@ -237,43 +231,6 @@ def batch_match_syms(
     return matched, jnp.minimum(mcount, K), flags, causes
 
 
-def batch_match_bytes_impl(
-    tables,
-    bytes_mat,
-    lengths,
-    *,
-    salt: int,
-    max_levels: int = 16,
-    frontier: int = 32,
-    max_matches: int = 64,
-    probes: int = 8,
-):
-    """Fused full-device pipeline: tokenize + vocab lookup + NFA match."""
-    h1, h2, nwords, dollar = tok.tokenize_device(
-        bytes_mat, lengths, salt, max_levels
-    )
-    syms = tok.vocab_lookup_device(tables, h1, h2, probes)
-    return batch_match_syms(
-        tables,
-        syms,
-        nwords,
-        dollar,
-        frontier=frontier,
-        max_matches=max_matches,
-        probes=probes,
-    )
-
-
-batch_match_bytes = partial(
-    jax.jit,
-    static_argnames=("salt", "max_levels", "frontier", "max_matches", "probes"),
-)(batch_match_bytes_impl)
-
-
-def _pad_pow2(n: int, lo: int = 256) -> int:
-    return max(lo, _next_pow2(n))
-
-
 class MatchError(RuntimeError):
     """Per-row match failure marker (returned, never raised mid-batch).
 
@@ -291,149 +248,3 @@ class MatchError(RuntimeError):
         )
         self.topic = topic
         self.cause = cause
-
-
-class TpuMatcher:
-    """Host-facing wrapper: owns packed tables on device, pads batches,
-    decodes matches back to filter names, and falls back to a caller-provided
-    exact matcher for flagged rows.
-
-    Records the hot-path flight-recorder series (`matcher.*`, see
-    docs/observability.md): device match wall time, batch size, delta-sync
-    upload time, and fallback-flagged row counts broken down by cause."""
-
-    def __init__(
-        self,
-        builder: NfaBuilder,
-        config: MatcherConfig = MatcherConfig(),
-        metrics=None,
-        mesh=None,
-    ):
-        """`mesh`: a ('dp','tp') jax Mesh — the NFA table mirror then
-        uploads through the segment manager with the canonical
-        replicated NamedSharding (parallel/mesh.table_placement), the
-        same placement-hook path every other table owner uses, so churn
-        stays O(delta) scatters on the mesh too."""
-        from emqx_tpu.broker.metrics import default_metrics
-        from emqx_tpu.ops.nfa import DeviceDeltaSync
-
-        self.builder = builder
-        if config.probes < MAX_PROBES:
-            import dataclasses
-
-            config = dataclasses.replace(config, probes=MAX_PROBES)
-        self.config = config
-        self.metrics = metrics if metrics is not None else default_metrics
-        if mesh is not None:
-            from emqx_tpu.parallel.mesh import table_placement
-
-            self._sync = DeviceDeltaSync(
-                placement=table_placement(mesh), name="nfa"
-            )
-        else:
-            self._sync = DeviceDeltaSync()
-        self._salt = 0
-
-    def _tables(self):
-        # delta-overlay sync: subscription churn reaches the device as
-        # scatters, not full re-uploads (see nfa.DeviceDeltaSync)
-        import time
-
-        self._salt = self.builder.salt
-        t0 = time.perf_counter()
-        tables = self._sync.sync(self.builder)
-        self.metrics.observe(
-            "matcher.sync.seconds", time.perf_counter() - t0
-        )
-        return tables
-
-    def match_batch(  # readback-site
-        self, topics: Sequence[str], fallback=None
-    ) -> List[List[str]]:
-        """Match a batch of topic strings -> list of matched filter names.
-
-        `fallback(topic) -> list[str]` handles rows the device flags
-        (too deep / overflow). With no fallback a flagged row yields a
-        `MatchError` IN ITS SLOT (per-row error contract) — the rest of
-        the batch still returns; one pathological topic cannot poison
-        the device work already done for its batchmates.
-        """
-        import jax
-        import time
-
-        cfg = self.config
-        tables = self._tables()
-        B = len(topics)
-        Bp = _pad_pow2(B, 64)
-        mat, lens, too_long = tok.encode_topics(list(topics), cfg.max_bytes)
-        if Bp != B:
-            mat = np.pad(mat, ((0, Bp - B), (0, 0)))
-            lens = np.pad(lens, (0, Bp - B))
-        t0 = time.perf_counter()
-        matched, mcount, flags, causes = batch_match_bytes(
-            tables,
-            mat,
-            lens,
-            salt=self._salt,
-            max_levels=cfg.max_levels,
-            frontier=cfg.frontier,
-            max_matches=cfg.max_matches,
-            probes=cfg.probes,
-        )
-        # ONE coalesced device->host transfer for everything the batch
-        # and its flight recorder need; per-array `asarray` pulls each
-        # paid their own sync + RTT (8 transfers on a flagged batch)
-        host = jax.device_get({
-            "matched": matched[:B],
-            "mcount": mcount[:B],
-            "flags": flags[:B],
-            "causes": {k: v[:B] for k, v in causes.items()},
-        })
-        matched, mcount = host["matched"], host["mcount"]
-        flags = host["flags"] | too_long
-        # cumulative link-bandwidth accounting (observe/device_watch.py)
-        self.metrics.inc(
-            "device.transfer.bytes",
-            sum(v.nbytes for v in (matched, mcount, host["flags"]))
-            + sum(v.nbytes for v in host["causes"].values()),
-        )
-        self._record(
-            B, time.perf_counter() - t0, flags, host["causes"], too_long
-        )
-        out: List[List[str]] = []
-        for i in range(B):
-            if flags[i]:
-                if fallback is None:
-                    out.append(MatchError(topics[i]))
-                else:
-                    out.append(fallback(topics[i]))
-            else:
-                names = []
-                for fid in matched[i, : mcount[i]]:
-                    name = self.builder.filter_name(int(fid))
-                    if name is not None:
-                        names.append(name)
-                out.append(names)
-        return out
-
-    def _record(self, B, wall_s, flags, causes, too_long) -> None:
-        """Flight-recorder write-back for one matched batch. `causes`
-        arrives as HOST arrays (already row-sliced) — the single
-        coalesced readback in `match_batch` covers them."""
-        m = self.metrics
-        m.observe("matcher.device.seconds", wall_s)
-        m.observe("matcher.batch.size", B)
-        m.inc("matcher.rows", B)
-        fell = int(np.count_nonzero(flags))
-        if not fell:
-            return
-        m.inc("matcher.fallback.rows", fell)
-        # causes are independent bits: one row can be both too deep and
-        # frontier-overflowed; the per-cause counters count each bit
-        for cause, arr in causes.items():
-            n = int(np.count_nonzero(arr))
-            if n:
-                m.inc(f"matcher.fallback.rows.{cause}", n)
-        n_long = int(np.count_nonzero(too_long))
-        if n_long:
-            m.inc("matcher.fallback.rows.too_long", n_long)

@@ -460,7 +460,7 @@ class SegmentCompactor:
                 )
 
     def compact_now(self, owner) -> bool:
-        """Synchronous cycle (tests / bench): begin+build+apply+offer on
+        """Synchronous cycle (tests): begin+build+apply+offer on
         the calling thread. Returns False when the cycle aborted."""
         cap = owner.begin()
         built = owner.build(cap)

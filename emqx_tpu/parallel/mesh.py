@@ -19,7 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from emqx_tpu.models.router_model import (
     compact_fanout_slots,
-    route_step_impl,
     shape_route_step_impl,
 )
 from emqx_tpu.ops.contract import device_contract
@@ -141,89 +140,6 @@ def _reduce_stats(out, with_groups: bool = False):
         out.pop("pick_gid", None)
         out.pop("pick_idx", None)
     return out
-
-
-@device_contract(
-    "dist_step",
-    kind="builder",
-    # the ONLY cross-chip traffic the NFA serving step may compile to:
-    # the stats psums over ('dp','tp'). A new collective here is a new
-    # ICI dependency and must be a deliberate contract change.
-    collectives=("psum",),
-)
-@lru_cache(maxsize=32)
-def _dist_step_fn(
-    mesh: Mesh,
-    table_keys: tuple,
-    salt: int,
-    max_levels: int,
-    frontier: int,
-    max_matches: int,
-    probes: int,
-):
-    """Build (once per mesh/config) the jitted sharded route step.
-
-    Cached so repeated dist_route_step calls reuse the compiled program
-    instead of re-tracing a fresh shard_map closure per batch.
-    """
-
-    def local_step(tables, sub_bitmaps, bytes_mat, lengths):
-        out = route_step_impl(
-            tables,
-            sub_bitmaps,
-            bytes_mat,
-            lengths,
-            salt=salt,
-            max_levels=max_levels,
-            frontier=frontier,
-            max_matches=max_matches,
-            probes=probes,
-        )
-        return _reduce_stats(out)
-
-    table_specs = {k: P() for k in table_keys}
-    fn = jax.shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(table_specs, P(None, "tp"), P("dp", None), P("dp")),
-        out_specs=_out_specs(),
-    )
-    return _register_built(jax.jit(fn))
-
-
-def dist_route_step(
-    mesh: Mesh,
-    tables: Dict,
-    sub_bitmaps,
-    bytes_mat,
-    lengths,
-    *,
-    salt: int,
-    max_levels: int = 16,
-    frontier: int = 32,
-    max_matches: int = 64,
-    probes: int = 8,
-):
-    """Run the full route step SPMD over the mesh.
-
-    Sharding layout:
-      - NFA tables: replicated (read-mostly; updates are host-pushed deltas)
-      - sub_bitmaps [Fcap, W]: sharded on W over 'tp' (each chip owns a
-        subscriber-lane slice — the topic-shard fan-out analog)
-      - bytes_mat/lengths [B, ...]: sharded on B over 'dp'
-      - outputs: matched/mcount/flags sharded over 'dp'; bitmaps sharded
-        over ('dp','tp'); stats psum'd to replicated scalars
-    """
-    fn = _dist_step_fn(
-        mesh,
-        tuple(sorted(tables)),
-        salt,
-        max_levels,
-        frontier,
-        max_matches,
-        probes,
-    )
-    return fn(tables, sub_bitmaps, bytes_mat, lengths)
 
 
 @device_contract(
@@ -708,11 +624,14 @@ def dist_shape_route_step(
     sem_topk: int = 0,
     rule_progs: tuple = (),
 ):
-    """Distributed serving step (shape engine). Sharding as in
-    `dist_route_step`: tables replicated, subscriber lanes on 'tp',
-    topic batch on 'dp', stats psum'd over ICI. With `group_tables`,
-    $share picks resolve on-device per dp shard (r3 verdict item 4 —
-    the host pick wall stays down on the multi-chip path too).
+    """Distributed serving step (shape engine). Sharding: tables
+    replicated (read-mostly; updates are host-pushed deltas), subscriber
+    lanes on 'tp' (each chip owns a slice), topic batch on 'dp';
+    matched/mcount/flags come back sharded over 'dp', bitmaps over
+    ('dp','tp'), stats psum'd over ICI to replicated scalars. With
+    `group_tables`, $share picks resolve on-device per dp shard (r3
+    verdict item 4 — the host pick wall stays down on the multi-chip
+    path too).
     ``kslot`` engages per-shard sparse fan-out compaction (see
     `_dist_shape_step_fn`). A dict `sub_bitmaps` = the CSR subscriber
     table, arrays sharded over 'tp' by their leading slot-owner axis."""
@@ -743,18 +662,6 @@ def dist_shape_route_step(
         rand, sub_bitmaps, bytes_mat, lengths,
         sem_tables, q_vecs, rule_feats, rule_valid,
     )
-
-
-def shard_inputs(mesh: Mesh, tables: Dict, sub_bitmaps, bytes_mat, lengths):
-    """device_put inputs with the canonical shardings (for repeated calls)."""
-    t = {
-        k: jax.device_put(v, NamedSharding(mesh, P()))
-        for k, v in tables.items()
-    }
-    sb = jax.device_put(sub_bitmaps, NamedSharding(mesh, P(None, "tp")))
-    bm = jax.device_put(bytes_mat, NamedSharding(mesh, P("dp", None)))
-    ln = jax.device_put(lengths, NamedSharding(mesh, P("dp")))
-    return t, sb, bm, ln
 
 
 def table_placement(mesh: Mesh):
@@ -828,9 +735,10 @@ def shard_shape_inputs(
     bytes_mat,
     lengths,
 ):
-    """`shard_inputs` for the serving (shape) engine — built from the
-    canonical placement helpers above (the ONE place the layout is
-    declared for every caller: dryrun, tests, DeviceRouter mesh mode)."""
+    """device_put the serving (shape) engine's inputs with the
+    canonical shardings — built from the placement helpers above (the
+    ONE place the layout is declared for every caller: dryrun, tests,
+    DeviceRouter mesh mode)."""
     tp = table_placement(mesh)
     st = {k: tp(k, v) for k, v in shape_tables.items()}
     nt = (

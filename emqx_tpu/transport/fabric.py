@@ -727,7 +727,7 @@ def pack_dlv_slabs(records, max_body: float = MAX_BODY):
 
 
 def unpack_pub_frame(frame: bytes):
-    """Whole-frame helper (tests/bench): -> (seq, legacy record list)
+    """Whole-frame helper (tests, wirecompat): -> (seq, legacy record list)
     for either pub wire format."""
     body = frame[5:]
     if frame[4] == T_PUBB_S:
@@ -737,7 +737,7 @@ def unpack_pub_frame(frame: bytes):
 
 
 def unpack_dlv_frame(frame: bytes):
-    """Whole-frame helper (tests/bench): -> legacy record list for
+    """Whole-frame helper (tests, wirecompat): -> legacy record list for
     either dlv wire format."""
     body = frame[5:]
     if frame[4] == T_DLV_S:

@@ -71,7 +71,7 @@ class TestCompileCache:
 
     def test_no_other_code_places_the_cache(self):
         sources = [os.path.join(ROOT, f) for f in (
-            "bench.py", "chip_smoke.py", "__graft_entry__.py")]
+            "chip_smoke.py", "__graft_entry__.py")]
         for top in ("emqx_tpu", "tools"):
             for d, _dirs, files in os.walk(os.path.join(ROOT, top)):
                 sources += [
@@ -108,20 +108,6 @@ class TestNoSilentCpu:
         on("TPU v9 imaginary")
         with pytest.raises(LookupError, match="TPU v9 imaginary"):
             profiler.device_peaks()
-
-    def test_bench_refuses_a_cpu_backend_and_its_parent_stays_off_jax(self):
-        r = _run(["bench.py", "exact_1k"])
-        assert r.returncode != 0 and "platform 'cpu'" in r.stderr
-        assert r.stdout.strip() == ""
-        code = (
-            "import sys, bench; sys.argv = ['bench.py']; rc = bench.main(); "
-            "assert 'jax' not in sys.modules, 'parent imported jax'; "
-            "sys.exit(rc)"
-        )
-        r = _run(["-c", code], BENCH_BUDGET_S="60")
-        assert r.returncode == 1, r.stderr[-2000:]
-        assert "platform 'cpu'" in r.stderr and "AssertionError" not in r.stderr
-        assert r.stdout.strip() == ""
 
     def test_worker_and_client_imports_stay_off_jax(self):
         """Connection workers and the smoke's driver run beside the one

@@ -468,12 +468,16 @@ async def test_ingest_sheds_on_olp_overload_and_drop_fault():
 # -- per-row matcher errors --------------------------------------------------
 
 def test_match_batch_returns_per_row_errors_without_fallback():
-    from emqx_tpu.ops.matcher import MatchError, TpuMatcher
-    from emqx_tpu.ops.nfa import NfaBuilder
+    """The residual NFA's rows: the filter sits outside the shape index."""
+    from emqx_tpu.models.router_model import DeviceRouter
+    from emqx_tpu.ops.matcher import MatchError
+    from emqx_tpu.ops.route_index import RouteIndex
 
-    builder = NfaBuilder()
-    builder.add("a/#")
-    matcher = TpuMatcher(builder, MatcherConfig(max_levels=4))
+    idx = RouteIndex(max_shapes=1)
+    idx.add("seed/+/x")  # takes the one shape
+    idx.add("a/#")
+    assert idx.residual_count == 1
+    matcher = DeviceRouter(idx, None, MatcherConfig(max_levels=4))
     deep = "a/" + "/".join("x" for _ in range(10))
     got = matcher.match_batch([deep, "a/b", deep], fallback=None)
     assert isinstance(got[0], MatchError) and got[0].topic == deep

@@ -205,8 +205,7 @@ def test_stale_snapshot_slot_reuse_on_compact_path():
 
 def test_dense_decode_survives_strided_rows():
     """`bits.view(np.uint8)` raises ValueError on non-contiguous rows —
-    some backends hand back strided readback buffers (bench.py works
-    around the same behavior with np.ascontiguousarray)."""
+    some backends hand back strided readback buffers."""
     b = _mk_broker(fanout_compact=False)
     got = []
     b.subscribe(

@@ -1,9 +1,15 @@
-"""Differential tests: TPU NFA matcher vs the authoritative CPU trie.
+"""Differential tests: the device match vs the authoritative CPU trie.
 
 This is the round-1 analog of the reference's emqx_trie_SUITE +
 emqx_router_SUITE correctness gates (SURVEY.md §7 stage 2): every behavior of
 the device matcher must agree with `TopicTrie.match` (itself tested
 brute-force against `topics.match`).
+
+Two subjects. The differential cases drive the product's match-only
+path, `Router.matcher` (a lazy `DeviceRouter`: shape index + residual
+NFA). The cases that pin the NFA's own limits run the NFA kernel alone,
+`batch_match_syms` over a `DeviceDeltaSync` mirror of an `NfaBuilder`,
+as the served step runs it for residual filters.
 """
 
 import random
@@ -11,13 +17,41 @@ import random
 import numpy as np
 import pytest
 
+from emqx_tpu.broker.router import Router
 from emqx_tpu.broker.trie import TopicTrie
 from emqx_tpu.ops import topics as T
-from emqx_tpu.ops.matcher import MatcherConfig, TpuMatcher, batch_match_syms
-from emqx_tpu.ops.nfa import NfaBuilder
+from emqx_tpu.ops.matcher import MatcherConfig, batch_match_syms
+from emqx_tpu.ops.nfa import MAX_PROBES, DeviceDeltaSync, NfaBuilder
 
 
-def make_pair(filters):
+class Pair:
+    """The reference trie beside the product's route table."""
+
+    def __init__(self, filters=(), cfg=None):
+        self.trie = TopicTrie()
+        self.router = Router(matcher_config=cfg)
+        for f in filters:
+            self.add(f)
+
+    def add(self, f):
+        self.trie.insert(f)
+        self.router.add_route(f)
+
+    def remove(self, f):
+        self.trie.delete(f)
+        self.router.delete_route(f)
+
+    def check(self, topics_list, ctx=None):
+        got = self.router.matcher.match_batch(
+            topics_list, fallback=self.trie.match
+        )
+        for topic, names in zip(topics_list, got):
+            assert sorted(names) == sorted(self.trie.match(topic)), (
+                ctx, topic,
+            )
+
+
+def make_nfa(filters):
     trie = TopicTrie()
     builder = NfaBuilder()
     for f in filters:
@@ -26,66 +60,84 @@ def make_pair(filters):
     return trie, builder
 
 
-def check(trie, builder, topics_list, cfg=MatcherConfig()):
-    m = TpuMatcher(builder, cfg)
-    got = m.match_batch(topics_list, fallback=trie.match)
-    for topic, names in zip(topics_list, got):
-        assert sorted(names) == sorted(trie.match(topic)), topic
+def nfa_check(trie, builder, topics_list, cfg=MatcherConfig(), sync=None):
+    """The NFA kernel alone: host tokenize -> `batch_match_syms` over the
+    delta-synced mirror; a flagged row takes the trie, as on the served
+    path. -> the per-cause flag arrays, so a limit test can show that
+    the limit was what sent the row back."""
+    tables = (sync or DeviceDeltaSync()).sync(builder)
+    rows = [builder.tokenize_host(t, cfg.max_levels) for t in topics_list]
+    matched, mcount, flags, causes = batch_match_syms(
+        tables,
+        np.stack([r[0] for r in rows]),
+        np.array([r[1] for r in rows], dtype=np.int32),
+        np.array([r[2] for r in rows]),
+        frontier=cfg.frontier,
+        max_matches=cfg.max_matches,
+        probes=max(cfg.probes, MAX_PROBES),
+    )
+    matched, mcount = np.asarray(matched), np.asarray(mcount)
+    flags = np.asarray(flags)
+    for i, topic in enumerate(topics_list):
+        if flags[i]:
+            continue  # the caller's fallback IS the trie
+        names = [
+            builder.filter_name(int(f)) for f in matched[i, : mcount[i]]
+        ]
+        assert sorted(n for n in names if n is not None) == sorted(
+            trie.match(topic)
+        ), topic
+    causes = {k: np.asarray(v).tolist() for k, v in causes.items()}
+    assert flags.tolist() == [
+        any(c[i] for c in causes.values()) for i in range(len(rows))
+    ]
+    return causes
 
 
 def test_basic_match():
     filters = ["a/b/c", "a/+/c", "a/#", "#", "+/b/c", "a/b/+", "x/y"]
-    trie, builder = make_pair(filters)
-    check(
-        trie,
-        builder,
+    Pair(filters).check(
         ["a/b/c", "a/b", "a", "x/y", "x/z", "q", "a/q/c", "a/b/q"],
     )
 
 
 def test_hash_parent_and_exact():
-    trie, builder = make_pair(["a/#", "a", "a/b/#"])
-    check(trie, builder, ["a", "a/b", "a/b/c", "b"])
+    Pair(["a/#", "a", "a/b/#"]).check(["a", "a/b", "a/b/c", "b"])
 
 
 def test_dollar_topics():
-    trie, builder = make_pair(["#", "+/x", "$SYS/#", "$SYS/+", "$share-ish/x"])
-    check(
-        trie,
-        builder,
+    Pair(["#", "+/x", "$SYS/#", "$SYS/+", "$share-ish/x"]).check(
         ["$SYS/x", "$SYS", "n/x", "$share-ish/x", "$other/x", "$SYS/a/b"],
     )
 
 
 def test_empty_levels_and_oov():
-    trie, builder = make_pair(["a/+/c", "a//c", "+/+", "//#"])
-    check(trie, builder, ["a//c", "a/zz/c", "/", "//", "a/", "/a", "never/seen"])
+    Pair(["a/+/c", "a//c", "+/+", "//#"]).check(
+        ["a//c", "a/zz/c", "/", "//", "a/", "/a", "never/seen"]
+    )
 
 
 def test_plus_only_and_root_hash():
-    trie, builder = make_pair(["+", "#", "+/+"])
-    check(trie, builder, ["a", "a/b", "a/b/c", "$sys", "$sys/b"])
+    Pair(["+", "#", "+/+"]).check(["a", "a/b", "a/b/c", "$sys", "$sys/b"])
 
 
 def test_delete_updates_tables():
-    trie, builder = make_pair(["a/+", "a/b", "b/#"])
-    trie.delete("a/+")
-    builder.remove("a/+")
-    check(trie, builder, ["a/b", "a/x", "b/q"])
-    trie.delete("b/#")
-    builder.remove("b/#")
-    check(trie, builder, ["a/b", "a/x", "b/q", "b"])
-    # re-add after delete (exercises node/sym free lists)
-    trie.insert("a/+")
-    builder.add("a/+")
-    check(trie, builder, ["a/b", "a/x"])
+    pair = Pair(["a/+", "a/b", "b/#"])
+    pair.remove("a/+")
+    pair.check(["a/b", "a/x", "b/q"])
+    pair.remove("b/#")
+    pair.check(["a/b", "a/x", "b/q", "b"])
+    # re-add after delete (exercises the index's free lists)
+    pair.add("a/+")
+    pair.check(["a/b", "a/x"])
 
 
 def test_too_deep_falls_back():
     cfg = MatcherConfig(max_levels=4)
-    trie, builder = make_pair(["a/#"])
+    trie, builder = make_nfa(["a/#"])
     deep = "a/" + "/".join("x" * 1 for _ in range(10))
-    check(trie, builder, [deep, "a/b"], cfg)
+    causes = nfa_check(trie, builder, [deep, "a/b"], cfg)
+    assert causes["too_deep"] == [True, False]
 
 
 def test_frontier_overflow_falls_back():
@@ -96,20 +148,26 @@ def test_frontier_overflow_falls_back():
         for b in ["+", "a", "b"]:
             for c in ["+", "a", "b"]:
                 filters.append(f"{a}/{b}/{c}")
-    trie, builder = make_pair(filters)
-    check(trie, builder, ["a/b/a", "b/b/b", "a/a/a"], cfg)
+    trie, builder = make_nfa(filters)
+    causes = nfa_check(trie, builder, ["a/b/a", "b/b/b", "a/a/a"], cfg)
+    assert all(causes["frontier_overflow"])
 
 
 def test_match_overflow_falls_back():
     cfg = MatcherConfig(max_matches=2)
-    trie, builder = make_pair(["a/#", "a/+", "a/b", "#", "+/b"])
-    check(trie, builder, ["a/b"], cfg)
+    trie, builder = make_nfa(["a/#", "a/+", "a/b", "#", "+/b"])
+    causes = nfa_check(trie, builder, ["a/b", "q"], cfg)
+    assert causes["match_overflow"] == [True, False]
 
 
 def test_long_topic_falls_back():
-    cfg = MatcherConfig(max_bytes=32)
-    trie, builder = make_pair(["a/#"])
-    check(trie, builder, ["a/" + "y" * 100, "a/b"], cfg)
+    # the byte budget belongs to the served step's device tokenizer
+    pair = Pair(["a/#"], MatcherConfig(max_bytes=32))
+    long = "a/" + "y" * 100
+    pair.check([long, "a/b"])
+    assert pair.router.matcher.route([long, "a/b"]).flags.tolist() == [
+        True, False,
+    ]
 
 
 def random_word(rng):
@@ -137,7 +195,7 @@ def test_randomized_differential(seed):
             filters.add(f)
         except T.TopicValidationError:
             pass
-    trie, builder = make_pair(sorted(filters))
+    pair = Pair(sorted(filters))
     topics_list = []
     for _ in range(500):
         depth = rng.randint(1, 8)
@@ -145,18 +203,17 @@ def test_randomized_differential(seed):
         if rng.random() < 0.1:
             ws[0] = "$" + ws[0]
         topics_list.append("/".join(ws))
-    check(trie, builder, topics_list)
+    pair.check(topics_list)
     # now delete a random half and re-check
     for f in sorted(filters):
         if rng.random() < 0.5:
-            trie.delete(f)
-            builder.remove(f)
-    check(trie, builder, topics_list)
+            pair.remove(f)
+    pair.check(topics_list)
 
 
 def test_host_tokenize_matches_device_path():
     # exercised indirectly above; here verify sym-level entry point too
-    trie, builder = make_pair(["dev/+/temp", "dev/1/temp"])
+    trie, builder = make_nfa(["dev/+/temp", "dev/1/temp"])
     tables = builder.pack().device_arrays()
     L = 8
     rows = [builder.tokenize_host(t, L) for t in ["dev/1/temp", "dev/9/hum"]]
@@ -179,43 +236,51 @@ def test_host_tokenize_matches_device_path():
 
 def test_invalid_add_does_not_corrupt_builder():
     # code-review finding: add('a/#/b') must fail without mutating state
-    trie, builder = make_pair(["a/b"])
+    trie, builder = make_nfa(["a/b"])
     with pytest.raises(T.TopicValidationError):
         builder.add("a/#/b")
     builder.add("a/+")
     trie.insert("a/+")
-    check(trie, builder, ["a/b", "a/x", "a"])
+    nfa_check(trie, builder, ["a/b", "a/x", "a"])
     assert builder.remove("a/+")
 
 
 def test_literal_plus_in_topic_not_wildcard():
     # code-review finding: a literal '+'/'#' char in a (malformed) topic must
     # not walk the wildcard branch as an exact word
-    trie, builder = make_pair(["a/+", "a/#"])
-    assert sorted(trie.match("a/+")) == ["a/#", "a/+"]  # via wildcards only
-    check(trie, builder, ["a/+", "a/#", "a/b"])
+    pair = Pair(["a/+", "a/#"])
+    assert sorted(pair.trie.match("a/+")) == ["a/#", "a/+"]  # via wildcards only
+    pair.check(["a/+", "a/#", "a/b"])
 
 
 def test_low_probe_config_is_clamped():
-    trie, builder = make_pair([f"w{i}/x" for i in range(200)])
-    m = TpuMatcher(builder, MatcherConfig(probes=1))
-    got = m.match_batch(["w34/x"], fallback=trie.match)
-    assert got == [["w34/x"]]
+    """`DeviceRouter.__init__` raises a probe bound under the build-time
+    one: with probes=1 the residual NFA's lookups would silently miss."""
+    from emqx_tpu.models.router_model import DeviceRouter
+    from emqx_tpu.ops.route_index import RouteIndex
+
+    index = RouteIndex(max_shapes=1)
+    index.add("seed/+/x/y")  # takes the one shape: the rest is residual
+    for i in range(200):
+        index.add(f"w{i}/x")
+    assert index.residual_count == 200
+    m = DeviceRouter(index, None, MatcherConfig(probes=1))
+    assert m.config.probes == MAX_PROBES
+    assert m.match_batch(["w34/x"], fallback=None) == [["w34/x"]]
 
 
 @pytest.mark.parametrize("seed", [7, 21])
 def test_churn_differential_delta_sync(seed):
-    """Sustained subscribe/unsubscribe churn against ONE TpuMatcher.
+    """Sustained subscribe/unsubscribe churn against ONE match engine.
 
-    The device mirror must track the host through delta scatters,
-    tombstoned slots, node/edge/vocab reuse, growth, and epoch bumps
-    (nfa.DeviceDeltaSync) — matching the CPU trie after every step.
+    The device mirrors must track the host through delta scatters,
+    tombstoned slots, slot reuse, growth, and epoch bumps
+    (ops/segments.DeviceSegmentManager) — matching the CPU trie after
+    every step.
     """
     rng = random.Random(seed)
     words = [f"w{i}" for i in range(40)] + ["+", "#"]
-    trie = TopicTrie()
-    builder = NfaBuilder()
-    m = TpuMatcher(builder, MatcherConfig(frontier=64, max_matches=64))
+    pair = Pair(cfg=MatcherConfig(frontier=64, max_matches=64))
     live = []
     topics_pool = [
         "/".join(rng.choice(words[:40]) for _ in range(rng.randint(1, 5)))
@@ -231,39 +296,34 @@ def test_churn_differential_delta_sync(seed):
                 T.validate(f)
             except T.TopicValidationError:
                 continue
-            trie.insert(f)
-            builder.add(f)
+            pair.add(f)
             live.append(f)
         for _ in range(rng.randint(0, 6)):
             if not live:
                 break
-            f = live.pop(rng.randrange(len(live)))
-            trie.delete(f)
-            builder.remove(f)
-        got = m.match_batch(topics_pool, fallback=trie.match)
-        for topic, names in zip(topics_pool, got):
-            assert sorted(names) == sorted(trie.match(topic)), (step, topic)
+            pair.remove(live.pop(rng.randrange(len(live))))
+        pair.check(topics_pool, ctx=step)
 
 
 def test_churn_epoch_growth():
-    """Push one matcher through table growth (epoch bump) mid-stream."""
+    """Push one NFA mirror through table growth (epoch bump) mid-stream."""
     trie = TopicTrie()
     builder = NfaBuilder()
-    m = TpuMatcher(builder)
+    sync = DeviceDeltaSync()
     # small tables first
     for i in range(4):
         trie.insert(f"a/{i}/+")
         builder.add(f"a/{i}/+")
-    got = m.match_batch(["a/1/x"], fallback=trie.match)
-    assert got[0] == ["a/1/+"]
+    nfa_check(trie, builder, ["a/1/x"], sync=sync)
+    epoch = builder.epoch
     # now >1024 filters: forces node-array growth + edge/vocab rehash
     for i in range(1500):
         trie.insert(f"grow/{i}/leaf")
         builder.add(f"grow/{i}/leaf")
+    assert builder.epoch > epoch
     topics_list = [f"grow/{i}/leaf" for i in range(0, 1500, 97)] + ["a/2/q"]
-    got = m.match_batch(topics_list, fallback=trie.match)
-    for topic, names in zip(topics_list, got):
-        assert sorted(names) == sorted(trie.match(topic)), topic
+    causes = nfa_check(trie, builder, topics_list, sync=sync)
+    assert not any(any(c) for c in causes.values())
 
 
 def test_oplog_cap_forces_epoch_resync():
@@ -271,15 +331,16 @@ def test_oplog_cap_forces_epoch_resync():
     trie = TopicTrie()
     builder = NfaBuilder()
     builder.OPLOG_MAX = 64  # tiny, to hit the cap fast
-    m = TpuMatcher(builder)
-    m.match_batch(["x"], fallback=trie.match)  # prime the mirror
+    sync = DeviceDeltaSync()
+    nfa_check(trie, builder, ["x"], sync=sync)  # prime the mirror
+    resyncs = sync.full_resyncs
     for i in range(300):
         trie.insert(f"c/{i}/#")
         builder.add(f"c/{i}/#")
     topics_list = [f"c/{i}/deep/leaf" for i in range(0, 300, 13)]
-    got = m.match_batch(topics_list, fallback=trie.match)
-    for topic, names in zip(topics_list, got):
-        assert sorted(names) == sorted(trie.match(topic)), topic
+    causes = nfa_check(trie, builder, topics_list, sync=sync)
+    assert not any(any(c) for c in causes.values())
+    assert sync.full_resyncs > resyncs
 
 
 def test_insert_cost_is_delta_not_table():
@@ -288,8 +349,6 @@ def test_insert_cost_is_delta_not_table():
     builder = NfaBuilder()
     for i in range(2000):
         builder.add(f"base/{i}/+/leaf")
-    from emqx_tpu.ops.nfa import DeviceDeltaSync
-
     sync = DeviceDeltaSync()
     sync.sync(builder)
     pos = len(builder.oplog)

@@ -315,14 +315,14 @@ def test_prometheus_histogram_exposition():
     from emqx_tpu.broker.metrics import Metrics
 
     m = Metrics()
-    m.observe_many("matcher.device.seconds", [0.0002, 0.003, 0.03])
+    m.observe_many("router.device.seconds", [0.0002, 0.003, 0.03])
     body = prometheus_exposition(m.snapshot(), histograms=m.histograms())
-    assert "# TYPE emqx_matcher_device_seconds histogram" in body
-    assert 'emqx_matcher_device_seconds_bucket{le="0.00025"} 1' in body
-    assert 'emqx_matcher_device_seconds_bucket{le="0.005"} 2' in body
-    assert 'emqx_matcher_device_seconds_bucket{le="+Inf"} 3' in body
-    assert "emqx_matcher_device_seconds_count 3" in body
-    assert "emqx_matcher_device_seconds_sum 0.0332" in body
+    assert "# TYPE emqx_router_device_seconds histogram" in body
+    assert 'emqx_router_device_seconds_bucket{le="0.00025"} 1' in body
+    assert 'emqx_router_device_seconds_bucket{le="0.005"} 2' in body
+    assert 'emqx_router_device_seconds_bucket{le="+Inf"} 3' in body
+    assert "emqx_router_device_seconds_count 3" in body
+    assert "emqx_router_device_seconds_sum 0.0332" in body
 
 
 def test_statsd_render_counters_as_deltas():
@@ -453,7 +453,6 @@ async def test_event_messages_and_observe_rest(tmp_path=None):
                 assert r.status == 200
                 hp = await r.json()
                 assert hp["dispatch"]["fanout"]["count"] >= 1
-                assert hp["matcher"]["fallback_by_cause"]["too_deep"] == 0
                 assert hp["alarms"]["tpu_fallback_rate_active"] is False
             # alarms endpoint (activate one by hand)
             app.alarms.activate("test_alarm", {"k": 1}, "manual")
@@ -473,51 +472,6 @@ async def test_event_messages_and_observe_rest(tmp_path=None):
 
 
 # -- hot-path flight recorder ----------------------------------------------
-
-def test_matcher_fallback_counter_by_cause_and_histogram_exposition():
-    """Acceptance gate: a topic exceeding MatcherConfig.max_levels bumps
-    the too_deep fallback counter, and the recorded device-latency
-    histogram renders as a real `# TYPE ... histogram` family."""
-    from emqx_tpu.broker.metrics import Metrics
-    from emqx_tpu.ops.matcher import MatcherConfig, TpuMatcher
-    from emqx_tpu.ops.nfa import NfaBuilder
-
-    m = Metrics()
-    builder = NfaBuilder()
-    builder.add("a/#")
-    matcher = TpuMatcher(builder, MatcherConfig(max_levels=4), metrics=m)
-    deep = "a/" + "/".join("x" for _ in range(10))  # 11 levels > 4
-    got = matcher.match_batch([deep, "a/b"], fallback=lambda t: ["cpu"])
-    assert got == [["cpu"], ["a/#"]]
-    assert m.get("matcher.rows") == 2
-    assert m.get("matcher.fallback.rows") == 1
-    assert m.get("matcher.fallback.rows.too_deep") == 1
-    assert m.get("matcher.fallback.rows.frontier_overflow") == 0
-    assert m.get("matcher.fallback.rows.match_overflow") == 0
-    assert m.get("matcher.fallback.rows.too_long") == 0
-    assert m.histogram("matcher.device.seconds").count == 1
-    assert m.histogram("matcher.sync.seconds").count >= 1
-    body = prometheus_exposition(m.snapshot(), histograms=m.histograms())
-    assert "# TYPE emqx_matcher_device_seconds histogram" in body
-    assert 'emqx_matcher_device_seconds_bucket{le="+Inf"} 1' in body
-    assert "emqx_matcher_device_seconds_count 1" in body
-    assert "emqx_matcher_fallback_rows_too_deep 1" in body
-
-
-def test_matcher_fallback_too_long_counted():
-    from emqx_tpu.broker.metrics import Metrics
-    from emqx_tpu.ops.matcher import MatcherConfig, TpuMatcher
-    from emqx_tpu.ops.nfa import NfaBuilder
-
-    m = Metrics()
-    builder = NfaBuilder()
-    builder.add("a/#")
-    matcher = TpuMatcher(builder, MatcherConfig(max_bytes=32), metrics=m)
-    got = matcher.match_batch(["a/" + "y" * 100], fallback=lambda t: ["cpu"])
-    assert got == [["cpu"]]
-    assert m.get("matcher.fallback.rows.too_long") == 1
-    assert m.get("matcher.fallback.rows") == 1
-
 
 def test_fallback_rate_alarm_trigger_and_clear():
     from emqx_tpu.broker.metrics import Metrics
@@ -545,11 +499,6 @@ def test_fallback_rate_alarm_trigger_and_clear():
     m.inc("messages.routed.device_fallback", 3)
     assert w.check(t + 4.5) is None
     assert not am.is_active(FallbackRateWatch.ALARM)
-    # matcher-path counters feed the same rate
-    m.inc("matcher.rows", 40)
-    m.inc("matcher.fallback.rows", 39)
-    assert w.check(t + 6.0) == pytest.approx(39 / 40)
-    assert am.is_active(FallbackRateWatch.ALARM)
 
 
 def test_ingest_flight_recorder_series():

@@ -78,40 +78,3 @@ def test_dist_matches_single_device(force_residual):
     )
     for k in local["stats"]:
         assert int(local["stats"][k]) == int(dist["stats"][k]), k
-
-
-def test_dist_nfa_step_still_works():
-    """The residual-NFA distributed step stays available (legacy path)."""
-    import __graft_entry__ as ge
-    from emqx_tpu.models.router_model import SubscriberTable, route_step
-    from emqx_tpu.ops.nfa import NfaBuilder
-    from emqx_tpu.ops.tokenizer import encode_topics
-    from emqx_tpu.parallel.mesh import dist_route_step, make_mesh, shard_inputs
-
-    builder = NfaBuilder()
-    subs = SubscriberTable(max_subscribers=512)
-    for i in range(64):
-        fid = builder.add(f"n/{i}/+/q")
-        subs.add(fid, i)
-    tables = builder.pack()
-    topics = [f"n/{i % 64}/z/q" for i in range(64)]
-    bytes_mat, lengths, _ = encode_topics(topics, 64)
-    sub_bitmaps = subs.pack(builder.num_filters_capacity)
-    dev = tables.device_arrays()
-    local = route_step(
-        dev, sub_bitmaps, bytes_mat, np.asarray(lengths),
-        salt=tables.salt, **ge._CFG,
-    )
-    mesh = make_mesh(8)
-    t, sb, bm, ln = shard_inputs(
-        mesh, dev, sub_bitmaps, bytes_mat, np.asarray(lengths)
-    )
-    dist = dist_route_step(mesh, t, sb, bm, ln, salt=tables.salt, **ge._CFG)
-    np.testing.assert_array_equal(
-        np.asarray(local["matched"]), np.asarray(dist["matched"])
-    )
-    np.testing.assert_array_equal(
-        np.asarray(local["bitmaps"]), np.asarray(dist["bitmaps"])
-    )
-    for k in local["stats"]:
-        assert int(local["stats"][k]) == int(dist["stats"][k]), k

@@ -593,8 +593,9 @@ class TestMassResume:
         cids = [f"c{i}" for i in range(n)]
         msgs = [Message(topic=f"t/{i}", payload=b"m", qos=1)
                 for i in range(n)]
-        rows = store.bulk_load(cids, msgs)
-        assert (rows >= 0).all() and store.table.live == n
+        for cid, msg in zip(cids, msgs):
+            store.inflight_insert(store.attach(cid), 1, msg, "publish")
+        assert store.table.live == n
         state = pickle.loads(pickle.dumps(store.capture()))
 
         store2 = SessionStore(

@@ -284,7 +284,6 @@ def test_metric_checker_sees_the_hot_path_call_sites():
         names.update(name for _, name in call_sites(mod))
     for expected in (
         "ingest.batch.size",
-        "matcher.device.seconds",
         "router.device.seconds",
         "dispatch.fanout",
         "messages.routed.device",

@@ -9,7 +9,7 @@ Unlike the old script this collects the declared set *statically* (every
 `declare("name", ...)` call in the scanned tree), so the analyzer never
 imports broker code. Dynamic names (f-strings, variables) are skipped —
 they must be composed from declared prefixes, e.g. the
-`matcher.fallback.rows.<cause>` family, each declared explicitly.
+`ingest.lane.settle.seconds.<lane>` family, each declared explicitly.
 """
 
 from __future__ import annotations

@@ -13,10 +13,10 @@ under the trace).
 
 Roots are jit-wrapped functions (decorated `@jax.jit` /
 `@partial(jax.jit, ...)`, or wrapped by a module-level assignment like
-`route_step = partial(jax.jit, static_argnames=...)(route_step_impl)`).
+`shape_route_step = partial(jax.jit, static_argnames=...)(shape_route_step_impl)`).
 Hazard = the root's parameters minus its static names. Hazards follow
 simple assignment and propagate through calls into callee parameters
-(`route_step_impl` hands `kslot` to `compact_fanout_slots` — dropping
+(`shape_route_step_impl` hands `kslot` to `compact_fanout_slots` — dropping
 `kslot` from the static tuple is flagged *inside the callee*). Deriving
 from `.shape`/`.ndim`/`.size`/`len()` clears the hazard: those are
 static at trace time. Closure variables are static by construction and

@@ -286,13 +286,10 @@ def _harness(name: str):
             {"B": 8, "kslot": 8},
             {"B": 16, "kslot": 8},
         ]
-    elif name in (
-        "route_step", "shape_route_step", "fused_route_retained_step"
-    ):
+    elif name in ("shape_route_step", "fused_route_retained_step"):
         configs = _configs_single()
     elif name in (
-        "dist_step", "dist_shape_step", "dist_fused_step",
-        "sparse_dist_shape_step",
+        "dist_shape_step", "dist_fused_step", "sparse_dist_shape_step",
     ):
         configs = (
             [
@@ -428,14 +425,6 @@ def _harness(name: str):
                 index.shapes.device_snapshot(), nfa, csr,
                 bytes_mat, lengths,
             )
-        if name == "route_step":
-            from emqx_tpu.models.router_model import route_step
-
-            tables = index.nfa.device_snapshot()
-            fn = partial(
-                route_step, salt=salt, kslot=cfg["kslot"], **kw
-            )
-            return fn, (tables, bits, bytes_mat, lengths)
         if name == "shape_route_step":
             from emqx_tpu.models.router_model import shape_route_step
 
@@ -503,15 +492,6 @@ def _harness(name: str):
         # batch divisible by dp, lanes by tp
         if B % cfg["dp"]:
             raise _SkipConfig(f"{name}: B={B} not divisible by dp")
-        if name == "dist_step":
-            from emqx_tpu.parallel.mesh import _dist_step_fn
-
-            tables = index.nfa.device_snapshot()
-            fn = _dist_step_fn(
-                mesh, tuple(sorted(tables)), salt, kw["max_levels"],
-                kw["frontier"], kw["max_matches"], kw["probes"],
-            )
-            return fn, (tables, bits, bytes_mat, lengths)
         if name == "dist_fused_step":
             from emqx_tpu.ops.route_index import RouteIndex
             from emqx_tpu.parallel.mesh import _dist_fused_step_fn

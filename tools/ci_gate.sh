@@ -16,7 +16,9 @@
 #      the log, so a race report is never buried in a 500-test dot wall
 #
 # All three run on the CPU. The chip is proved separately, through the
-# chip tool: `python chip_smoke.py`.
+# chip tool: `python chip_smoke.py`, and speed is what the benchmark's
+# cells measure there (`python3 benchmark/run.py --workload <cell>
+# --seed <n> --seconds 30 --trace <0|1>`; BENCHMARK.json).
 #
 # Fast mode for the inner loop (pre-push, not pre-merge):
 #
@@ -26,55 +28,6 @@
 #                               # --smoke`: replay capped at 8 rounds,
 #                               # full corpus replay, contracts
 #                               # skipped) + race suite
-#
-# Bench recipes (NOT part of this gate; bench.py runs on a TPU only and
-# exits non-zero elsewhere — run them through the chip tool when a PR
-# touches the paths they measure):
-#
-#   python bench.py --configs chaos_soak    # degradation ladder gate
-#                                           # (incl. the overload wave:
-#                                           # QoS0 firehose + open
-#                                           # breaker vs the control
-#                                           # lane, SLO ladder asserts)
-#   python bench.py --configs latency_frontier # SLO-adaptive batching:
-#                                           # measured latency-vs-
-#                                           # throughput frontier 10%->
-#                                           # 100% load; gates p99@10%
-#                                           # < 5ms, monotone frontier,
-#                                           # bounded control-lane p99
-#                                           # under a storm
-#                                           # (docs/robustness.md)
-#   python bench.py churn_storm             # segmented update path at
-#                                           # 10M subs (~3-4 min): gates
-#                                           # >1M inserts/s and <10ms
-#                                           # subscribe visibility
-#                                           # (docs/update_path.md)
-#   python bench.py --configs session_storm # device-resident session
-#                                           # state: 1M-session resume
-#                                           # via segment replay + QoS1
-#                                           # redelivery flood (~30s —
-#                                           # docs/sessions.md)
-#   python bench.py --configs conn_scaling  # slab protocol plane:
-#                                           # 10k->1M simulated-client
-#                                           # scaling curve with the
-#                                           # distinct-topic axis
-#                                           # (4096->100k->1M topics;
-#                                           # CSR sub_table_bytes
-#                                           # measured per point,
-#                                           # deliveries drained to
-#                                           # quiescence) + codec
-#                                           # microbench
-#                                           # (docs/protocol_plane.md,
-#                                           # serving_pipeline.md)
-#   python bench.py --configs agentic_fabric # semantic routing plane:
-#                                           # mixed topic+semantic
-#                                           # fan-in/fan-out scenarios,
-#                                           # device-fused similarity +
-#                                           # rule WHERE masks vs the
-#                                           # post-dispatch host filter
-#                                           # (docs/semantic_routing.md)
-#   python bench.py                         # full sweep (one JSON line;
-#                                           # chiprun_out/bench_full.json)
 #
 # Exit non-zero on the first failing gate.
 set -euo pipefail
