@@ -22,7 +22,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from emqx_tpu.broker.broker import Broker
-from emqx_tpu.broker.hooks import Hooks
+from emqx_tpu.broker.hooks import STOP, Hooks
 from emqx_tpu.broker import mountpoint as MP
 from emqx_tpu.broker.message import Message
 from emqx_tpu.broker.session import Session, SessionConfig
@@ -140,17 +140,6 @@ class Channel:
         finally:
             _prof.end()
 
-    def _send_all(self, packets) -> None:
-        """A read chunk's replacement sends: one section; the sink
-        writes them with the chunk's end."""
-        _prof.begin("egress.send")
-        try:
-            for p in packets:
-                self.sink.send_packet(p)
-            self.broker.metrics.inc("packets.sent", len(packets))
-        finally:
-            _prof.end(len(packets))
-
     def _close(self, reason: str, rc: Optional[int] = None) -> None:
         if rc is not None and self.version == pkt.MQTT_V5 and self.state == "connected":
             self._send(pkt.Disconnect(reason_code=rc))
@@ -232,43 +221,98 @@ class Channel:
 
     def handle_acks(self, acks) -> None:
         """A read chunk's run of PUBACK / PUBREC / PUBCOMP on a connected
-        channel (`Connection.run` groups them): `handle_in` for each, in
-        one `channel.ack_in` section."""
+        channel (`Connection.run` groups them): what `handle_in` does for
+        one, for the run."""
         self.broker.metrics.inc("packets.received", len(acks))
         self._in_acks(acks)
 
     def _in_acks(self, acks) -> None:
-        # the subscriber's side of QoS1/2 deliveries: the acks, the
-        # session queue's drain, then the replacement sends in one write
-        # batch. One section per run, its entries the ack packets.
+        # the subscriber's side of QoS1/2 deliveries, as the batch a read
+        # chunk already is: the session clears and refills its window in
+        # one pass, the hook chains are resolved once and run per message,
+        # the PUBRELs and the refills leave as one write batch. One
+        # section per run, its entries the ack packets; a lone ack is the
+        # run of one.
         _prof.begin("channel.ack_in")
         try:
-            out: List = []
-            for p in acks:
-                t = p.type
-                if t == pkt.PUBREC:
-                    rel = pkt.PubAck(
-                        packet_id=p.packet_id,
-                        reason_code=pkt.RC_SUCCESS
-                        if self.session.pubrec(p.packet_id)
-                        else pkt.RC_PACKET_IDENTIFIER_NOT_FOUND,
-                    )
-                    rel.type = pkt.PUBREL
-                    out.append(rel)
-                    continue
-                done, more = (
-                    self.session.puback(p.packet_id)
-                    if t == pkt.PUBACK
-                    else self.session.pubcomp(p.packet_id)
-                )
-                if done is not None:
-                    self.hooks.run("message.acked", self._ci_snapshot(), done)
-                    self._delivery_completed(done)
-                out.extend(more)
-            if out:
-                self._send_all(out)
+            self.broker.metrics.inc("channel.ack.runs")
+            done, recs, refills = self.session.ack_run(
+                [(p.type, p.packet_id) for p in acks]
+            )
+            if done:
+                self._acked(done)
+            if recs or refills:
+                self._send_run(recs, refills)
         finally:
             _prof.end(len(acks))
+
+    def _acked(self, done) -> None:
+        """`message.acked`, then `delivery.completed`, for each message a
+        run acknowledged, in the run's order: what `Hooks.run` does per
+        message, with the chains, the client info and the wall clock taken
+        once. A hook point without a synchronous callback costs nothing."""
+        acked = self.hooks.sync_callbacks("message.acked")
+        completed = self.hooks.sync_callbacks("delivery.completed")
+        if not (acked or completed):
+            return
+        ci = self._ci_snapshot()
+        now = time.time()
+        for msg in done:
+            for cb in acked:
+                if cb(ci, msg) is STOP:
+                    break
+            for cb in completed:
+                if cb(ci, msg, now - msg.timestamp) is STOP:
+                    break
+
+    def _send_run(self, recs, refills) -> None:
+        """An ack run's output: the PUBRELs in the run's order, then the
+        refills in the queue's, each refill already in the window. One
+        `egress.send` section (entries: packets); the sink writes them
+        with the chunk's end. A refill is the split frame its message's
+        first sends share (`_split_frame`), and a `send_packet` where
+        `_send_pub_split` falls back too: a sink without `send_segments`,
+        a retained replay, an oversize topic."""
+        from emqx_tpu.mqtt.slab_serializer import pid_bytes
+
+        n = len(recs) + len(refills)
+        _prof.begin("egress.send")
+        try:
+            sink = self.sink
+            for pid, known in recs:
+                rel = pkt.PubAck(
+                    packet_id=pid,
+                    reason_code=pkt.RC_SUCCESS
+                    if known
+                    else pkt.RC_PACKET_IDENTIFIER_NOT_FOUND,
+                )
+                rel.type = pkt.PUBREL
+                sink.send_packet(rel)
+            ws = getattr(sink, "send_segments", None)
+            segs: List = []
+            split = 0
+            for pid, msg in refills:
+                ent = None
+                if ws is not None and not msg.headers.get("retained"):
+                    ent = self._split_frame(msg, msg.qos, msg.retain)
+                if ent is not None:
+                    segs += (ent[0], pid_bytes(pid), ent[1])
+                    split += 1
+                    continue
+                if segs:  # byte order is the refills' order
+                    ws(segs)
+                    segs = []
+                sink.send_packet(
+                    self.session._publish_packet(msg, msg.qos, pid)
+                )
+            if segs:
+                ws(segs)
+            metrics = self.broker.metrics
+            metrics.inc("packets.sent", n)
+            if split:
+                metrics.inc("dispatch.serialize.frames", split)
+        finally:
+            _prof.end(n)
 
     async def _in_reauth(self, p) -> None:
         method = p.properties.get("Authentication-Method")
@@ -983,14 +1027,11 @@ class Channel:
                 self._delivery_completed(msg)
 
     def _send_pub_split(self, msg: Message, q) -> bool:
-        """QoS1/2 fan-out fast path: serialize the PUBLISH ONCE per
-        (version, qos, retain, topic) as a head/tail pair around the
-        packet-id slot (mqtt/slab_serializer.split_publish — bytes
-        identical to frame.serialize) and hand each subscriber's frame
-        to the sink as the segments [head, pid, tail] — the payload is
-        never re-serialised per target. The cache rides the Message
-        like the QoS0 `_fb` cache; retained-store replays are excluded
-        for the same lifetime reason. Returns False to fall back to `_send`."""
+        """QoS1/2 fan-out fast path: hand the subscriber's frame to the
+        sink as the segments [head, pid, tail] of `_split_frame` — the
+        payload is never re-serialised per target. Retained-store replays
+        are excluded for the `_fb` cache's lifetime reason. Returns False
+        to fall back to `_send`."""
         ws = getattr(self.sink, "send_segments", None)
         if ws is None or msg.headers.get("retained"):
             return False
@@ -999,28 +1040,41 @@ class Channel:
         _prof.begin("egress.send")
         sent = 0
         try:
-            fbq = getattr(msg, "_fbq", None)
-            if fbq is None:
-                fbq = {}
-                msg._fbq = fbq
-            key = (self.version, q.qos, q.retain, q.topic)
-            ent = fbq.get(key)
+            ent = self._split_frame(msg, q.qos, q.retain)
             if ent is None:
-                tb = q.topic.encode("utf-8")
-                if len(tb) > 0xFFFF:
-                    return False  # _send raises the codec's exact error
-                ent = fbq[key] = SS.split_publish(
-                    tb, q.payload, q.qos, q.retain, False, self.version,
-                    q.properties,
-                )
-            head, tail = ent
-            ws([head, SS.pid_bytes(q.packet_id), tail])
+                return False  # _send raises the codec's exact error
+            ws([ent[0], SS.pid_bytes(q.packet_id), ent[1]])
             self.broker.metrics.inc("packets.sent")
             self.broker.metrics.inc("dispatch.serialize.frames")
             sent = 1
             return True
         finally:
             _prof.end(sent)
+
+    def _split_frame(self, msg: Message, qos: int, retain: bool):
+        """`msg`'s PUBLISH serialised ONCE per (version, qos, retain,
+        topic) as a (head, tail) pair around the packet-id slot
+        (mqtt/slab_serializer.split_publish — bytes identical to
+        frame.serialize). The cache rides the Message like the QoS0 `_fb`
+        cache, so a message's first sends (`_send_pub_split`) and its
+        sends out of the session queues (`_send_run`) share one
+        serialisation over all its subscribers. None: an oversize topic."""
+        fbq = getattr(msg, "_fbq", None)
+        if fbq is None:
+            fbq = msg._fbq = {}
+        key = (self.version, qos, retain, msg.topic)
+        ent = fbq.get(key)
+        if ent is None:
+            from emqx_tpu.mqtt import slab_serializer as SS
+
+            tb = msg.topic.encode("utf-8")
+            if len(tb) > 0xFFFF:
+                return None
+            ent = fbq[key] = SS.split_publish(
+                tb, msg.payload, qos, retain, False, self.version,
+                msg.properties,
+            )
+        return ent
 
     def _queue_dropped(self, msg: Message) -> None:
         """The session's full queue dropped `msg` (MQueue drops the

@@ -74,6 +74,12 @@ class Hooks:
             if cb(*args) is STOP:
                 return
 
+    def sync_callbacks(self, name: str) -> List[Callable]:
+        """The callbacks `run(name, ...)` calls, in its order: for a
+        caller that resolves the chain once for a batch and then runs it
+        per item, honouring STOP as `run` does. Empty = `run` is a no-op."""
+        return [e[2] for e in self._table.get(name, ()) if not e[3]]
+
     def run_fold(self, name: str, args: tuple, acc: Any) -> Any:
         """Fold acc through the chain.
 

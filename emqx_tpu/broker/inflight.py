@@ -47,12 +47,28 @@ class Inflight:
     def get(self, packet_id: int) -> Optional[InflightEntry]:
         return self._d.get(packet_id)
 
-    def insert(self, packet_id: int, msg: Message, phase: str = "publish"):
+    def insert(
+        self,
+        packet_id: int,
+        msg: Message,
+        phase: str = "publish",
+        now: Optional[float] = None,
+    ):
+        """`now`: the caller's monotonic-clock reading, where it stamps a
+        whole refill with one (Session.refill)."""
         if msg is not None:
             # slab-escape site: the window outlives the dispatch tick —
             # a SlabMessage must own its bytes, not pin the read buffer
             msg.own_buffers()
-        self._d[packet_id] = InflightEntry(msg, phase, time.monotonic())
+        self._d[packet_id] = InflightEntry(
+            msg, phase, now or time.monotonic()
+        )
+
+    def room(self, want: int) -> int:
+        """How many of `want` more entries the window takes."""
+        if self.max_size <= 0:
+            return want
+        return min(want, max(0, self.max_size - len(self._d)))
 
     def update(self, packet_id: int, phase: str) -> bool:
         e = self._d.get(packet_id)

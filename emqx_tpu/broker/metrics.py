@@ -295,6 +295,11 @@ declare("egress.writes", COUNTER,
         "connection per batch boundary); packets.sent over this is how "
         "many packets one write carries")
 declare("packets.received", COUNTER, "MQTT packets read from clients")
+declare("channel.ack.runs", COUNTER,
+        "runs of PUBACK / PUBREC / PUBCOMP handled in one pass over the "
+        "session window (Channel._in_acks: a read chunk's run, or a lone "
+        "ack); the entries of section channel.ack_in over this is how "
+        "many acks one pass carries")
 declare("messages.received", COUNTER, "messages entering dispatch")
 declare("messages.delivered", COUNTER, "deliveries handed to subscribers")
 declare("messages.dropped", COUNTER, "messages dropped before dispatch")

@@ -8,7 +8,8 @@ window has room (handled by the session)."""
 from __future__ import annotations
 
 from collections import deque
-from typing import Dict, Optional
+from itertools import repeat, starmap
+from typing import Dict, List, Optional
 
 from emqx_tpu.broker.message import Message
 
@@ -60,14 +61,27 @@ class MQueue:
         return dropped
 
     def out(self) -> Optional[Message]:
-        if self._len == 0:
-            return None
-        for p in sorted(self._qs, reverse=True):
-            q = self._qs[p]
-            if q:
-                self._len -= 1
-                return q.popleft()
-        return None
+        got = self.take(1)
+        return got[0] if got else None
+
+    def take(self, n: int) -> List[Message]:
+        """Dequeue up to `n` messages: the highest priority band first,
+        FIFO inside a band."""
+        out: List[Message] = []
+        if n <= 0 or self._len == 0:
+            return out
+        qs = self._qs
+        for p in sorted(qs, reverse=True) if len(qs) > 1 else tuple(qs):
+            q = qs[p]
+            if len(q) <= n - len(out):
+                out.extend(q)
+                q.clear()
+            else:  # n - len(out) calls of popleft, made by the C loop
+                out.extend(starmap(q.popleft, repeat((), n - len(out))))
+            if len(out) == n:
+                break
+        self._len -= len(out)
+        return out
 
     def peek_all(self):
         for p in sorted(self._qs, reverse=True):

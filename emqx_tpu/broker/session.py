@@ -6,7 +6,8 @@ on takeover the whole object moves to the new channel
 (emqx_session:takeover/resume/replay, emqx_session.erl:85-90).
 
 Pure state machine — no I/O. `deliver` returns the Publish packets to send;
-acks mutate the window and release queued messages.
+a run of acks (`ack_run`) clears the window and refills it from the queue
+once, whatever its length.
 """
 
 from __future__ import annotations
@@ -82,11 +83,20 @@ class Session:
 
     # -- packet ids -------------------------------------------------------
     def alloc_packet_id(self) -> int:
-        while True:
-            pid = self._next_pid
-            self._next_pid = pid % 65535 + 1
-            if not self.inflight.contains(pid):
-                return pid
+        return self.alloc_packet_ids(1)[0]
+
+    def alloc_packet_ids(self, n: int) -> List[int]:
+        """`n` packet ids for `n` inserts to come: the counter walks on
+        (65535 wraps to 1) past every id the window holds."""
+        contains = self.inflight.contains
+        pid = self._next_pid
+        out: List[int] = []
+        while len(out) < n:
+            if not contains(pid):
+                out.append(pid)
+            pid = pid % 65535 + 1
+        self._next_pid = pid
+        return out
 
     # -- outgoing (broker -> client) --------------------------------------
     def deliver(
@@ -136,12 +146,68 @@ class Session:
             properties=dict(msg.properties),
         )
 
+    def ack_run(
+        self, acks
+    ) -> Tuple[List[Message], List[Tuple[int, bool]], List[Tuple[int, Message]]]:
+        """A run of the subscriber's acks, `(packet type, packet id)` pairs
+        in arrival order (a read chunk's, or one) -> (the acknowledged
+        messages, `(packet id, known)` of each PUBREC, the refills).
+
+        One pass: a PUBACK / PUBCOMP pops its id from the window (an
+        unknown id yields nothing; a PUBCOMP counts only in the rel
+        phase), a PUBREC moves its entry to the rel phase, and the window
+        is then refilled once from the queue (`refill`). A message leaves
+        the window only on its own ack."""
+        delete = self.inflight.delete
+        puback, pubrec = pkt.PUBACK, pkt.PUBREC
+        done: List[Message] = []
+        recs: List[Tuple[int, bool]] = []
+        for t, pid in acks:
+            if t == pubrec:
+                recs.append((pid, self.pubrec(pid)))
+                continue
+            e = delete(pid)
+            if (
+                e is not None
+                and e.msg is not None
+                and (t == puback or e.phase == "pubrel")
+            ):
+                done.append(e.msg)
+        return done, recs, (self.refill() if len(recs) < len(acks) else [])
+
+    def refill(self) -> List[Tuple[int, Message]]:
+        """Move queued messages into the room the window has, in the
+        queue's order -> `(packet id, message)` of each, in the window
+        (one clock reading for all) before the caller sends it."""
+        room = self.inflight.room(len(self.mqueue))
+        if not room:
+            return []
+        insert = self.inflight.insert
+        now = time.monotonic()
+        out = list(zip(self.alloc_packet_ids(room), self.mqueue.take(room)))
+        for pid, msg in out:
+            insert(pid, msg, "publish", now)
+        return out
+
+    def _publish_packets(self, refills) -> List[pkt.Publish]:
+        return [
+            self._publish_packet(msg, msg.qos, pid) for pid, msg in refills
+        ]
+
+    def _drain(self) -> List[pkt.Publish]:
+        return self._publish_packets(self.refill())
+
+    def _ack_one(
+        self, type_: int, packet_id: int
+    ) -> Tuple[Optional[Message], List[pkt.Publish]]:
+        done, _, more = self.ack_run(((type_, packet_id),))
+        return (done[0] if done else None), self._publish_packets(more)
+
     def puback(
         self, packet_id: int
     ) -> Tuple[Optional[Message], List[pkt.Publish]]:
         """QoS1 ack; returns (acked msg | None, replacement publishes)."""
-        e = self.inflight.delete(packet_id)
-        return (e.msg if e is not None else None), self._drain()
+        return self._ack_one(pkt.PUBACK, packet_id)
 
     def pubrec(self, packet_id: int) -> bool:
         """QoS2 phase 1 ack'd by receiver -> move to rel phase."""
@@ -154,20 +220,7 @@ class Session:
     def pubcomp(
         self, packet_id: int
     ) -> Tuple[Optional[Message], List[pkt.Publish]]:
-        e = self.inflight.delete(packet_id)
-        ok = e is not None and e.phase == "pubrel"
-        return (e.msg if ok else None), self._drain()
-
-    def _drain(self) -> List[pkt.Publish]:
-        out: List[pkt.Publish] = []
-        while not self.inflight.is_full():
-            msg = self.mqueue.out()
-            if msg is None:
-                break
-            pid = self.alloc_packet_id()
-            self.inflight.insert(pid, msg)
-            out.append(self._publish_packet(msg, msg.qos, pid))
-        return out
+        return self._ack_one(pkt.PUBCOMP, packet_id)
 
     # -- incoming QoS2 (client -> broker) ---------------------------------
     def await_rel(self, packet_id: int) -> bool:

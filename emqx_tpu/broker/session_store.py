@@ -91,8 +91,8 @@ class StoreInflight(Inflight):
         self.store = store
         self.slot = slot
 
-    def insert(self, packet_id: int, msg, phase: str = "publish"):
-        super().insert(packet_id, msg, phase)
+    def insert(self, packet_id: int, msg, phase: str = "publish", now=None):
+        super().insert(packet_id, msg, phase, now)
         self.store.inflight_insert(self.slot, packet_id, msg, phase)
 
     def update(self, packet_id: int, phase: str) -> bool:

@@ -186,9 +186,9 @@ def split_publish(
     return bytes(head), pb + bytes(p)
 
 
-def pid_bytes(pid: int) -> bytes:
-    """The 2-byte packet-id patch between a split frame's head/tail."""
-    return _U16BE.pack(pid)
+# pid_bytes(pid) -> the 2-byte packet-id patch between a split frame's
+# head and tail (the bound C method: called once per QoS1/2 packet out)
+pid_bytes = _U16BE.pack
 
 
 # tiny fixed frames for the rel phase: PUBREL with rc=SUCCESS and no

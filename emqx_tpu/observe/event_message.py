@@ -165,14 +165,23 @@ class EventMessage:
         )
 
     def attach(self, hooks) -> None:
-        hooks.add("client.connected", self.on_client_connected, tag="event_message")
-        hooks.add("client.disconnected", self.on_client_disconnected,
-                  tag="event_message")
-        hooks.add("session.subscribed", self.on_session_subscribed,
-                  tag="event_message")
-        hooks.add("session.unsubscribed", self.on_session_unsubscribed,
-                  tag="event_message")
-        hooks.add("message.delivered", self.on_message_delivered,
-                  tag="event_message")
-        hooks.add("message.acked", self.on_message_acked, tag="event_message")
-        hooks.add("message.dropped", self.on_message_dropped, tag="event_message")
+        """The client and session events always; the three per-message
+        events only where enabled, so a disabled one costs the serving
+        path no callback at all (an empty `message.acked` chain is what
+        lets an ack run skip the hook). Call again after changing
+        `enabled`: every callback is registered anew."""
+        for name, cb in (
+            ("client.connected", self.on_client_connected),
+            ("client.disconnected", self.on_client_disconnected),
+            ("session.subscribed", self.on_session_subscribed),
+            ("session.unsubscribed", self.on_session_unsubscribed),
+            ("message.delivered", self.on_message_delivered),
+            ("message.acked", self.on_message_acked),
+            ("message.dropped", self.on_message_dropped),
+        ):
+            hooks.delete(name, "event_message")
+            if (
+                not name.startswith("message.")
+                or name.replace(".", "_") in self.enabled
+            ):
+                hooks.add(name, cb, tag="event_message")

@@ -140,6 +140,7 @@ class RuleEngine:
         # evaluated inside serving launches (docs/semantic_routing.md).
         # None = every rule stays on the per-message hook path.
         self.device_filter = None
+        self._hooks: Optional[Hooks] = None  # set by `attach`
 
     # -- device-predicate plane (rules/compile.py) -------------------------
     def attach_device(self) -> None:
@@ -276,6 +277,7 @@ class RuleEngine:
             if not replace and rule_id in self._rules:
                 raise ValueError(f"rule {rule_id!r} already exists")
             self._rules[rule_id] = rule
+        self._sync_message_hooks()
         self.refresh_device()
         return rule
 
@@ -283,6 +285,7 @@ class RuleEngine:
         with self._lock:
             existed = self._rules.pop(rule_id, None) is not None
         if existed:
+            self._sync_message_hooks()
             self.refresh_device()
         return existed
 
@@ -305,21 +308,8 @@ class RuleEngine:
 
     def attach(self, hooks: Hooks) -> None:
         hooks.add("message.publish", self._on_publish, priority=120)
-        hooks.add(
-            "message.delivered",
-            lambda ci, msg: self._any_enabled()
-            and self._fire(EV.message_delivered(ci, msg)),
-        )
-        hooks.add(
-            "message.acked",
-            lambda ci, m: self._any_enabled()
-            and self._fire(EV.message_acked(ci, m)),
-        )
-        hooks.add(
-            "message.dropped",
-            lambda msg, reason: self._any_enabled()
-            and self._fire(EV.message_dropped(msg, reason)),
-        )
+        self._hooks = hooks
+        self._sync_message_hooks()
         hooks.add(
             "client.connected",
             lambda ci, _ch: self._any_enabled()
@@ -340,6 +330,36 @@ class RuleEngine:
             lambda ci, f: self._any_enabled()
             and self._fire(EV.session_unsubscribed(ci, f)),
         )
+
+    def _sync_message_hooks(self) -> None:
+        """`message.delivered` / `.acked` / `.dropped` run once per
+        delivery, ack and drop, so each is attached only while some rule's
+        FROM names its event: a broker without such a rule keeps those
+        chains free of this engine (an empty `message.acked` chain is
+        what lets an ack run skip the hook). Whether a rule is *enabled*
+        stays a live check inside the callback."""
+        hooks = self._hooks
+        if hooks is None:
+            return
+        named = {
+            EV.event_topic_to_name(t)
+            for rule in self.rules()
+            for t in rule.query.topics
+            if t.startswith("$events/")
+        }
+        for name, ctx in (
+            ("message.delivered", EV.message_delivered),
+            ("message.acked", EV.message_acked),
+            ("message.dropped", EV.message_dropped),
+        ):
+            hooks.delete(name, "rule_engine")
+            if name in named:
+                hooks.add(
+                    name,
+                    lambda a, b, ctx=ctx: self._any_enabled()
+                    and self._fire(ctx(a, b)),
+                    tag="rule_engine",
+                )
 
     def _on_publish(self, msg: Optional[Message]):
         """'message.publish' fold callback: fire rules, pass msg through.
