@@ -14,6 +14,8 @@ import zlib
 
 import numpy as np
 
+from harness import share
+
 FILLER_ROWS = 4096
 
 
@@ -22,30 +24,74 @@ class Table:
     template. `per_subscriber` families give subscriber s the filters with
     {s} = s and {j} = 0..per_subscriber-1. `count` families put one filter on
     each of the `count` most popular device ids d (rank -> id by the seed),
-    held by subscriber (d + holder_offset) mod subscribers, so that no client
-    holds two filters matching one topic."""
+    held by subscriber (d + holder_offset) mod the plain subscribers, so that
+    no client holds two filters matching one topic. `share` families
+    (share.py) are `$share` groups: each takes a block of connections of its
+    own after the plain subscribers, so a connection is a plain subscriber
+    or a member of exactly one group, and within a group no two real
+    filters match one topic.
+
+    What a run is judged by is the *receiver class*: a plain subscriber is
+    its own, a group's members are one, named by the first member's
+    connection (`class_of`). `classes()` gives each class's real filters,
+    which is what the reference is given; `filters_of` is what a connection
+    puts on the wire."""
 
     def __init__(self, spec, seed):
         self.spec = spec
         self.n_sub = spec["subscribers"]
         self.rank_to_id = rank_to_id(spec["id_space"], seed)
+        in_groups = sum(f["groups"] * f["members"] for f in spec["families"]
+                        if "share" in f)
+        self.n_plain = self.n_sub - in_groups
+        if self.n_plain < 0:
+            raise ValueError(f"{in_groups} group members in {self.n_sub} connections")
+        self.groups = share.groups(spec["families"], self.n_plain)
+        self.class_of = np.arange(self.n_sub, dtype=np.int64)
+        self.members_of = np.ones(self.n_sub, np.int64)  # by class
+        self._group_at = {}
+        for group in self.groups:
+            first, members = group[:2]
+            self.class_of[first:first + members] = first
+            self.members_of[first] = members
+            self._group_at[first] = group
 
-    def filters_of(self, s):
+    def _plain_filters(self, s):
         out = []
         for fam in self.spec["families"]:
             if "per_subscriber" in fam:
                 out += [fam["filter"].format(s=s, j=j)
                         for j in range(fam["per_subscriber"])]
-            else:
+            elif "count" in fam:
                 for d in self.rank_to_id[:fam["count"]]:
-                    if (int(d) + fam["holder_offset"]) % self.n_sub == s:
+                    if (int(d) + fam["holder_offset"]) % self.n_plain == s:
                         out.append(fam["filter"].format(d=int(d)))
         return out
 
+    def filters_of(self, s):
+        if s < self.n_plain:
+            return self._plain_filters(s)
+        _, _, name, real = self._group_at[int(self.class_of[s])]
+        return [share.wire(name, flt) for flt in real]
+
     def n_filters(self):
+        """Subscriptions: every member of a group holds each of its filters."""
+        return self.n_class_filters() + sum(
+            (members - 1) * len(real) for _, members, _, real in self.groups)
+
+    def classes(self):
+        """-> (class, its connections, its real filters) of every class."""
+        for s in range(self.n_plain):
+            yield s, range(s, s + 1), self._plain_filters(s)
+        for first, members, _, real in self.groups:
+            yield first, range(first, first + members), real
+
+    def n_class_filters(self):
+        """What the reference holds: one entry per class and real filter."""
         return sum(
-            fam["per_subscriber"] * self.n_sub if "per_subscriber" in fam
-            else fam["count"] for fam in self.spec["families"])
+            fam["per_subscriber"] * self.n_plain if "per_subscriber" in fam
+            else fam.get("count", 0) for fam in self.spec["families"]) \
+            + sum(len(real) for _, _, _, real in self.groups)
 
 
 RANK_CLASSES = 4

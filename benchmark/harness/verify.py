@@ -1,15 +1,21 @@
 """The comparison that decides `correct`, and the reduction from the
 generators' records to the end-to-end metrics. The reference (reference.py)
 answers every message the run sent, on the seed's own table; what subscriber
-sockets received is compared with it as per-subscriber multisets. Exact: every
-limit is 0, except the share of the window's messages the device routed, which
-has the cell's own floor (traffic file, `device_share_min`)."""
+sockets received is compared with it as multisets per receiver class
+(traffic.py, Table: a plain subscriber is its own class, a `$share` group's
+members are one, so `missing` is also a group that did not get a message owed
+to it and `unexpected` a second member that got it too). Exact: every limit is
+0, except the share of the window's messages the device routed, which has the
+cell's own floor (traffic file, `device_share_min`), and, where the traffic
+file states it, the fullest member's share of its group's deliveries
+(`share_member_share_max`, share.py)."""
 
 import numpy as np
 
+from harness import share
 from harness.traffic import Stream
 
-SEQ_BITS = 40  # a delivery's key: subscriber << SEQ_BITS | sequence number
+SEQ_BITS = 40  # a delivery's key: receiver class << SEQ_BITS | sequence number
 SEQ_MASK = (1 << SEQ_BITS) - 1
 
 # any of these above zero means the degrade ladder, a shed, a drop or an
@@ -57,9 +63,10 @@ def expected(matcher, table, traffic, seed, sent):
 
 
 def received(sub_results):
-    """-> (keys, crc, receipt time, redelivered) over every delivery the
-    subscriber sockets got; DUP-flagged redeliveries (legal under QoS1) are
-    counted and left out of the multisets."""
+    """-> (keys by connection, not yet by class; crc; receipt time;
+    redelivered) over every delivery the subscriber sockets got; DUP-flagged
+    redeliveries (legal under QoS1) are counted and left out of the
+    multisets."""
     keys, crcs, ts, dups = [], [], [], 0
     for r in sub_results:
         keep = np.ones(len(r["seq"]), bool)
@@ -127,7 +134,9 @@ def judge(matcher, table, traffic, seed, pub_results, sub_results, window,
         else expected(matcher, table, traffic, seed, sent)
     got_keys, got_crc, got_t, redelivered = received(sub_results)
     if control is not None:
-        got_keys, got_crc, got_t = control(exp_keys, exp_crc, fan)
+        got_keys, got_crc, got_t = control(exp_keys, exp_crc, fan, table)
+    got_subs = got_keys >> SEQ_BITS
+    got_keys = (table.class_of[got_subs] << SEQ_BITS) | (got_keys & SEQ_MASK)
     missing, unexpected, corrupt, short = compare(
         exp_keys, exp_crc, got_keys, got_crc)
     unacked = int((was_sent & np.isnan(ack_t)).sum())
@@ -181,6 +190,10 @@ def judge(matcher, table, traffic, seed, pub_results, sub_results, window,
         "device_share_min": [min(device_share(*w) for w in prom_windows),
                              traffic["device_share_min"]],
     }
+    if "share_member_share_max" in traffic:
+        value, out["share"] = share.member_share(got_subs, table)
+        out["checks"]["share_member_share_max"] = [
+            value, traffic["share_member_share_max"]]
     out["correct"] = all(v >= lim if name.endswith("_min") else v <= lim
                          for name, (v, lim) in out["checks"].items())
     return out

@@ -317,18 +317,21 @@ def drive(args, manifest, cell, config, traffic, table, dep, gens, hooks):
             "pubs": [(c, place(c, plans, split_of)) for c in part]})
 
     # the reference is built while the generators connect and the table
-    # loads: one trie over all subscribers, whatever node holds them (a
-    # cluster has to deliver exactly what one broker would), and the
-    # (filter, node) pairs every node's replica of the route table has to hold
+    # loads: one trie over all receiver classes (Table: a plain subscriber, or
+    # a `$share` group, owed each matching message once) with their real
+    # filters, whatever node holds them (a cluster has to deliver exactly what
+    # one broker would), and the (filter, node) pairs every node's replica of
+    # the route table has to hold
     matcher, routes = Matcher(), set()
-    for s in range(n_sub):
-        for flt in table.filters_of(s):
-            matcher.insert(flt, s)
+    for cls, conns, filters in table.classes():
+        for flt in filters:
+            matcher.insert(flt, cls)
             if len(nodes) > 1:
-                routes.add((flt, node_of(s, len(nodes))))
+                routes.update((flt, node_of(s, len(nodes))) for s in conns)
     n_filters = table.n_filters()
-    if matcher.count != n_filters:
-        raise RuntimeError(f"reference holds {matcher.count} filters, not {n_filters}")
+    if matcher.count != table.n_class_filters():
+        raise RuntimeError(f"reference holds {matcher.count} filters, not "
+                           f"{table.n_class_filters()}")
     gens.gather("sub", "connected", START_TIMEOUT_S)
     gens.gather("pub", "connected", START_TIMEOUT_S)
     workers = workers_of(dep)
@@ -362,7 +365,10 @@ def drive(args, manifest, cell, config, traffic, table, dep, gens, hooks):
     # programs are compiled (or loaded from the cache) before it. The first
     # launch after a load is slow (compile, upload): a stage marked
     # `until_first_batch` ends early once a device has served a batch. The
-    # whole warm-up with the settling has a fixed length, so set-up is steady.
+    # whole warm-up with the settling has a fixed length, so set-up is steady,
+    # but for a checkout's first run, which compiles: a stage that names its
+    # `bucket` is held beyond its seconds, `cold_s` at the most, until that
+    # bucket has served a batch, so that no program is compiled in the window.
     t_warm = time.monotonic()
     buckets_loaded = buckets()
     for stage in traffic["warmup"]:
@@ -374,6 +380,10 @@ def drive(args, manifest, cell, config, traffic, table, dep, gens, hooks):
                 break
         else:
             sleep_until(t_end)
+        while "bucket" in stage and time.monotonic() < t_end + stage["cold_s"] \
+                and buckets().get(stage["bucket"], 0) \
+                == buckets_loaded.get(stage["bucket"], 0):
+            sleep_until(time.monotonic() + 0.5)
     buckets_warm = buckets()
     t_loop = time.monotonic() + 0.2
     t_open = max(t_loop + traffic["settle_s"], t_warm + traffic["settle_s"]
@@ -553,12 +563,15 @@ def drive(args, manifest, cell, config, traffic, table, dep, gens, hooks):
             "subscriptions": held, "rss_bytes": rss}
         if want is not None and len(want[0]):
             # of the deliveries due, those whose subscriber is on another
-            # node than their publisher (the generators' side: `place`)
+            # node than their publisher (the generators' side: `place`; a
+            # group is counted where its first member is)
             subs, pubs = want[0] >> verify.SEQ_BITS, (want[0] & verify.SEQ_MASK) % n_pub
             result["counts"]["cluster"]["cross_node_share"] = float(
                 (node_of(subs, len(nodes)) != node_of(pubs, len(nodes))).mean())
     if not rehearsal and not args.trace:  # what an untraced run can read of the layers
         result["counts"]["layers"] = {k: v["value"] for k, v in layers.items()}
+    if "share" in j:  # a cell with groups: who received, and how evenly
+        result["counts"]["share"] = j["share"]
     if "rate_schedule" in traffic:
         result["counts"]["stages"] = verify.stages(
             j, traffic["rate_schedule"], pub_results[0]["t_loop"])

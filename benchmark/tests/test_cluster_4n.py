@@ -43,7 +43,7 @@ def read(name, ctx):
 def test_the_manifest_holds_the_cell_its_configuration_and_its_metrics():
     manifest, faults = manifest_check.load_and_check(ROOT)
     assert faults == []
-    cell = manifest["workloads"][-1]
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["name"], cell["config"], cell["chips"]) == (CELL, "cluster_4n", 4)
     by_name = {e["name"]: e for e in manifest["per_layer"]}
     for name in NEW:
@@ -58,7 +58,8 @@ def test_the_manifest_holds_the_cell_its_configuration_and_its_metrics():
     assert config["broker"]["session"] == mixed["broker"]["session"]
     assert config["nodes"]["count"] == 4
     assert config["broker"]["cluster"]["rpc_mode"] == "sync"
-    assert sorted(config["reduced"]) == sorted(manifest["configs"][-1]["reduced"])
+    assert sorted(config["reduced"]) == sorted(next(
+        c for c in manifest["configs"] if c["name"] == "cluster_4n")["reduced"])
     sat = bench_run.load_json("traffic", "mixed_1m.sat.json")
     mine = bench_run.load_json("traffic", CELL + ".json")
     for key in sat:  # one mix on one node and on four

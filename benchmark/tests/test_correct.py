@@ -40,10 +40,11 @@ def test_the_reference_in_its_own_place_is_exact():
     assert verify.compare(keys, crc, keys[::-1], crc[seq][::-1])[:3] == (0, 0, 0)
 
 
-@pytest.mark.parametrize("name", sorted(controls.CONTROLS))
+@pytest.mark.parametrize("name", ["altered_payload", "at_most_once", "stale_table"])
 def test_control_is_not_correct(name):
+    """(The two controls of a table with groups: test_share.py.)"""
     keys, crc, fan = small_answer()
-    got_keys, got_crc, _ = controls.CONTROLS[name](keys, crc, fan)
+    got_keys, got_crc, _ = controls.CONTROLS[name](keys, crc, fan, None)
     missing, unexpected, corrupt, _ = verify.compare(keys, crc, got_keys, got_crc)
     assert missing + unexpected + corrupt >= 1
 
