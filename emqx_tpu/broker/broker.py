@@ -207,18 +207,14 @@ class Broker:
             self.metrics.inc("semantic.subscribe.rejected")
             embedding = None
         if group is not None:
-            # one route ref per group (matched by delete on group-empty)
-            if self.shared.subscribe(group, real, sub):
-                rk = self.shared.route_filter(group, real)
-                self.router.add_route(rk)
-                if self.cluster is not None:
-                    self.cluster._replicate_add(rk)
-                    self.cluster.shared_join(real, group)
-            fid = self.router.filter_id(real)
-            if fid is not None:
-                gid = self.grouptab.ensure_group(fid, real, group)
-                g = self.shared.group(real, group)
-                self.grouptab.set_len(gid, len(g.members) if g else 0)
+            # section `broker.share_subscribe`: what a table of groups
+            # adds to a SUBSCRIBE (membership, the group's route, its
+            # device row); entries = shared subscriptions
+            _prof.begin("broker.share_subscribe")
+            try:
+                self._subscribe_shared(group, real, sub)
+            finally:
+                _prof.end()
         else:
             entry = self._subs.setdefault(real, {})
             prev = entry.get(sid)
@@ -269,6 +265,30 @@ class Broker:
                         self.subtab.add(fid, sub.slot)
         self.metrics.gauge_set("subscriptions.count", self.subscription_count())
 
+    def _subscribe_shared(self, group: str, real: str, sub) -> None:
+        # one route ref per group (matched by delete on group-empty)
+        if self.shared.subscribe(group, real, sub):
+            rk = self.shared.route_filter(group, real)
+            self.router.add_route(rk)
+            if self.cluster is not None:
+                self.cluster._replicate_add(rk)
+                self.cluster.shared_join(real, group)
+        fid = self.router.filter_id(real)
+        if fid is not None:
+            gid = self.grouptab.ensure_group(fid, real, group)
+            g = self.shared.group(real, group)  # just joined: it exists
+            self.grouptab.set_len(gid, len(g.members))
+            # a new row's base is 0: the device picks from the group's own
+            # (random) start, like the host path
+            self.grouptab.set_rr(gid, g.rr_index)
+        self._shared_gauges()
+
+    def _shared_gauges(self) -> None:
+        self.metrics.gauge_set(
+            "shared.subscriptions.count", self.shared.count()
+        )
+        self.metrics.gauge_set("grouptab.groups", len(self.grouptab))
+
     def unsubscribe(self, sid: str, filter_: str) -> bool:
         group, real = T.parse_share(filter_)
         if group is not None:
@@ -292,6 +312,10 @@ class Broker:
                     self.grouptab.repin(gid, g.members.keys(), g.sticky_sid)
             if removed:
                 self.released += 1
+                self._shared_gauges()
+                self.metrics.gauge_set(
+                    "subscriptions.count", self.subscription_count()
+                )
             return removed
         entry = self._subs.get(real)
         if not entry or sid not in entry:
@@ -920,6 +944,7 @@ class Broker:
         need_fids = picks is None and bool(self.shared._table)
         matched_l = matched.tolist() if need_fids else None
         fanouts: List[int] = []
+        pick_stats = [0, 0]  # picks handed over, stale among them
         for i, m in enumerate(msgs):
             t_ns = (
                 rec.now_ns()
@@ -954,7 +979,7 @@ class Broker:
                 n = self._dispatch_row(
                     m, bits, fids, msg_picks, touched_gids,
                     slots=slots, match_memo=match_memo, fid_memo=fid_memo,
-                    stats=fanouts, dedup=sem,
+                    stats=fanouts, dedup=sem, pick_stats=pick_stats,
                 )
             if t_ns:
                 rec.deliver(
@@ -976,6 +1001,10 @@ class Broker:
                 self.metrics.inc("messages.delivered", delivered)
         if touched_gids:
             self._sync_group_counters(touched_gids)
+        if pick_stats[0]:
+            self.metrics.inc("shared.picks", pick_stats[0])
+            if pick_stats[1]:
+                self.metrics.inc("shared.picks.stale", pick_stats[1])
         if fell_back:
             self.metrics.inc("messages.routed.device_fallback", fell_back)
         self.metrics.inc("messages.routed.device", len(msgs) - fell_back)
@@ -990,7 +1019,7 @@ class Broker:
         touched_gids: Optional[set] = None, *, slots=None,
         match_memo: Optional[Dict] = None,
         fid_memo: Optional[Dict] = None, stats: Optional[List] = None,
-        dedup: bool = False,
+        dedup: bool = False, pick_stats: Optional[List[int]] = None,
     ) -> int:
         """Deliver one routed message from its device outputs: subscriber
         slot list (compact path) or bitmap (dense path) -> plain subs;
@@ -1068,25 +1097,42 @@ class Broker:
                     continue
             n += self._deliver_one(sub, msg)
         if picks is not None:
-            # device-resolved $share picks: host does delivery + failover
+            # device-resolved $share picks: host does delivery + failover.
+            # Section `shared.dispatch_picked`: one entry per pick handed
+            # over; a pick is stale when its group is gone, its filter no
+            # longer matches, or no member took the message
             gids, idxs = picks
-            for gid, idx in zip(gids, idxs):
-                if gid < 0:
-                    continue
-                info = self.grouptab.info(int(gid))
-                if info is None:
-                    continue  # group dropped while the batch was in flight
-                real, gname = info
-                # staleness net, same as slots: re-verify the filter
-                ok = match_memo.get((topic, real))
-                if ok is None:
-                    ok = T.match(topic, real)
-                    match_memo[(topic, real)] = ok
-                if not ok:
-                    continue
-                n += self.shared.dispatch_picked(real, gname, int(idx), msg)
-                if touched_gids is not None:
-                    touched_gids.add(int(gid))
+            handed = served = 0
+            _prof.begin("shared.dispatch_picked")
+            try:
+                for gid, idx in zip(gids, idxs):
+                    if gid < 0:
+                        continue
+                    handed += 1
+                    info = self.grouptab.info(int(gid))
+                    if info is None:
+                        continue  # group dropped while the batch was in flight
+                    real, gname = info
+                    # staleness net, same as slots: re-verify the filter
+                    ok = match_memo.get((topic, real))
+                    if ok is None:
+                        ok = T.match(topic, real)
+                        match_memo[(topic, real)] = ok
+                    if not ok:
+                        continue
+                    d = self.shared.dispatch_picked(
+                        real, gname, int(idx), msg
+                    )
+                    n += d
+                    served += d
+                    if touched_gids is not None:
+                        touched_gids.add(int(gid))
+            finally:
+                _prof.end(handed)
+            if pick_stats is not None:
+                # the caller batches the counters: one add per launch
+                pick_stats[0] += handed
+                pick_stats[1] += handed - served
         else:
             for fid in fids:
                 fid = int(fid)

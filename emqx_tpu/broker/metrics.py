@@ -300,6 +300,19 @@ declare("channel.ack.runs", COUNTER,
         "session window (Channel._in_acks: a read chunk's run, or a lone "
         "ack); the entries of section channel.ack_in over this is how "
         "many acks one pass carries")
+declare("shared.picks", COUNTER,
+        "device-resolved $share picks handed to the host for delivery "
+        "(Broker._dispatch_row's picks branch; one add per launch); over "
+        "the count of ingest.batch.size this is picks per launch")
+declare("shared.picks.stale", COUNTER,
+        "picks that delivered nothing: the group was dropped or its "
+        "filter no longer matched while the batch was in flight, or no "
+        "member took the message (on a cluster node also: another node "
+        "leads this message's pick)")
+declare("grouptab.uploads", COUNTER,
+        "whole re-uploads of the device's group arrays: a launch found "
+        "GroupTable's epoch bumped (growth, or its op-log past "
+        "OPLOG_MAX) and could not scatter deltas")
 declare("messages.received", COUNTER, "messages entering dispatch")
 declare("messages.delivered", COUNTER, "deliveries handed to subscribers")
 declare("messages.dropped", COUNTER, "messages dropped before dispatch")
@@ -457,6 +470,12 @@ declare("cluster.retain.dump_truncated", COUNTER)
 # gauges (emqx_stats.erl analogs + monitor extras)
 declare("connections.count", GAUGE)
 declare("subscriptions.count", GAUGE)
+declare("shared.subscriptions.count", GAUGE,
+        "$share subscriptions held: members over every (real filter, "
+        "group), SharedSub's running count; part of subscriptions.count")
+declare("grouptab.groups", GAUGE,
+        "(real filter, group) pairs with a row in the device's group "
+        "table (GroupTable)")
 declare("topics.count", GAUGE)
 declare("retained.count", GAUGE)
 declare("delayed.count", GAUGE)
@@ -821,6 +840,8 @@ SECTIONS: Tuple[str, ...] = (
     "prepare",              # stage: table snapshot + upload
     "ingest.finish",        # BatchIngest._finish: the per-message fut.set_result loop
     "host_dispatch",        # stage: settle-time fan-out
+    "shared.dispatch_picked",  # a message's device picks -> SharedSub.dispatch_picked (entries: picks handed over)
+    "broker.share_subscribe",  # the shared half of Broker.subscribe (entries: shared subscriptions)
     "housekeeping",         # the 1 Hz tick
     "cluster.forward.out",  # ClusterNode.forward_batch_remote: replica match, grouping, hand-off (entries: messages forwarded)
     "cluster.forward.in",   # the receiving half of a forward up to its dispatch (entries: messages)

@@ -34,9 +34,9 @@ def stable_hash(s: Optional[str]) -> int:
 class _Group:
     __slots__ = ("members", "rr_index", "sticky_sid")
 
-    def __init__(self) -> None:
+    def __init__(self, rr_start: int = 0) -> None:
         self.members: Dict[str, object] = {}  # sid -> Subscriber
-        self.rr_index = 0
+        self.rr_index = rr_start
         self.sticky_sid: Optional[str] = None
 
 
@@ -45,6 +45,11 @@ class SharedSub:
         self.strategy = strategy
         # real_filter -> {group -> _Group}
         self._table: Dict[str, Dict[str, _Group]] = {}
+        # running member count over every (real filter, group): count()
+        # feeds a gauge on every SUBSCRIBE, so a walk of the table there
+        # makes loading N shared subscriptions O(N^2). Only subscribe /
+        # unsubscribe below may add or drop a member
+        self._count = 0
         self._rng = _random.Random(0xEC0)
         # cluster mode: (real, group, msg) -> bool; exactly one member
         # node dispatches each message. Every member node already holds
@@ -64,8 +69,16 @@ class SharedSub:
         g = groups.get(group)
         created = False
         if g is None:
-            g = groups[group] = _Group()
+            # round_robin starts at a random member, as upstream's does
+            # (emqx_shared_sub.erl:234-285: `rand:uniform(Count) - 1` for
+            # a (group, topic) without a counter yet). A group is the pair
+            # (group name, real filter): a service subscribed on a
+            # thousand filters that see a message or two each would
+            # otherwise hand every one of them to its first member
+            g = groups[group] = _Group(self._rng.randrange(1 << 16))
             created = True
+        if sub.sid not in g.members:
+            self._count += 1
         g.members[sub.sid] = sub
         return created
 
@@ -76,6 +89,8 @@ class SharedSub:
             return False, False
         g = groups[group]
         removed = g.members.pop(sid, None) is not None
+        if removed:
+            self._count -= 1
         if g.sticky_sid == sid:
             g.sticky_sid = None
         empty = not g.members
@@ -86,11 +101,8 @@ class SharedSub:
         return removed, empty
 
     def count(self) -> int:
-        return sum(
-            len(g.members)
-            for groups in self._table.values()
-            for g in groups.values()
-        )
+        """Shared subscriptions held (members over all groups), O(1)."""
+        return self._count
 
     def subscriptions(self) -> List[Tuple[str, str, object]]:
         out = []

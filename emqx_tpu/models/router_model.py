@@ -1669,7 +1669,13 @@ class DeviceRouter:
         m_active = idx.shapes.m_active()
         if self.grouptab is not None and len(self.grouptab):
             self.grouptab.pack_fcap(idx.num_filters_capacity)
+            fulls = self._group_sync.full_resyncs
             group_tables = self._group_sync.sync(self.grouptab)
+            fulls = self._group_sync.full_resyncs - fulls
+            if fulls and self.metrics is not None:
+                # an epoch bump (growth, op-log overflow) since the last
+                # launch: the group arrays went up whole, not as deltas
+                self.metrics.inc("grouptab.uploads", fulls)
         else:
             group_tables = None
         if sem_on:

@@ -56,7 +56,8 @@ def collect(app) -> Dict:
             "detached_sessions": app.cm.detached_count(),
             "subscriptions": broker.subscription_count(),
             "routes": len(broker.router),
-            "shared_groups": broker.shared.count(),
+            "shared_subscriptions": broker.shared.count(),
+            "shared_groups": len(broker.grouptab),
             "retained": len(app.retainer),
             "route_index": {
                 "filters": len(broker.router.index),
