@@ -17,6 +17,7 @@ the session's registered deliver callback. Shared-subscription groups
 from __future__ import annotations
 
 import asyncio
+from itertools import repeat
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -97,6 +98,106 @@ class PendingDispatch:
 
     def __await__(self):
         return self._complete().__await__()
+
+
+def run_target(deliver):
+    """Where `deliver` offers a delivery run: the object that takes a
+    settled batch's deliveries through it in one call,
+    `handle_deliver_run([(msg, opts), ...])` (docs/protocol_plane.md "The
+    delivery run"), and the key they are collected under: the connection,
+    not the subscription. A deliverer offers one by being the closure its
+    target names: a function closed over the target alone whose code is
+    the target's `run_deliverer` (`Channel._make_deliverer`'s). The
+    closure a subscription already holds says it, so a million
+    subscriptions carry no object and no slot more. None (the pool's, a
+    gateway's, a persistent session's, the cluster's, a test's stub):
+    called per message."""
+    cells = getattr(deliver, "__closure__", None)
+    if cells is not None and len(cells) == 1:
+        target = cells[0].cell_contents
+        if getattr(target, "run_deliverer", None) is deliver.__code__:
+            return target
+    return None
+
+
+class DeliveryRuns:
+    """A settled batch's deliveries to the connections that offer a run
+    (`run_target`), collected in message order while the rows are
+    dispatched (`hand`) and handed over one call a connection
+    (`deliver`): before the batch's counts are final and before its
+    write boundary.
+
+    `counts[row]` is row's local fan-out. A collected delivery counts when
+    it is collected; what a run gives back is settled per message after
+    the runs and taken off again where nobody took it. An item the run
+    reports as failed was offered to its connection: a plain
+    subscription's then counts as when its deliverer raised, a pick fails
+    over to the group's next member. A run that raised as a whole took
+    nothing: its items go the per-message path, member and all.
+    `pick_stats`: picks handed over, stale among them. Nothing overtakes a pending
+    run: whoever delivers on the spot to a connection that may hold one
+    (a flagged row's CPU dispatch, a host-side group pick) calls
+    `deliver` first."""
+
+    __slots__ = ("broker", "counts", "pick_stats", "row", "_runs")
+
+    def __init__(self, broker: "Broker", rows: int):
+        self.broker = broker
+        self.counts = [0] * rows
+        self.pick_stats = [0, 0]
+        self.row = 0  # the row being dispatched
+        # target -> its run as four flat lists (messages, options, rows,
+        # origins: the Subscriber, or a pick's (real, group, idx)). No
+        # object per delivery is held over the batch: 48,000 deliveries
+        # a batch (`fanout_1k`) would be a young collector pass each
+        self._runs: Dict = {}
+
+    def hand(self, sub: "Subscriber", msg: Message, pick=None) -> None:
+        """One delivery of row `self.row`: into its connection's run, or
+        made on the spot where the deliverer offers none (raises what
+        that raises: the caller's NACK). `pick`: it is a $share pick's."""
+        target = run_target(sub.deliver)
+        if target is None:
+            sub.deliver(msg, sub.opts)
+            return
+        run = self._runs.get(target)
+        if run is None:
+            run = self._runs[target] = ([], [], [], [])
+        run[0].append(msg)
+        run[1].append(sub.opts)
+        run[2].append(self.row)
+        run[3].append(sub if pick is None else pick)
+
+    def deliver(self) -> None:
+        """Hand every pending run over, then settle what came back."""
+        if not self._runs:
+            return
+        runs, self._runs = self._runs, {}
+        back: List = []  # (msg, row, origin, candidates that refused it)
+        handed = carried = 0
+        for target, (msgs, opts, rows, origins) in runs.items():
+            try:
+                failed = target.handle_deliver_run(list(zip(msgs, opts)))
+            except Exception:  # noqa: BLE001 — the whole run, per message
+                back += zip(msgs, rows, origins, repeat(0))
+                continue
+            handed += 1
+            carried += len(msgs) - len(failed)
+            for j, _ in failed:
+                back.append((msgs[j], rows[j], origins[j], 1))
+        b = self.broker
+        b.metrics.inc("dispatch.runs", handed)
+        b.metrics.inc("dispatch.run.deliveries", carried)
+        for msg, row, org, refused in back:
+            if type(org) is tuple:
+                took = b.shared.dispatch_picked(*org, msg, refused=refused)
+                self.pick_stats[1] += 1 - took
+            elif refused:
+                b.metrics.inc("delivery.errors")
+                took = 0
+            else:
+                took = b._deliver_one(org, msg)
+            self.counts[row] += took - 1
 
 
 class Broker:
@@ -924,7 +1025,6 @@ class Broker:
             if forward and self.cluster is not None
             else None
         )
-        out: List[int] = []
         fell_back = 0
         touched_gids: set = set()
         match_memo: Dict[Tuple[str, str], bool] = {}
@@ -934,7 +1034,7 @@ class Broker:
         # batch-level fan-out prep (docs/protocol_plane.md): ONE
         # .tolist() per device output matrix up front — the per-message
         # loop below then runs on plain ints, with per-row metric
-        # observes batched into `fanouts` at the end. The old per-row
+        # observes batched at the end. The old per-row
         # numpy mask/filter chains were a top per-message dispatch cost.
         flags_l = np.asarray(flags).tolist()
         slots_ll = results.slots.tolist() if compact else None
@@ -943,55 +1043,64 @@ class Broker:
         # AND the device didn't already resolve the picks
         need_fids = picks is None and bool(self.shared._table)
         matched_l = matched.tolist() if need_fids else None
-        fanouts: List[int] = []
-        pick_stats = [0, 0]  # picks handed over, stale among them
+        # the batch's deliveries to one connection are one run
+        # (docs/protocol_plane.md "The delivery run"): collected per row,
+        # handed over after the rows; a row's count is final only then
+        runs = DeliveryRuns(self, len(msgs))
+        counts, pick_stats = runs.counts, runs.pick_stats
+        stamps: List[Tuple[int, int]] = []  # traced rows: (row, start)
         for i, m in enumerate(msgs):
-            t_ns = (
-                rec.now_ns()
-                if rec is not None and TRACE_HEADER in m.headers
-                else 0
-            )
+            if rec is not None and TRACE_HEADER in m.headers:
+                stamps.append((i, rec.now_ns()))
             if flags_l[i]:
                 fell_back += 1
                 tp("dispatch.fallback", topic=m.topic)
-                n = self._route_dispatch(m, r.match(m.topic))
+                runs.deliver()  # the CPU dispatch delivers on the spot
+                counts[i] = self._route_dispatch(m, r.match(m.topic))
+                continue
+            msg_picks = (
+                (picks[0][i], picks[1][i]) if picks is not None else None
+            )
+            if compact and not ovf_l[i]:
+                # -1 pads skip inside the dispatch loop
+                bits, slots = None, slots_ll[i]
+            elif compact:
+                bits = results.dense_rows[results.dense_index[i]]
+                # semantic winners live in the device slot row (the
+                # dense fallback covers only the TOPIC fan-out):
+                # union them back in — dup topic slots dedup below
+                slots = slots_ll[i] if sem else None
             else:
-                msg_picks = (
-                    (picks[0][i], picks[1][i]) if picks is not None else None
-                )
-                if compact and not ovf_l[i]:
-                    # -1 pads skip inside the dispatch loop
-                    bits, slots = None, slots_ll[i]
-                elif compact:
-                    bits = results.dense_rows[results.dense_index[i]]
-                    # semantic winners live in the device slot row (the
-                    # dense fallback covers only the TOPIC fan-out):
-                    # union them back in — dup topic slots dedup below
-                    slots = slots_ll[i] if sem else None
-                else:
-                    bits, slots = results.bitmaps[i], None
-                # matched rows are SPARSE (-1 holes between engines)
-                fids = (
-                    [f for f in matched_l[i] if f >= 0]
-                    if matched_l is not None
-                    else ()
-                )
-                n = self._dispatch_row(
-                    m, bits, fids, msg_picks, touched_gids,
-                    slots=slots, match_memo=match_memo, fid_memo=fid_memo,
-                    stats=fanouts, dedup=sem, pick_stats=pick_stats,
-                )
-            if t_ns:
-                rec.deliver(
-                    m, n, start_ns=t_ns, device_span=device_span,
-                    fallback=bool(flags_l[i]),
-                )
-            if fwd is not None:
-                n += fwd[i]
-            if n == 0:
-                self.hooks.run("message.dropped", m, "no_subscribers")
-                self.metrics.inc("messages.dropped.no_subscribers")
-            out.append(n)
+                bits, slots = results.bitmaps[i], None
+            # matched rows are SPARSE (-1 holes between engines)
+            fids = (
+                [f for f in matched_l[i] if f >= 0]
+                if matched_l is not None
+                else ()
+            )
+            runs.row = i
+            self._dispatch_row(
+                m, bits, fids, msg_picks, touched_gids,
+                slots=slots, match_memo=match_memo, fid_memo=fid_memo,
+                runs=runs, dedup=sem,
+            )
+        runs.deliver()
+        for i, t_ns in stamps:
+            rec.deliver(
+                msgs[i], counts[i], start_ns=t_ns, device_span=device_span,
+                fallback=bool(flags_l[i]),
+            )
+        out = counts if fwd is None else [n + f for n, f in zip(counts, fwd)]
+        if 0 in out:
+            for m, n in zip(msgs, out):
+                if n == 0:
+                    self.hooks.run("message.dropped", m, "no_subscribers")
+                    self.metrics.inc("messages.dropped.no_subscribers")
+        fanouts = (
+            [n for n, flag in zip(counts, flags_l) if not flag]
+            if fell_back
+            else counts
+        )
         if fanouts:
             # batched flight-recorder upkeep: same series, one lock
             self.metrics.inc("messages.received", len(fanouts))
@@ -1018,8 +1127,8 @@ class Broker:
         self, msg: Message, bits: Optional[np.ndarray], fids, picks=None,
         touched_gids: Optional[set] = None, *, slots=None,
         match_memo: Optional[Dict] = None,
-        fid_memo: Optional[Dict] = None, stats: Optional[List] = None,
-        dedup: bool = False, pick_stats: Optional[List[int]] = None,
+        fid_memo: Optional[Dict] = None,
+        runs: Optional[DeliveryRuns] = None, dedup: bool = False,
     ) -> int:
         """Deliver one routed message from its device outputs: subscriber
         slot list (compact path) or bitmap (dense path) -> plain subs;
@@ -1028,14 +1137,17 @@ class Broker:
         group delivery goes straight to the picked member with host-side
         failover only; otherwise the host runs the full pick.
         `slots` may be a plain int list (batch callers pre-.tolist() the
-        whole slot matrix; -1 pads are skipped here) — with `stats`
-        given, the fan-out lands in it and the per-row metric calls are
+        whole slot matrix; -1 pads are skipped here). With `runs` given
+        (the batch's `DeliveryRuns`, at this row) a delivery whose
+        deliverer offers a run is collected there and not made here, the
+        fan-out lands in `runs.counts`, final once the runs are handed
+        over, and the per-row metric calls are
         batched by the caller instead. `bits` AND `slots` together =
         the semantic overflow contract: the dense row carries the topic
         fan-out, the slot list carries the device row's semantic
         winners, and `dedup` guards double delivery (also set for mesh
         batches, where two 'tp' shards can emit the same slot)."""
-        if stats is None:
+        if runs is None:
             self.metrics.inc("messages.received")
         if match_memo is None:
             match_memo = {}
@@ -1065,6 +1177,7 @@ class Broker:
         slot_subs = self._slot_subs
         nsubs = len(slot_subs)
         seen = set() if dedup else None
+        hand = runs.hand if runs is not None else None
         for slot in slots:
             # -1 pads (compact rows) and slots past the local table
             # (another node's lanes) skip here — plain int compares,
@@ -1095,12 +1208,15 @@ class Broker:
                     match_memo[(topic, f)] = ok
                 if not ok:
                     continue
-            n += self._deliver_one(sub, msg)
+            n += self._deliver_one(sub, msg, hand)
         if picks is not None:
             # device-resolved $share picks: host does delivery + failover.
             # Section `shared.dispatch_picked`: one entry per pick handed
             # over; a pick is stale when its group is gone, its filter no
-            # longer matches, or no member took the message
+            # longer matches, or no member took the message. The section
+            # holds the group look-up, the re-verify, the member's choice
+            # and the hand-over; an in-process member's send happens in
+            # its connection's run, after the rows
             gids, idxs = picks
             handed = served = 0
             _prof.begin("shared.dispatch_picked")
@@ -1121,7 +1237,7 @@ class Broker:
                     if not ok:
                         continue
                     d = self.shared.dispatch_picked(
-                        real, gname, int(idx), msg
+                        real, gname, int(idx), msg, hand
                     )
                     n += d
                     served += d
@@ -1129,10 +1245,10 @@ class Broker:
                         touched_gids.add(int(gid))
             finally:
                 _prof.end(handed)
-            if pick_stats is not None:
+            if runs is not None:
                 # the caller batches the counters: one add per launch
-                pick_stats[0] += handed
-                pick_stats[1] += handed - served
+                runs.pick_stats[0] += handed
+                runs.pick_stats[1] += handed - served
         else:
             for fid in fids:
                 fid = int(fid)
@@ -1152,9 +1268,13 @@ class Broker:
                     ok = T.match(topic, name)
                     match_memo[(topic, name)] = ok
                 if ok:
+                    if runs is not None:
+                        runs.deliver()  # a host pick delivers on the spot
                     n += self.shared.dispatch_groups(name, msg)
-        if stats is not None:
-            stats.append(n)  # caller batches the metric upkeep
+        if runs is not None:
+            # += : a run handed over inside this row may have given
+            # some of the row's deliveries back already
+            runs.counts[runs.row] += n  # caller batches the metric upkeep
             return n
         self.metrics.observe("dispatch.fanout", n)
         if n:
@@ -1244,11 +1364,15 @@ class Broker:
             self.metrics.inc("messages.delivered", n)
         return n
 
-    def _deliver_one(self, sub: Subscriber, msg: Message) -> int:
+    def _deliver_one(self, sub: Subscriber, msg: Message, hand=None) -> int:
         """One raising deliverer must not poison the rest of the fan-out
-        (or, on the batch path, every other message in the batch)."""
+        (or, on the batch path, every other message in the batch).
+        `hand`: the batch's `DeliveryRuns.hand`, in the deliverer's place."""
         try:
-            sub.deliver(msg, sub.opts)
+            if hand is None:
+                sub.deliver(msg, sub.opts)
+            else:
+                hand(sub, msg)
             return 1
         except Exception:
             self.metrics.inc("delivery.errors")

@@ -90,7 +90,23 @@ class _ClosedSink:
 _CLOSED_SINK = _ClosedSink()
 
 
+def _deliverer(channel: "Channel"):
+    """A subscription's deliverer. It offers a delivery run
+    (`broker.run_target`): this closure over the channel alone, which the
+    channel names as `run_deliverer`, so that a settled batch's deliveries
+    through any of the connection's subscriptions go to one
+    `handle_deliver_run` call."""
+
+    def deliver(msg: Message, subopts: pkt.SubOpts) -> None:
+        channel.handle_deliver(msg, subopts)
+
+    return deliver
+
+
 class Channel:
+    # the code of the closures that offer this channel's run
+    run_deliverer = _deliverer(None).__code__
+
     def __init__(
         self,
         broker: Broker,
@@ -270,9 +286,9 @@ class Channel:
         refills in the queue's, each refill already in the window. One
         `egress.send` section (entries: packets); the sink writes them
         with the chunk's end. A refill is the split frame its message's
-        first sends share (`_split_frame`), and a `send_packet` where
-        `_send_pub_split` falls back too: a sink without `send_segments`,
-        a retained replay, an oversize topic."""
+        first sends share (`_split_frame`), and a `send_packet` where a
+        delivery run (`_send_deliveries`) falls back too: a sink without
+        `send_segments`, a retained replay, an oversize topic."""
         from emqx_tpu.mqtt.slab_serializer import pid_bytes
 
         n = len(recs) + len(refills)
@@ -875,10 +891,7 @@ class Channel:
         self._send(pkt.Suback(packet_id=p.packet_id, reason_codes=rcs))
 
     def _make_deliverer(self, opts: pkt.SubOpts):
-        def deliver(msg: Message, subopts: pkt.SubOpts) -> None:
-            self.handle_deliver(msg, subopts)
-
-        return deliver
+        return _deliverer(self)
 
     async def _in_unsubscribe(self, p: pkt.Unsubscribe) -> None:
         filters = await self.hooks.arun_fold(
@@ -950,113 +963,190 @@ class Channel:
 
     # -- outbound deliveries ----------------------------------------------
     def handle_deliver(self, msg: Message, opts: pkt.SubOpts) -> None:
-        if self.mountpoint and msg.topic.startswith(self.mountpoint):
-            # unmount on the way out (emqx_channel.erl:970-976)
-            import copy
+        """One delivery: the run of one (the retainer's replays, the CPU
+        batch path, `_route_dispatch`, every non-batch publish). Raises
+        what its delivery raised: the caller's NACK."""
+        failed = self.handle_deliver_run(((msg, opts),))
+        if failed:
+            raise failed[0][1]
 
-            msg = copy.copy(msg)
-            msg.topic = MP.unmount(self.mountpoint, msg.topic)
-        if self.state != "connected" or self.session is None:
+    def handle_deliver_run(self, items) -> List:
+        """A run of deliveries to this connection, `(message, subscription
+        options)` pairs in delivery order: a settled batch's
+        (`Broker._dispatch_device_results` collects them per connection),
+        or one. The mountpoint / state / session tests once, one pass
+        over the session window (`Session.deliver_run`), the
+        `message.delivered` chain resolved once and run per SENT message
+        (a queued one runs none), one `egress.send` section (entries:
+        packets) and one `send_segments` for the frames: per message the
+        split frame its other receivers share (`_split_frame`), no
+        `pkt.Publish` built for it. What has no split frame goes out in
+        its place in the order: a QoS0 message as its cached `_fb` frame
+        (its `delivery.completed` follows the sends), a `send_packet`
+        for a sink without `send_segments` / `send_bytes`, a retained
+        replay, an oversize topic. The bytes are those of the same
+        deliveries made one by one (docs/protocol_plane.md "The delivery
+        run").
+
+        -> `(index in the run, exception)` of every item whose delivery
+        raised, as `handle_deliver` would have for it alone; the others
+        are delivered. Empty: all are."""
+        mp = self.mountpoint
+        if mp:
+            # unmount on the way out (emqx_channel.erl:970-976)
+            items = [(self._unmounted(m, mp), o) for m, o in items]
+        session = self.session
+        if self.state != "connected" or session is None:
             # connection-less window (e.g. between takeover begin/end):
             # park in the session queue for replay
-            if self.session is not None and msg.qos > 0:
-                dropped = self.session.mqueue.in_(msg)
-                if dropped is not None:
-                    self._queue_dropped(dropped)
-            return
-        # QoS0 fan-out fast path: serialize ONCE per (version, retain,
-        # topic) and write the same bytes to every subscriber socket —
-        # per-subscriber Publish construction + serialization was a top
-        # per-delivery cost with fan-out 8 (the cache rides the Message
-        # object, shared across its mount-variant copies)
-        # retained-store replays are EXCLUDED: those Message objects live
-        # as long as the store, and the cache would pin one serialized
-        # copy per (version, retain, topic) variant against each of
-        # millions of stored messages
-        qos0 = (
-            msg.qos == 0 or (opts is not None and opts.qos == 0)
-        ) and not msg.headers.get("retained")
-        sb = getattr(self.sink, "send_bytes", None)
-        if qos0 and sb is not None:
-            retain = (
-                msg.retain
-                if (opts is not None and opts.retain_as_published)
-                else bool(msg.headers.get("retained"))
-            )
-            fb = getattr(msg, "_fb", None)
-            if fb is None:
-                fb = {}
-                msg._fb = fb
-            key = (self.version, retain, msg.topic)
-            buf = fb.get(key)
-            if buf is None:
-                buf = fb[key] = serialize(
-                    pkt.Publish(
-                        topic=msg.topic,
-                        payload=msg.payload,
-                        qos=0,
-                        retain=retain,
-                        packet_id=None,
-                        properties=dict(msg.properties),
-                    ),
-                    self.version,
-                )
-            self.hooks.run("message.delivered", self._ci_snapshot(), msg)
-            _prof.begin("egress.send")
+            if session is not None:
+                for msg, _ in items:
+                    if msg.qos > 0:
+                        dropped = session.mqueue.in_(msg)
+                        if dropped is not None:
+                            self._queue_dropped(dropped)
+            return ()
+        sends = session.deliver_run(items)
+        if not sends:
+            return ()
+        failed: List = []
+        delivered = self.hooks.sync_callbacks("message.delivered")
+        if delivered:
+            sends = self._run_chain(delivered, sends, failed)
+        done = self._send_deliveries(sends, failed)
+        if done:
+            # QoS0 completes at send; QoS1/2 complete at PUBACK/PUBCOMP
+            # ('delivery.completed' hook, emqx_slow_subs.erl:25 parity)
+            completed = self.hooks.sync_callbacks("delivery.completed")
+            if completed:
+                self._run_chain(completed, done, failed, time.time())
+        return failed
+
+    def _unmounted(self, msg: Message, mp: str) -> Message:
+        if not msg.topic.startswith(mp):
+            return msg
+        import copy
+
+        msg = copy.copy(msg)
+        msg.topic = MP.unmount(mp, msg.topic)
+        return msg
+
+    def _run_chain(self, chain, sends, failed, now=None) -> List:
+        """A hook chain, resolved by the caller, run per message of
+        `sends` as `Hooks.run` runs it (STOP ends a message's chain) ->
+        the sends whose chain did not raise; the others join `failed`.
+        `now`: the chain is `delivery.completed`'s, it takes the latency."""
+        ci = self._ci_snapshot()
+        kept = []
+        for s in sends:
+            msg = s[1]
             try:
-                sb(buf)
-                self.broker.metrics.inc("packets.sent")
-            finally:
-                _prof.end()
-            self._delivery_completed(msg)
-            return
-        out = self.session.deliver(msg, opts)
-        for q in out:
-            self.hooks.run("message.delivered", self._ci_snapshot(), msg)
-            if not (
-                q.type == pkt.PUBLISH
-                and q.qos
-                and q.packet_id
-                and not q.dup
-                and self._send_pub_split(msg, q)
-            ):
-                self._send(q)
-            if q.type == pkt.PUBLISH and q.qos == 0:
-                # QoS0 completes at send; QoS1/2 complete at PUBACK/PUBCOMP
-                # ('delivery.completed' hook, emqx_slow_subs.erl:25 parity)
-                self._delivery_completed(msg)
+                for cb in chain:
+                    r = (
+                        cb(ci, msg)
+                        if now is None
+                        else cb(ci, msg, now - msg.timestamp)
+                    )
+                    if r is STOP:
+                        break
+                kept.append(s)
+            except Exception as e:  # noqa: BLE001 — this delivery's NACK
+                failed.append((s[0], e))
+        return kept
 
-    def _send_pub_split(self, msg: Message, q) -> bool:
-        """QoS1/2 fan-out fast path: hand the subscriber's frame to the
-        sink as the segments [head, pid, tail] of `_split_frame` — the
-        payload is never re-serialised per target. Retained-store replays
-        are excluded for the `_fb` cache's lifetime reason. Returns False
-        to fall back to `_send`."""
-        ws = getattr(self.sink, "send_segments", None)
-        if ws is None or msg.headers.get("retained"):
-            return False
-        from emqx_tpu.mqtt import slab_serializer as SS
+    def _send_deliveries(self, sends, failed) -> List:
+        """A delivery run's output, in the run's order; each QoS1/2 message
+        already in the window. One `egress.send` section (entries: the
+        packets sent); the sink writes them at the batch's boundary.
+        -> the QoS0 sends that went out: complete at send."""
+        from emqx_tpu.mqtt.slab_serializer import pid_bytes
 
+        n = split = 0
+        done: List = []
         _prof.begin("egress.send")
-        sent = 0
         try:
-            ent = self._split_frame(msg, q.qos, q.retain)
-            if ent is None:
-                return False  # _send raises the codec's exact error
-            ws([ent[0], SS.pid_bytes(q.packet_id), ent[1]])
-            self.broker.metrics.inc("packets.sent")
-            self.broker.metrics.inc("dispatch.serialize.frames")
-            sent = 1
-            return True
+            sink = self.sink
+            ws = getattr(sink, "send_segments", None)
+            sb = getattr(sink, "send_bytes", None)
+            segs: List = []
+            for s in sends:
+                try:
+                    _, msg, qos, retain, pid = s
+                    # retained-store replays are EXCLUDED from both
+                    # caches: those Message objects live as long as the
+                    # store, and a cache would pin one serialised copy
+                    # per (version, retain, topic) variant against each
+                    # of millions of stored messages
+                    cached = not msg.headers.get("retained")
+                    if qos and cached and ws is not None:
+                        ent = self._split_frame(msg, qos, retain)
+                        if ent is not None:
+                            segs += (ent[0], pid_bytes(pid), ent[1])
+                            split += 1
+                            continue
+                    if segs:  # byte order is the run's order
+                        ws(segs)
+                        segs = []
+                    if not qos and cached and sb is not None:
+                        sb(self._qos0_frame(msg, retain))
+                    else:
+                        sink.send_packet(
+                            pkt.Publish(
+                                topic=msg.topic,
+                                payload=msg.payload,
+                                qos=qos,
+                                retain=retain,
+                                packet_id=pid,
+                                properties=dict(msg.properties),
+                            )
+                        )
+                    n += 1
+                    if not qos:
+                        done.append(s)
+                except Exception as e:  # noqa: BLE001 — this delivery's NACK
+                    failed.append((s[0], e))
+            if segs:
+                ws(segs)
+            n += split
+            metrics = self.broker.metrics
+            metrics.inc("packets.sent", n)
+            if split:
+                metrics.inc("dispatch.serialize.frames", split)
         finally:
-            _prof.end(sent)
+            _prof.end(n)
+        return done
+
+    def _qos0_frame(self, msg: Message, retain: bool) -> bytes:
+        """QoS0 fan-out fast path: serialize ONCE per (version, retain,
+        topic) and write the same bytes to every subscriber socket —
+        per-subscriber Publish construction + serialization was a top
+        per-delivery cost with fan-out 8 (the cache rides the Message
+        object, shared across its mount-variant copies)."""
+        fb = getattr(msg, "_fb", None)
+        if fb is None:
+            fb = msg._fb = {}
+        key = (self.version, retain, msg.topic)
+        buf = fb.get(key)
+        if buf is None:
+            buf = fb[key] = serialize(
+                pkt.Publish(
+                    topic=msg.topic,
+                    payload=msg.payload,
+                    qos=0,
+                    retain=retain,
+                    packet_id=None,
+                    properties=dict(msg.properties),
+                ),
+                self.version,
+            )
+        return buf
 
     def _split_frame(self, msg: Message, qos: int, retain: bool):
         """`msg`'s PUBLISH serialised ONCE per (version, qos, retain,
         topic) as a (head, tail) pair around the packet-id slot
         (mqtt/slab_serializer.split_publish — bytes identical to
         frame.serialize). The cache rides the Message like the QoS0 `_fb`
-        cache, so a message's first sends (`_send_pub_split`) and its
+        cache, so a message's first sends (`_send_deliveries`) and its
         sends out of the session queues (`_send_run`) share one
         serialisation over all its subscribers. None: an oversize topic."""
         fbq = getattr(msg, "_fbq", None)

@@ -300,6 +300,17 @@ declare("channel.ack.runs", COUNTER,
         "session window (Channel._in_acks: a read chunk's run, or a lone "
         "ack); the entries of section channel.ack_in over this is how "
         "many acks one pass carries")
+declare("dispatch.runs", COUNTER,
+        "delivery runs handed over: a settled batch's deliveries to one "
+        "in-process connection, one call of Channel.handle_deliver_run "
+        "(Broker.DeliveryRuns.deliver; one add per batch)")
+declare("dispatch.run.deliveries", COUNTER,
+        "deliveries those runs carried (one add per batch); over "
+        "dispatch.runs this is how many one pass over a session window "
+        "carries, over messages.delivered the share of deliveries that "
+        "went in runs. A delivery made per message (its deliverer offers "
+        "no run: the pool's, a gateway's; or its run gave it back) is in "
+        "neither")
 declare("shared.picks", COUNTER,
         "device-resolved $share picks handed to the host for delivery "
         "(Broker._dispatch_row's picks branch; one add per launch); over "

@@ -5,8 +5,9 @@ awaiting_rel set, and the packet-id counter. Survives connection churn:
 on takeover the whole object moves to the new channel
 (emqx_session:takeover/resume/replay, emqx_session.erl:85-90).
 
-Pure state machine — no I/O. `deliver` returns the Publish packets to send;
-a run of acks (`ack_run`) clears the window and refills it from the queue
+Pure state machine — no I/O. A run of deliveries (`deliver_run`; `deliver`
+is the run of one) takes the window's room once and says what to send; a
+run of acks (`ack_run`) clears the window and refills it from the queue
 once, whatever its length.
 """
 
@@ -103,25 +104,69 @@ class Session:
         self, msg: Message, opts: Optional[pkt.SubOpts] = None
     ) -> List[pkt.Publish]:
         """Accept one routed message; return PUBLISH packets ready to send."""
-        qos = min(msg.qos, opts.qos) if opts else msg.qos
-        # MQTT spec: forwarded messages carry retain=0 unless the subscription
-        # set retain-as-published; retained-store replays keep retain=1
-        retain = (
-            msg.retain
-            if (opts and opts.retain_as_published)
-            else bool(msg.headers.get("retained"))
-        )
-        msg = self._adjust(msg, qos, retain)
-        if qos == 0:
-            return [self._publish_packet(msg, 0, None)]
-        if self.inflight.is_full():
-            dropped = self.mqueue.in_(msg)
-            if dropped is not None and self.on_dropped is not None:
+        return [
+            self._publish_packet(self._adjust(m, qos, retain), qos, pid)
+            for _, m, qos, retain, pid in self.deliver_run(((msg, opts),))
+        ]
+
+    def deliver_run(
+        self, items
+    ) -> List[Tuple[int, Message, int, bool, Optional[int]]]:
+        """Accept a run of routed messages, `(message, subscription
+        options)` pairs in delivery order (a settled batch's for this
+        connection, or one) -> what leaves now, `(index in the run,
+        message, qos, retain, packet id)` in the run's order.
+
+        One pass. The effective QoS is the lower of the message's and the
+        subscription's; a forwarded message carries retain=0 unless the
+        subscription set retain-as-published, a retained-store replay
+        keeps retain=1 (MQTT spec). A QoS0 item stays in place in the
+        order (packet id None). The QoS1/2 items take the window's room
+        once: `alloc_packet_ids`, one clock reading and the inserts for
+        those that fit, the queue for every later one (what it drops goes
+        to `on_dropped`, in the queue's order). No ack is handled inside a run, so a window that
+        fills stays full: this is what `deliver` called once per item
+        leaves behind, packet ids included."""
+        free = self.inflight.room(len(items))
+        sends: List = []
+        fits: List = []  # (place in `sends`, the message as the window holds it)
+        drops: List[Message] = []
+        for i, (msg, opts) in enumerate(items):
+            if opts:
+                qos = min(msg.qos, opts.qos)
+                retain = (
+                    msg.retain
+                    if opts.retain_as_published
+                    else bool(msg.headers.get("retained"))
+                )
+            else:
+                qos, retain = msg.qos, bool(msg.headers.get("retained"))
+            if qos == 0:
+                sends.append((i, msg, 0, retain, None))
+                continue
+            held = (
+                msg
+                if msg.qos == qos and msg.retain == retain
+                else self._adjust(msg, qos, retain)
+            )
+            if len(fits) < free:
+                fits.append((len(sends), held))
+                sends.append((i, msg, qos, retain))
+                continue
+            dropped = self.mqueue.in_(held)
+            if dropped is not None:
+                drops.append(dropped)
+        if fits:
+            insert = self.inflight.insert
+            now = time.monotonic()
+            for pid, (at, held) in zip(self.alloc_packet_ids(len(fits)), fits):
+                insert(pid, held, "publish", now)
+                sends[at] += (pid,)
+        if drops and self.on_dropped is not None:
+            # after the inserts: a callback that raises loses no message
+            for dropped in drops:
                 self.on_dropped(dropped)
-            return []
-        pid = self.alloc_packet_id()
-        self.inflight.insert(pid, msg)
-        return [self._publish_packet(msg, qos, pid)]
+        return sends
 
     def _adjust(self, msg: Message, qos: int, retain: bool) -> Message:
         if msg.qos == qos and msg.retain == retain:

@@ -159,11 +159,23 @@ class SharedSub:
         groups = self._table.get(real)
         return groups.get(gname) if groups else None
 
-    def dispatch_picked(self, real: str, gname: str, idx: int, msg) -> int:
+    def dispatch_picked(
+        self, real: str, gname: str, idx: int, msg, hand=None,
+        refused: Optional[int] = None,
+    ) -> int:
         """Deliver to the device-picked member index, host keeping only
         ack/retry failover (emqx_shared_sub.erl:165-189 redispatch). The
         pick came from a table snapshot, so an out-of-range idx (members
-        left since) just means failover order starts elsewhere."""
+        left since) just means failover order starts elsewhere.
+
+        `hand(sub, msg, (real, gname, idx))` in the place of the member's
+        deliverer: the broker's settle-time fan-out, which collects a
+        connection's deliveries of one batch into a run
+        (`Broker.DeliveryRuns.hand`). The member then counts as having
+        taken the message; where its run gives the message back, the
+        broker calls again with `refused`, the candidates from the pick
+        on that already refused it: the failover goes on behind them, and
+        the round-robin counter, advanced at the hand-over, stays."""
         g = self.group(real, gname)
         if g is None or not g.members:
             return 0
@@ -172,16 +184,20 @@ class SharedSub:
         sids = list(g.members.keys())
         i = idx % len(sids) if sids else 0
         candidates = sids[i:] + sids[:i]
-        for sid in candidates:
+        pick = (real, gname, idx) if hand is not None else None
+        for sid in candidates[refused:] if refused else candidates:
             sub = g.members.get(sid)
             if sub is None:
                 continue
             try:
-                sub.deliver(msg, sub.opts)
+                if hand is None:
+                    sub.deliver(msg, sub.opts)
+                else:
+                    hand(sub, msg, pick)
                 tp("shared.delivered", sid=sid, mid=str(msg.mid))
                 if self.strategy == "sticky":
                     g.sticky_sid = sid
-                elif self.strategy == "round_robin":
+                elif self.strategy == "round_robin" and refused is None:
                     g.rr_index += 1
                 return 1
             except Exception:
