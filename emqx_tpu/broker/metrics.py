@@ -334,6 +334,23 @@ declare("messages.routed.device", COUNTER,
         "batch rows routed by the device kernel")
 declare("messages.routed.device_fallback", COUNTER,
         "batch rows the device flagged; routed by the CPU trie")
+declare("route.nfa.matches", COUNTER,
+        "matches the residual NFA engine's columns of a launch's "
+        "`matched` gave (rows it flagged left out; one add per launch, "
+        "from the readback's host copy); 0 while no filter is residual. "
+        "Over the count of ingest.batch.size: NFA matches per launch")
+declare("route.nfa.flagged", COUNTER,
+        "rows the residual NFA engine flagged (each once): they are "
+        "served by the CPU trie and counted in "
+        "messages.routed.device_fallback")
+declare("route.nfa.flagged.too_deep", COUNTER,
+        "flagged rows by cause: more levels than matcher.max_levels (16)")
+declare("route.nfa.flagged.frontier_overflow", COUNTER,
+        "flagged rows by cause: more live NFA states at one level than "
+        "matcher.frontier (32)")
+declare("route.nfa.flagged.match_overflow", COUNTER,
+        "flagged rows by cause: more residual filters matched than "
+        "matcher.max_matches (64) columns hold")
 declare("messages.forward.failed", COUNTER)
 declare("delivery.errors", COUNTER)
 
@@ -487,6 +504,14 @@ declare("shared.subscriptions.count", GAUGE,
 declare("grouptab.groups", GAUGE,
         "(real filter, group) pairs with a row in the device's group "
         "table (GroupTable)")
+declare("route.shapes.active", GAUGE,
+        "width of the shape index's device slice (ShapeIndex.m_active: "
+        "the active shapes, pow2-bucketed, MAX_SHAPES = 64 at the most), "
+        "set when DeviceRouter.prepare finds the tables changed")
+declare("route.residual.filters", GAUGE,
+        "distinct filters the shape index could not take (a 65th shape, "
+        "a hash collision): the residual NFA engine matches them inside "
+        "the same route step (`with_nfa`); set with route.shapes.active")
 declare("topics.count", GAUGE)
 declare("retained.count", GAUGE)
 declare("delayed.count", GAUGE)

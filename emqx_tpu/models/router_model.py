@@ -128,6 +128,11 @@ def compact_fanout_slots(bitmaps, kslot: int):
         return slots, count, count > kslot
 
 
+# `batch_match_syms`' causes, in the order the step's `nfa_flagged[1:]` and
+# the `route.nfa.flagged.<cause>` counters carry them
+NFA_FLAG_CAUSES = ("too_deep", "frontier_overflow", "match_overflow")
+
+
 def shape_route_step_impl(
     shape_tables,
     nfa_tables,
@@ -205,8 +210,8 @@ def shape_route_step_impl(
         # SHAPE_PROBES slots) or cluster-tail entries become invisible
         shape_probes = SHAPE_PROBES
     # stable device-side stage names (`jax.named_scope`: op metadata in
-    # a trace, no primitive added): match, csr_gather, fanout_compact,
-    # share_pick
+    # a trace, no primitive added): match (nfa_walk inside it),
+    # csr_gather, fanout_compact, share_pick
     with jax.named_scope("match"):
         h1, h2, nwords, dollar = tok.tokenize_device(
             bytes_mat, lengths, salt, max_levels
@@ -215,19 +220,31 @@ def shape_route_step_impl(
             shape_tables, m_active, h1, h2, nwords, dollar, probes=shape_probes
         )
         flags = nwords > max_levels
+        nfa_flagged = None
         if with_nfa:
-            syms = tok.vocab_lookup_device(nfa_tables, h1, h2, probes)
-            m2, _c2, f2, _causes2 = batch_match_syms(
-                nfa_tables,
-                syms,
-                nwords,
-                dollar,
-                frontier=frontier,
-                max_matches=max_matches,
-                probes=probes,
-            )
+            with jax.named_scope("nfa_walk"):
+                syms = tok.vocab_lookup_device(nfa_tables, h1, h2, probes)
+                m2, _c2, f2, causes2 = batch_match_syms(
+                    nfa_tables,
+                    syms,
+                    nwords,
+                    dollar,
+                    frontier=frontier,
+                    max_matches=max_matches,
+                    probes=probes,
+                )
             matched = jnp.concatenate([matched, m2], axis=1)
             flags = flags | f2
+            # rows the residual engine flagged, then by cause
+            # (NFA_FLAG_CAUSES; a row may have several): four ints a
+            # launch for the `route.nfa.flagged` counters, over the live
+            # rows alone (a bucket's padding rows are empty topics), as
+            # the host counts `route.nfa.matches`
+            live = lengths > 0
+            nfa_flagged = jnp.stack([
+                jnp.sum((c & live).astype(jnp.int32))
+                for c in (f2, *(causes2[k] for k in NFA_FLAG_CAUSES))
+            ])
         mcount = jnp.sum((matched >= 0).astype(jnp.int32), axis=1)
     sparse_out = None
     if isinstance(sub_bitmaps, dict):  # CSR representation
@@ -270,6 +287,8 @@ def shape_route_step_impl(
         "pick_idx": pick_idx,
         "stats": stats,
     }
+    if nfa_flagged is not None:
+        out["nfa_flagged"] = nfa_flagged
     if sparse_out is not None:
         out["slots"], out["slot_count"], out["overflow"] = sparse_out
     elif kslot > 0 and bitmaps is not None:
@@ -1667,6 +1686,11 @@ class DeviceRouter:
         with_nfa = idx.residual_count > 0
         nfa_tables = self._nfa_sync.sync(idx.nfa) if with_nfa else None
         m_active = idx.shapes.m_active()
+        if self.metrics is not None:
+            self.metrics.gauge_set("route.shapes.active", m_active)
+            self.metrics.gauge_set(
+                "route.residual.filters", idx.residual_count
+            )
         if self.grouptab is not None and len(self.grouptab):
             self.grouptab.pack_fcap(idx.num_filters_capacity)
             fulls = self._group_sync.full_resyncs
@@ -2090,6 +2114,8 @@ class DeviceRouter:
         pulls = {k: out[k][:n] for k in rows}
         if out.get("rule_masks") is not None:
             pulls["rule_masks"] = out["rule_masks"][:, :n]
+        if out.get("nfa_flagged") is not None:  # four ints, whole: no slice
+            pulls["nfa_flagged"] = out["nfa_flagged"]
         if retained is not None:
             # the fused storm's chunk-0 match matrix rides the SAME
             # coalesced transfer as the route outputs; extra chunks
@@ -2175,6 +2201,17 @@ class DeviceRouter:
         rule_masks = host.get("rule_masks")
         mcount = host["mcount"]
         flags = host["flags"] | too_long
+        nfa_flagged = host.get("nfa_flagged")
+        if nfa_flagged is not None and m is not None:
+            # with_nfa: the residual engine's columns come last
+            nfa = matched[:, -self.config.max_matches:]
+            if flags.any():
+                nfa = nfa[~flags]
+            m.inc("route.nfa.matches", int(np.count_nonzero(nfa >= 0)))
+            if nfa_flagged[0]:
+                m.inc("route.nfa.flagged", int(nfa_flagged[0]))
+                for cause, n in zip(NFA_FLAG_CAUSES, nfa_flagged[1:]):
+                    m.inc("route.nfa.flagged." + cause, int(n))
         picks = (
             (host["pick_gid"], host["pick_idx"]) if with_groups else None
         )

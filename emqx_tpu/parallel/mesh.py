@@ -79,7 +79,7 @@ def make_mesh(
 
 # canonical output shardings + stats reduction, shared by both engines
 def _out_specs(with_groups: bool = False, with_slots: bool = False,
-               dense_bitmaps: bool = True):
+               dense_bitmaps: bool = True, with_nfa: bool = False):
     specs = {
         "matched": P("dp", None),
         "mcount": P("dp"),
@@ -99,6 +99,8 @@ def _out_specs(with_groups: bool = False, with_slots: bool = False,
         specs["slots"] = P("dp", "tp")
         specs["slot_count"] = P("dp")
         specs["overflow"] = P("dp")
+    if with_nfa:
+        specs["nfa_flagged"] = P()
     return specs
 
 
@@ -136,6 +138,8 @@ def _reduce_stats(out, with_groups: bool = False):
         "matches": jax.lax.psum(stats["matches"], "dp"),
         "fanout_bits": jax.lax.psum(stats["fanout_bits"], ("dp", "tp")),
     }
+    if "nfa_flagged" in out:  # like routed: identical across tp
+        out["nfa_flagged"] = jax.lax.psum(out["nfa_flagged"], "dp")
     if not with_groups:
         out.pop("pick_gid", None)
         out.pop("pick_idx", None)
@@ -280,7 +284,7 @@ def _dist_shape_step_fn(
     sem_specs = {k: P("tp") for k in sem_keys} if with_sem else None
     out_specs = _out_specs(
         with_groups, with_slots=kslot > 0,
-        dense_bitmaps=not sparse,
+        dense_bitmaps=not sparse, with_nfa=with_nfa,
     )
     if with_sem:
         out_specs["sem_count"] = P("dp")
@@ -450,7 +454,8 @@ def _dist_fused_step_fn(
     )
     per_topic = P("dp") if with_groups else P()
     out_specs = _out_specs(
-        with_groups, with_slots=kslot > 0, dense_bitmaps=not sparse
+        with_groups, with_slots=kslot > 0, dense_bitmaps=not sparse,
+        with_nfa=with_nfa,
     )
     out_specs["retained"] = P("dp", None)
     if with_sem:
