@@ -351,6 +351,15 @@ declare("route.nfa.flagged.frontier_overflow", COUNTER,
 declare("route.nfa.flagged.match_overflow", COUNTER,
         "flagged rows by cause: more residual filters matched than "
         "matcher.max_matches (64) columns hold")
+declare("route.nfa.levels", COUNTER,
+        "levels the residual NFA walk took (one add per launch): its "
+        "launch's deepest row, at most matcher.max_levels (16). Over the "
+        "count of ingest.batch.size: levels per launch")
+declare("route.nfa.frontier_peak", COUNTER,
+        "the most live NFA states any row of a launch had after a walked "
+        "level, before the matcher.frontier (32) cap (one add per launch; "
+        "the max over a mesh's shards). Over the count of "
+        "ingest.batch.size: the peak per launch")
 declare("messages.forward.failed", COUNTER)
 declare("delivery.errors", COUNTER)
 

@@ -128,9 +128,15 @@ def compact_fanout_slots(bitmaps, kslot: int):
         return slots, count, count > kslot
 
 
-# `batch_match_syms`' causes, in the order the step's `nfa_flagged[1:]` and
+# `batch_match_syms`' causes, in the order the step's `nfa_flagged[1:4]` and
 # the `route.nfa.flagged.<cause>` counters carry them
 NFA_FLAG_CAUSES = ("too_deep", "frontier_overflow", "match_overflow")
+# its walk's two scalars, in the order `nfa_flagged[4:]` carries them, and
+# the counters the host adds them to
+NFA_WALK_COUNTERS = (
+    ("levels", "route.nfa.levels"),
+    ("frontier_peak", "route.nfa.frontier_peak"),
+)
 
 
 def shape_route_step_impl(
@@ -224,7 +230,7 @@ def shape_route_step_impl(
         if with_nfa:
             with jax.named_scope("nfa_walk"):
                 syms = tok.vocab_lookup_device(nfa_tables, h1, h2, probes)
-                m2, _c2, f2, causes2 = batch_match_syms(
+                m2, _c2, f2, causes2, walk = batch_match_syms(
                     nfa_tables,
                     syms,
                     nwords,
@@ -239,12 +245,13 @@ def shape_route_step_impl(
             # (NFA_FLAG_CAUSES; a row may have several): four ints a
             # launch for the `route.nfa.flagged` counters, over the live
             # rows alone (a bucket's padding rows are empty topics), as
-            # the host counts `route.nfa.matches`
+            # the host counts `route.nfa.matches`; then the walk's two
+            # scalars (NFA_WALK_COUNTERS)
             live = lengths > 0
             nfa_flagged = jnp.stack([
                 jnp.sum((c & live).astype(jnp.int32))
                 for c in (f2, *(causes2[k] for k in NFA_FLAG_CAUSES))
-            ])
+            ] + [walk[k] for k, _ in NFA_WALK_COUNTERS])
         mcount = jnp.sum((matched >= 0).astype(jnp.int32), axis=1)
     sparse_out = None
     if isinstance(sub_bitmaps, dict):  # CSR representation
@@ -2114,7 +2121,7 @@ class DeviceRouter:
         pulls = {k: out[k][:n] for k in rows}
         if out.get("rule_masks") is not None:
             pulls["rule_masks"] = out["rule_masks"][:, :n]
-        if out.get("nfa_flagged") is not None:  # four ints, whole: no slice
+        if out.get("nfa_flagged") is not None:  # six ints, whole: no slice
             pulls["nfa_flagged"] = out["nfa_flagged"]
         if retained is not None:
             # the fused storm's chunk-0 match matrix rides the SAME
@@ -2210,8 +2217,10 @@ class DeviceRouter:
             m.inc("route.nfa.matches", int(np.count_nonzero(nfa >= 0)))
             if nfa_flagged[0]:
                 m.inc("route.nfa.flagged", int(nfa_flagged[0]))
-                for cause, n in zip(NFA_FLAG_CAUSES, nfa_flagged[1:]):
+                for cause, n in zip(NFA_FLAG_CAUSES, nfa_flagged[1:4]):
                     m.inc("route.nfa.flagged." + cause, int(n))
+            for (_, name), n in zip(NFA_WALK_COUNTERS, nfa_flagged[4:]):
+                m.inc(name, int(n))
         picks = (
             (host["pick_gid"], host["pick_idx"]) if with_groups else None
         )

@@ -7,14 +7,16 @@ over a *batch* of topics:
 
 - state: a fixed-width frontier of NFA node ids per topic (a trie has no
   converging paths, so the frontier never contains duplicates);
-- one `lax.scan` step per topic level: gather `#`-terminals (they match any
+- one `lax.while_loop` trip per topic level, up to the batch's deepest row
+  (at most the level budget): gather `#`-terminals (they match any
   non-empty suffix), probe the literal-edge hash table, gather `+` children,
   then compact the doubled frontier with a cumsum+scatter;
-- end-of-scan: collect exact terminals and `#`-terminals of the surviving
+- end of the walk: collect exact terminals and `#`-terminals of the surviving
   frontier (``a/#`` matches ``a`` — 'match_#' at emqx_trie.erl:288-291);
 - `$`-topics skip root-level ``+``/``#`` (emqx_trie.erl:271-278).
 
-Everything is static-shape, data-independent control flow; matched filter ids
+Everything is static-shape; the one data-dependent bound is the trip count,
+a device scalar, so one program serves every depth. Matched filter ids
 accumulate into a fixed [B, K] buffer via cumsum+scatter with an overflow
 flag. Rows that overflow (frontier or matches) or exceed the level budget are
 flagged so the host can fall back to the authoritative CPU trie
@@ -38,7 +40,7 @@ from emqx_tpu.ops.nfa import (
 
 @dataclass(frozen=True)
 class MatcherConfig:
-    max_levels: int = 16  # topic depth budget (scan length)
+    max_levels: int = 16  # topic depth budget (the walk's longest)
     frontier: int = 32  # max simultaneous NFA states per topic
     max_matches: int = 64  # max matched filters per topic
     # open-addressing probe bound; must cover the build-time bound
@@ -102,7 +104,10 @@ def _probe_edges(tables, node, sym, probes: int):
 
 
 def _compact(cand, width: int):
-    """Left-pack the >=0 entries of cand [B, W] into [B, width]; flag overflow."""
+    """Left-pack the >=0 entries of cand [B, W] into [B, width].
+
+    -> (packed [B, width], count [B] of the >=0 entries); a row whose
+    count is over `width` lost its tail."""
     import jax.numpy as jnp
 
     B = cand.shape[0]
@@ -112,8 +117,7 @@ def _compact(cand, width: int):
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     out = jnp.full((B, width), -1, dtype=jnp.int32)
     out = out.at[rows, idx].set(cand, mode="drop")
-    over = jnp.sum(valid, axis=1) > width
-    return out, over
+    return out, jnp.sum(valid, axis=1, dtype=jnp.int32)
 
 
 def _member_mask(matched, ids):
@@ -167,7 +171,16 @@ def batch_match_syms(
        flags bool [B] (overflow or too-deep => host must fall back),
        causes {too_deep, frontier_overflow, match_overflow} bool [B]
        (per-cause breakdown of flags — the flight recorder counts WHY
-       the fast path missed, not just that it did)
+       the fast path missed, not just that it did),
+       walk {levels, frontier_peak} int32 scalars: the levels walked and
+       the most live NFA states (before the `frontier` cap) any row had
+       after a walked level
+
+    The walk stops at the batch's deepest row: min(L, max(nwords))
+    trips, a device scalar, so there is one program whatever the depth.
+    L stays the budget: a deeper row is flagged too_deep. A trip past a
+    row's last level changes nothing in that row, so the outputs are
+    those of a walk over all L levels.
     """
     import jax
     import jax.numpy as jnp
@@ -176,17 +189,23 @@ def batch_match_syms(
     F, K = frontier, max_matches
 
     # derive carry inits from the inputs so they carry the same device-varying
-    # type as the loop body under shard_map (see shard_map scan-vma docs)
+    # type as the loop body under shard_map (see shard_map scan-vma docs);
+    # the trip bound too: each shard walks to its own deepest row
     z = jnp.zeros_like(nwords)  # [B] int32
     frontier0 = jnp.full((B, F), -1, dtype=jnp.int32) + z[:, None]
     frontier0 = frontier0.at[:, 0].set(z)  # root
     matched0 = jnp.full((B, K), -1, dtype=jnp.int32) + z[:, None]
-    mcount0 = z
-    fover0 = z < 0  # all-False, device-varying
+    levels = jnp.minimum(jnp.max(nwords, initial=0), L)
+    # a level's symbols as a row: indexing the major axis per trip is
+    # cheaper on the chip than a column of syms (PERF.md section 6, PR 38)
+    syms_by_level = syms.T
 
-    def step(carry, xs):
-        fr, matched, mcount, fover = carry
-        wsym, lvl = xs
+    def walked(carry):
+        return carry[0] < levels
+
+    def step(carry):
+        lvl, fr, matched, mcount, widest = carry
+        wsym = syms_by_level[lvl]
         active_row = lvl < nwords
         act = (fr >= 0) & active_row[:, None]
         fr_safe = jnp.maximum(fr, 0)
@@ -201,15 +220,15 @@ def batch_match_syms(
             probes,
         )
         plus = jnp.where(allow_wild, tables["plus_child"][fr_safe], -1)
-        newf, over = _compact(jnp.concatenate([lit, plus], axis=1), F)
+        newf, live = _compact(jnp.concatenate([lit, plus], axis=1), F)
         fr = jnp.where(active_row[:, None], newf, fr)
-        fover = fover | (over & active_row)
-        return (fr, matched, mcount, fover), None
+        # a row's most live states after any of its levels: over F, its
+        # frontier lost states (frontier_overflow)
+        widest = jnp.maximum(widest, jnp.where(active_row, live, 0))
+        return lvl + 1, fr, matched, mcount, widest
 
-    (fr, matched, mcount, fover), _ = jax.lax.scan(
-        step,
-        (frontier0, matched0, mcount0, fover0),
-        (syms.T, jnp.arange(L, dtype=jnp.int32)),
+    _, fr, matched, mcount, widest = jax.lax.while_loop(
+        walked, step, (jnp.int32(0), frontier0, matched0, z, z)
     )
 
     done = nwords <= L
@@ -221,6 +240,7 @@ def batch_match_syms(
     matched, mcount = _append(matched, mcount, endhash, K)
 
     too_deep = ~done
+    fover = widest > F
     mover = mcount > K
     flags = fover | mover | too_deep
     causes = {
@@ -228,7 +248,8 @@ def batch_match_syms(
         "frontier_overflow": fover,
         "match_overflow": mover,
     }
-    return matched, jnp.minimum(mcount, K), flags, causes
+    walk = {"levels": levels, "frontier_peak": jnp.max(widest, initial=0)}
+    return matched, jnp.minimum(mcount, K), flags, causes, walk
 
 
 class MatchError(RuntimeError):

@@ -139,7 +139,12 @@ def _reduce_stats(out, with_groups: bool = False):
         "fanout_bits": jax.lax.psum(stats["fanout_bits"], ("dp", "tp")),
     }
     if "nfa_flagged" in out:  # like routed: identical across tp
-        out["nfa_flagged"] = jax.lax.psum(out["nfa_flagged"], "dp")
+        # the flagged counts sum over the batch shards; the walk's two
+        # scalars (levels, frontier peak) are each shard's own: the max
+        nf = out["nfa_flagged"]
+        out["nfa_flagged"] = jnp.concatenate([
+            jax.lax.psum(nf[:4], "dp"), jax.lax.pmax(nf[4:], "dp"),
+        ])
     if not with_groups:
         out.pop("pick_gid", None)
         out.pop("pick_idx", None)
@@ -150,8 +155,9 @@ def _reduce_stats(out, with_groups: bool = False):
     "dist_shape_step",
     kind="builder",
     # stats psum over ('dp','tp') + the kslot>0 per-shard compaction's
-    # lane-offset rebase (axis_index) and count/overflow psum over 'tp'
-    collectives=("psum", "axis_index"),
+    # lane-offset rebase (axis_index) and count/overflow psum over 'tp';
+    # with residual filters, the NFA walk's two scalars pmax over 'dp'
+    collectives=("psum", "axis_index", "pmax"),
     out_bounds={
         # per-shard compaction concatenates over tp: [B, kslot * tp]
         "slots": lambda cfg: (
@@ -312,11 +318,12 @@ def _dist_shape_step_fn(
     "dist_fused_step",
     kind="builder",
     # the fused serving builder inherits dist_shape_step's ICI budget:
-    # stats psums + the per-shard compaction's lane-offset rebase. The
+    # stats psums + the per-shard compaction's lane-offset rebase (and
+    # the NFA walk's pmax, with residual filters). The
     # retained half is shard-local by construction (chunk rows ride
     # 'dp'; its tables are replicated) — a collective appearing there
     # is a contract violation, not a tuning knob.
-    collectives=("psum", "axis_index"),
+    collectives=("psum", "axis_index", "pmax"),
     out_bounds={
         "slots": lambda cfg: (
             cfg["B"] * cfg["kslot"] * cfg.get("tp", 1) * 4

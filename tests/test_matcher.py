@@ -14,6 +14,7 @@ as the served step runs it for residual filters.
 
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -67,7 +68,7 @@ def nfa_check(trie, builder, topics_list, cfg=MatcherConfig(), sync=None):
     the limit was what sent the row back."""
     tables = (sync or DeviceDeltaSync()).sync(builder)
     rows = [builder.tokenize_host(t, cfg.max_levels) for t in topics_list]
-    matched, mcount, flags, causes = batch_match_syms(
+    matched, mcount, flags, causes, _walk = batch_match_syms(
         tables,
         np.stack([r[0] for r in rows]),
         np.array([r[1] for r in rows], dtype=np.int32),
@@ -138,6 +139,118 @@ def test_too_deep_falls_back():
     deep = "a/" + "/".join("x" * 1 for _ in range(10))
     causes = nfa_check(trie, builder, [deep, "a/b"], cfg)
     assert causes["too_deep"] == [True, False]
+
+
+# -- the walk stops at the batch's deepest row ------------------------------
+
+WALK_CFG = MatcherConfig()  # the served budget: 16 levels, 32 states
+WALK_DEPTHS = [1, 3, 8, 16, 17, 23]  # a batch's deepest row
+
+
+@pytest.fixture(scope="module")
+def walk_nfa():
+    """Filters 1 to 20 levels deep over four words, a third of the
+    levels `+`, a fifth ending in `#`, and the root and `$SYS`
+    wildcards; -> (trie, builder, device tables, the trie's node
+    prefixes: a filter's levels without its `#`)."""
+    rng = random.Random(5)
+    filters = {"#", "+", "+/+/#", "a/#", "$SYS/#", "$SYS/+/b"}
+    while len(filters) < 160:
+        ws = [rng.choice("++abcd") for _ in range(rng.randint(1, 20))]
+        filters.add("/".join(ws + ["#"] * (rng.random() < 0.2)))
+    trie, builder = make_nfa(sorted(filters))
+    prefixes = {()}
+    for f in filters:
+        ws = f.split("/")
+        ws = ws[:-1] if ws[-1] == "#" else ws
+        prefixes |= {tuple(ws[:i]) for i in range(1, len(ws) + 1)}
+    return trie, builder, DeviceDeltaSync().sync(builder), prefixes
+
+
+def walk_topics(deepest, rng, n=24):
+    """One row exactly `deepest` levels, 15 of mixed depth up to it (a
+    quarter of them `$SYS` topics), the rest empty padding rows."""
+    def topic(depth):
+        ws = [rng.choice("abcd") for _ in range(depth)]
+        if rng.random() < 0.25:
+            ws[0] = "$SYS"
+        return "/".join(ws)
+
+    rows = [topic(deepest)] + [topic(rng.randint(1, deepest))
+                               for _ in range(15)]
+    return rows + [""] * (n - len(rows))
+
+
+def widest_host(prefixes, topic, L):
+    """The most live states the trie walk of `topic` holds after any of
+    its first L levels: a recount on the host, from the filters alone."""
+    words, states, widest = T.words(topic), [()], 0
+    for lvl, w in enumerate(words[:L]):
+        nxt = [p + (w,) for p in states if p + (w,) in prefixes]
+        if not (lvl == 0 and topic.startswith("$")):
+            nxt += [p + ("+",) for p in states if p + ("+",) in prefixes]
+        states, widest = nxt, max(widest, len(nxt))
+    return widest
+
+
+def run_walk(tables, builder, topics_list, cfg=WALK_CFG):
+    rows = [builder.tokenize_host(t, cfg.max_levels) for t in topics_list]
+    nwords = np.array([r[1] for r in rows], dtype=np.int32)
+    out = batch_match_syms(
+        tables,
+        np.stack([r[0] for r in rows]),
+        nwords,
+        np.array([r[2] for r in rows]),
+        frontier=cfg.frontier,
+        max_matches=cfg.max_matches,
+        probes=max(cfg.probes, MAX_PROBES),
+    )
+    return (nwords, *jax.tree_util.tree_map(np.asarray, out))
+
+
+@pytest.mark.parametrize("deepest", WALK_DEPTHS)
+def test_the_walk_stops_at_the_deepest_row_and_matches_the_trie(
+        walk_nfa, deepest):
+    trie, builder, tables, prefixes = walk_nfa
+    L, F = WALK_CFG.max_levels, WALK_CFG.frontier
+    topics_list = walk_topics(deepest, random.Random(deepest))
+    nwords, matched, mcount, flags, causes, walk = run_walk(
+        tables, builder, topics_list)
+    widest = [widest_host(prefixes, t, L) for t in topics_list]
+    assert max(widest) <= F  # the table never overflows the frontier
+    assert int(walk["levels"]) == min(L, deepest) == min(L, nwords.max())
+    assert int(walk["frontier_peak"]) == max(widest)
+    for i, topic in enumerate(topics_list):
+        assert bool(causes["too_deep"][i]) == (nwords[i] > L), topic
+        assert not causes["frontier_overflow"][i], topic
+        assert not causes["match_overflow"][i], topic
+        assert bool(flags[i]) == (nwords[i] > L), topic
+        if flags[i]:
+            continue  # the CPU trie serves it
+        want = sorted(trie.match(topic))
+        assert int(mcount[i]) == len(want), topic
+        assert sorted(builder.filter_name(int(f))
+                      for f in matched[i, :mcount[i]]) == want, topic
+    # only the last row deep: the walk runs all L levels, and no other
+    # row's outputs move, column for column
+    deep = run_walk(tables, builder, topics_list[:-1] + ["a/" * 15 + "b"])
+    assert int(deep[5]["levels"]) == L
+    for got, base in zip(deep[1:4], (matched, mcount, flags)):
+        np.testing.assert_array_equal(got[:-1], base[:-1])
+    for k, v in causes.items():
+        np.testing.assert_array_equal(deep[4][k][:-1], v[:-1])
+
+
+def test_one_walk_program_serves_every_depth(walk_nfa):
+    _trie, builder, tables, _prefixes = walk_nfa
+    batch_match_syms.clear_cache()
+    levels = [
+        int(run_walk(tables, builder,
+                     walk_topics(d, random.Random(d)))[5]["levels"])
+        for d in WALK_DEPTHS
+    ]
+    assert levels == [min(d, WALK_CFG.max_levels) for d in WALK_DEPTHS]
+    assert batch_match_syms._cache_size() == 1
 
 
 def test_frontier_overflow_falls_back():
@@ -220,7 +333,7 @@ def test_host_tokenize_matches_device_path():
     syms = np.stack([r[0] for r in rows])
     nwords = np.array([r[1] for r in rows], dtype=np.int32)
     dollar = np.array([r[2] for r in rows])
-    matched, mcount, flags, causes = batch_match_syms(
+    matched, mcount, flags, causes, _walk = batch_match_syms(
         tables, syms, nwords, dollar, frontier=8, max_matches=8, probes=8
     )
     got = sorted(

@@ -73,6 +73,18 @@ class Trie:
                         for o in node["#"].get(None, ())]
         return out
 
+    def widest(self, topic, levels=16):
+        """-> the most nodes the walk of `topic` holds after one of its
+        first `levels` levels: the NFA engine's live states"""
+        frontier, most = [self.root], 0
+        for depth, level in enumerate(topic.split("/")[:levels]):
+            hide = depth == 0 and topic.startswith("$")
+            frontier = [node[k] for node in frontier
+                        for k in (level, *(() if hide else ("+",)))
+                        if k in node]
+            most = max(most, len(frontier))
+        return most
+
 
 def topic_of(d, j):
     return f"plant/a{d % 7}/l{d % 13}/d{d}/tele/g{j % 3}/c{j}/val"
@@ -264,6 +276,16 @@ FLAGGED = {
     "frontier_overflow": (frontier_filters(), "f0/f1/f2/f3/f4/f5/f6/end"),
     "match_overflow": (hash_filters(), "/".join(f"m{i}" for i in range(12))),
 }
+# the levels the walk takes over a batch with that topic: its deepest row,
+# at most the budget of 16
+FLAGGED_LEVELS = {"too_deep": 16, "frontier_overflow": 8, "match_overflow": 12}
+
+
+def residual_trie(bed):
+    trie = Trie()
+    for flt in bed.index._residual:
+        trie.insert(flt, None)
+    return trie
 
 
 @pytest.mark.parametrize("sub_table", ["dense", "sparse"])
@@ -285,9 +307,16 @@ def test_a_flagged_row_is_counted_by_cause_and_served_by_the_cpu_trie(
     assert bed.metric("route.nfa.flagged") == 1
     assert {c: bed.metric(f"route.nfa.flagged.{c}") for c in CAUSES} \
         == {c: int(c == cause) for c in CAUSES}
-    # the batch after it is the device's again, whole
+    assert bed.metric("route.nfa.levels") == FLAGGED_LEVELS[cause]
+    peak = bed.metric("route.nfa.frontier_peak")
+    if cause == "frontier_overflow":
+        assert peak > MatcherConfig().frontier
+    else:
+        assert peak == max(residual_trie(bed).widest(m.topic) for m in msgs)
+    # the batch after it is the device's again, whole, and 8 levels deep
     check_served(bed, messages(22, n=100))
     assert bed.metric("route.nfa.flagged") == 1
+    assert bed.metric("route.nfa.levels") == FLAGGED_LEVELS[cause] + 8
 
 
 def test_the_mesh_engine_counts_the_same_series():
@@ -299,6 +328,11 @@ def test_the_mesh_engine_counts_the_same_series():
     assert nfa == residual > 0
     assert bed.metric("route.nfa.flagged") \
         == bed.metric("route.nfa.flagged.too_deep") == 1
+    # the walk's two scalars, the max over the batch shards: the 17-level
+    # row's shard walked the budget's 16 levels
+    assert bed.metric("route.nfa.levels") == 16
+    assert bed.metric("route.nfa.frontier_peak") \
+        == max(residual_trie(bed).widest(m.topic) for m in msgs) > 1
     assert bed.broker.metrics.gauge("route.residual.filters") \
         == bed.index.residual_count
 

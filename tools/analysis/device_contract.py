@@ -200,19 +200,22 @@ def _path_part(p) -> str:
 # pow2 capacities hold) shared by every kernel; per-kernel closures bind
 # the static args and name the outputs.
 
-def _workload(max_subs: int = 512):
+def _workload(max_subs: int = 512, residual: bool = False):
     if str(ROOT) not in sys.path:
         sys.path.insert(0, str(ROOT))
     import __graft_entry__ as ge
 
-    return ge._workload(max_subs=max_subs)
+    return ge._workload(max_subs=max_subs, residual=residual)
 
 
+# a config with "nfa" traces the step over a table with a residual
+# filter, so the NFA walk (and, on a mesh, its pmax) is pinned too
 def _configs_single() -> List[Dict]:
     return [
         {"B": 8, "kslot": 0},
         {"B": 8, "kslot": 8},
         {"B": 16, "kslot": 8},
+        {"B": 8, "kslot": 8, "nfa": 1},
     ]
 
 
@@ -220,6 +223,7 @@ def _configs_mesh() -> List[Dict]:
     return [
         {"B": 8, "kslot": 0, "dp": 1, "tp": 1},
         {"B": 8, "kslot": 8, "dp": 2, "tp": 2},
+        {"B": 8, "kslot": 8, "dp": 2, "tp": 2, "nfa": 1},
     ]
 
 
@@ -229,6 +233,8 @@ def _cfg_key(cfg: Dict) -> str:
         parts.append(f"D{cfg['D']}")
     if "dp" in cfg:
         parts.append(f"dp{cfg['dp']}tp{cfg['tp']}")
+    if cfg.get("nfa"):
+        parts.append("nfa")
     return "_".join(parts)
 
 
@@ -305,7 +311,9 @@ def _harness(name: str):
     def build(cfg):
         from functools import partial
 
-        index, subs, bytes_mat, lengths, m_active = _workload()
+        index, subs, bytes_mat, lengths, m_active = _workload(
+            residual=bool(cfg.get("nfa"))
+        )
         B = cfg["B"]
         bytes_mat = bytes_mat[:B]
         lengths = np.asarray(lengths[:B])
