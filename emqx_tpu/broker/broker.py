@@ -748,6 +748,7 @@ class Broker:
         deg = self.degrade
 
         def _cpu_pending(degraded: bool = False):
+            _prof.dark_ledger.untook()  # no launch will light the chip
             ready = loop.create_future()
             ready.set_result(None)
             if degraded:

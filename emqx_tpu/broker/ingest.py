@@ -218,8 +218,14 @@ class BatchIngest:
         return await self.enqueue(msg)
 
     def _take_batch(self, now: float, force: bool = False) -> List[Tuple]:
+        # the batch's encode starts here, should the chip be dark until its
+        # launch lights it (observe/profiler.py DarkLedger)
+        _prof.dark_ledger.took(self._seq)
         with _prof.section("ingest.take", batch=self._seq):
-            return self._assemble(now, force)
+            batch = self._assemble(now, force)
+        if not batch:
+            _prof.dark_ledger.untook(self._seq)
+        return batch
 
     def _assemble(self, now: float, force: bool) -> List[Tuple]:
         """Assemble up to max_batch in lane-priority order. The low lane

@@ -852,13 +852,6 @@ declare("profile.stage.host_dispatch.seconds", HISTOGRAM,
 declare("profile.captures", COUNTER,
         "completed jax.profiler trace captures (armed via "
         "POST /api/v5/profile)")
-declare("profile.capture.seconds", HISTOGRAM,
-        "armed duration of each completed capture",
-        buckets=LATENCY_BUCKETS, unit="seconds")
-declare("profile.capture.bytes", HISTOGRAM,
-        "on-disk size of each completed capture (over-budget captures "
-        "are deleted and record the size that tripped the bound)",
-        buckets=READBACK_BUCKETS)
 declare("profile.cost.kernels", GAUGE,
         "contract kernels covered by the last cost-analysis harvest "
         "(14 = the full registry)")
@@ -874,7 +867,7 @@ declare("provenance.device.count", GAUGE,
 # The section names are a contract (PERF.md section 3 lists each with the
 # metric that reads it). Sums and counts only (`Histogram.add` at flush:
 # scrape, GET /api/v5/profile, the 1 Hz tick); read as a mean or a share.
-SECTIONS: Tuple[str, ...] = (
+OWNER_SECTIONS: Tuple[str, ...] = (
     # the device owner's main thread
     "ingress.decode",       # parser.feed of a read chunk / a pool worker's PUB slab
     "channel.publish_in",   # Channel._in_publish up to the enqueue (checks, authz)
@@ -891,6 +884,8 @@ SECTIONS: Tuple[str, ...] = (
     "cluster.forward.out",  # ClusterNode.forward_batch_remote: replica match, grouping, hand-off (entries: messages forwarded)
     "cluster.forward.in",   # the receiving half of a forward up to its dispatch (entries: messages)
     "cluster.route.apply",  # one applied slice of a peer's route batch
+)
+SECTIONS: Tuple[str, ...] = OWNER_SECTIONS + (
     # the dispatch executor's threads
     "launch",
     "device_execute",
@@ -932,3 +927,20 @@ declare("owner.gc.thaws", COUNTER,
 declare("owner.gc.frozen.objects", GAUGE,
         "objects the collector policy holds outside the generations, as "
         "counted when they were frozen (set at every tick)")
+
+# -- the chip's dark time (observe/profiler.py DarkLedger) ------------------
+# Dark: no launch lit, from a launch's device_execute end (none left lit)
+# to the next kernel enqueue (the end of section `launch`). Each interval's
+# causes add up to it: `encode` (the closing batch's ingest.take to its
+# enqueue), `select` (the owner loop blocked, nothing launchable), the
+# outermost owner-thread section (children included), `other`.
+DARK_CAUSES: Tuple[str, ...] = ("select", "encode", "other") + OWNER_SECTIONS
+declare("device.dark.seconds", HISTOGRAM,
+        "the chip's dark time: one observation per dark interval (its "
+        "count is the number of intervals); a flush inside an open "
+        "interval adds its elapsed part, which its close does not repeat",
+        unit="seconds")
+for _name in DARK_CAUSES:
+    declare(f"device.dark.{_name}.seconds", HISTOGRAM,
+            f"dark time put down to {_name}; the causes add up to "
+            "device.dark.seconds", unit="seconds")

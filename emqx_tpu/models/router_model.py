@@ -2184,12 +2184,19 @@ class DeviceRouter:
         m = self.metrics
         if launch_open:
             launch_s = _prof.end()
+            # the kernel is enqueued: the chip is lit until this launch's
+            # device_execute ends (observe/profiler.py DarkLedger)
+            _prof.dark_ledger.lit()
             if m is not None:
                 m.observe("profile.stage.launch.seconds", launch_s)
-        with _prof.section("device_execute") as sec:
-            # the program's outputs complete together: waiting on one
-            # output IS the device-execute boundary
-            jax.block_until_ready(out["matched"])
+        try:
+            with _prof.section("device_execute") as sec:
+                # the program's outputs complete together: waiting on one
+                # output IS the device-execute boundary
+                jax.block_until_ready(out["matched"])
+        finally:
+            if launch_open:
+                _prof.dark_ledger.unlit()
         if m is not None:
             m.observe("profile.stage.device_execute.seconds", sec.seconds)
         _prof.begin("readback")

@@ -61,7 +61,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
-from emqx_tpu.broker.metrics import SECTIONS
+from emqx_tpu.broker.metrics import DARK_CAUSES, OWNER_SECTIONS, SECTIONS
 from emqx_tpu.observe import provenance
 
 # the waterfall stage set, in pipeline order (series:
@@ -142,12 +142,15 @@ class _ThreadAcc:
     """One thread's open sections and totals. Only its own thread writes;
     a flush only reads, so there is no lock."""
 
-    __slots__ = ("stack", "acc", "flushed", "ids", "budget")
+    __slots__ = ("stack", "acc", "outer", "flushed", "ids", "budget")
 
     def __init__(self) -> None:
         # open frames, innermost last: [name, annotation, child_s, t0]
         self.stack: List[list] = []
         self.acc: Dict[str, list] = {}  # name -> [total_s, self_s, entries]
+        # name -> total_s of the entries that left the stack empty: what
+        # the thread ran, by outermost section (the dark-time split)
+        self.outer: Dict[str, float] = {}
         self.flushed: Dict[str, tuple] = {}  # the flush's cursor, same shape
         self.ids: Dict[str, Any] = {}  # ambient annotation ids (`batch_ids`)
         # the LoopBudget whose loop runs on this thread: it flushes these
@@ -205,8 +208,19 @@ def end(n: int = 1) -> float:
     read chunk is one section and `n` packets). Returns its seconds."""
     now = _now()
     a = _tls.acc
-    name, ann, child, t0 = a.stack.pop()
+    stack = a.stack
+    name, ann, child, t0 = stack[-1]
     dur = now - t0
+    if len(stack) == 1:
+        # no call between the two stores, so no other thread runs between
+        # them: `DarkLedger` sees the section open or in `outer`, never
+        # neither and never both
+        outer = a.outer
+        outer[name] = outer[name] + dur if name in outer else dur
+        del stack[-1]
+    else:
+        del stack[-1]
+        stack[-1][2] += dur
     tot = a.acc.get(name)
     if tot is None:
         a.acc[name] = [dur, dur - child, n]
@@ -214,8 +228,6 @@ def end(n: int = 1) -> float:
         tot[0] += dur
         tot[1] += dur - child
         tot[2] += n
-    if a.stack:
-        a.stack[-1][2] += dur
     if ann is not None:
         ann.__exit__(None, None, None)
     return dur
@@ -358,6 +370,8 @@ class LoopBudget:
             a.budget = self
             self._flushed = self._totals()
         else:
+            # no call between this store and `_t_out`'s: `DarkLedger`
+            # reads the select as open (`_t_in > _t_out`) or added in
             self.select_s += t_out - self._t_in
         self._t_out = t_out
         self._mark = {k: v[1] for k, v in self._acc.acc.items()}
@@ -490,6 +504,252 @@ def loop_factory():
     return asyncio.SelectorEventLoop(budget.selector())
 
 
+# -- dark time ------------------------------------------------------------------
+DARK_KEPT = 4096  # intervals kept for `dark.json` while a capture is armed
+TAKES_KEPT = 64  # takes whose batch has not lit the chip (yet, or ever)
+TAKES_BEHIND = 16  # a batch this many seqs behind a lit one lights nothing
+
+
+class DarkLedger:
+    """The chip's dark time, put down to what the owner thread ran.
+
+    The chip is *lit* from a launch's kernel enqueue (the end of section
+    `launch`, on a dispatch thread) until that launch's `device_execute`
+    ends, and *dark* whenever no launch is lit: a lit count that goes
+    1 -> 0 opens a dark interval and 0 -> 1 closes it. The interval is
+    split into causes that add up to it by construction:
+
+    - `encode`: from the `ingest.take` of the closing launch's batch to its
+      enqueue (take, prepare, host arrays, the executor hop, `launch`),
+      clipped to the interval;
+    - the rest by what the owner thread did, from a view of its
+      accumulators at each end (open frames' partial time included):
+      `select` (its loop blocked with no callback ready), the outermost
+      section open on it, children included, and `other` (run time no
+      section names, a section outside `OWNER_SECTIONS` among it).
+
+    A view is taken at the interval's start (on the dispatch thread), at
+    a take while the chip is dark (on the owner thread) and, where the
+    closing launch came from no take, at its end. A flush that lands in an
+    open interval books the part elapsed so far, so the series never lag a
+    long interval; the interval is counted once, when it closes. Series:
+    `device.dark.seconds` and `device.dark.<cause>.seconds`
+    (docs/observability.md "Dark time")."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._lit = 0  # guarded-by: _lock
+        # while dark: [start, booked_to, view at booked_to, opened_by,
+        # causes booked so far]
+        self._open: Optional[list] = None  # guarded-by: _lock
+        # batch seq -> (take time, the owner's view then or None)
+        self._takes: Dict[Any, tuple] = {}  # guarded-by: _lock
+        self._owner: Optional[_ThreadAcc] = None  # guarded-by: _lock
+        self._seconds = 0.0  # guarded-by: _lock
+        self._count = 0  # guarded-by: _lock
+        self._causes: Dict[str, float] = {}  # guarded-by: _lock
+        self._flushed: Dict[str, float] = {}  # guarded-by: _lock
+        self._flushed_total = (0.0, 0)  # guarded-by: _lock
+        # closed intervals while a capture is armed; None disarmed
+        self._kept: Optional[list] = None  # guarded-by: _lock
+        self._dropped = 0  # guarded-by: _lock
+
+    def _view(self) -> tuple:  # holds-lock: _lock
+        """(t, select seconds, {outermost section: seconds}, thread, loop
+        budget) of the owner thread at t. Its budget's select state, open
+        frame and `outer` are read with no call between them (see `end`
+        and `LoopBudget.exit_select`)."""
+        a = self._owner
+        if a is None:
+            return _now(), 0.0, {}, None, None
+        b = a.budget
+        t_in = t_out = None
+        if b is not None:
+            t_in, t_out, sel = b._t_in, b._t_out, b.select_s
+        stack = a.stack
+        frame = stack[0] if stack else None
+        outer = dict(a.outer)
+        t = _now()
+        if t_out is None:  # no loop budget: its selects are `other`
+            sel = 0.0
+        elif t_in > t_out:  # blocked in select() since t_in
+            sel += t - t_in
+        if frame is not None and frame[3] < t:
+            outer[frame[0]] = outer.get(frame[0], 0.0) + t - frame[3]
+        return t, sel, outer, a, b
+
+    @staticmethod
+    def _split(v0: tuple, v1: tuple, into: Dict[str, float]) -> None:
+        """What the owner did from v0 to v1, into `into` by cause."""
+        rest = v1[0] - v0[0]
+        if v0[3] is v1[3] and v0[4] is v1[4]:  # the same thread and loop
+            sel = v1[1] - v0[1]
+            into["select"] = into.get("select", 0.0) + sel
+            rest -= sel
+            o0 = v0[2]
+            for name, s in v1[2].items():
+                d = s - o0.get(name, 0.0)
+                if d and name in _OWNER:
+                    into[name] = into.get(name, 0.0) + d
+                    rest -= d
+        into["other"] = into.get("other", 0.0) + rest
+
+    def _book(  # holds-lock: _lock
+        self, seconds: float, causes: Dict[str, float]
+    ) -> None:
+        self._seconds += seconds
+        _add(self._causes, causes)
+
+    def _part(  # holds-lock: _lock
+        self, op: list, take: Optional[tuple], v1: tuple
+    ) -> Dict[str, float]:
+        """The open interval `op` from where it is booked to, to view `v1`,
+        by cause: from `take` (the take of the batch that lights the chip
+        again: its time and the owner's view then) on, that batch's
+        `encode`; before it, what the owner did."""
+        part: Dict[str, float] = {}
+        if take is None:  # no take: the owner's view splits it all
+            self._split(op[2], v1, part)
+        elif take[0] > op[1] and take[1] is not None:
+            self._split(op[2], take[1], part)
+            part["encode"] = v1[0] - take[0]
+        else:  # taken before this stretch: all encode
+            part["encode"] = v1[0] - op[1]
+        return part
+
+    def _pending(self) -> Optional[tuple]:  # holds-lock: _lock
+        """The oldest take whose batch has not lit the chip: the one an
+        open interval's launch most likely comes from."""
+        return next(iter(self._takes.values()), None)
+
+    def took(self, seq) -> None:
+        """The ingest takes batch `seq` (owner thread): its encode starts."""
+        with self._lock:
+            self._owner = _acc()
+            view = self._view() if self._open is not None else None
+            takes = self._takes
+            takes[seq] = (view[0] if view else _now(), view)
+            if len(takes) > TAKES_KEPT:
+                del takes[next(iter(takes))]
+
+    def lit(self, seq=None) -> None:
+        """A launch's kernel is enqueued (the end of section `launch`):
+        closes the dark interval if the chip was dark. `seq`: the batch's,
+        by default this thread's ambient `batch` id."""
+        if seq is None:
+            seq = _batch_id()
+        with self._lock:
+            self._lit += 1
+            takes = self._takes
+            take = takes.pop(seq, None)
+            if isinstance(seq, int):
+                # a take this far behind a lit batch will light nothing (its
+                # launch failed): it must not pose as the pending one
+                for k in [k for k in takes
+                          if isinstance(k, int) and k < seq - TAKES_BEHIND]:
+                    del takes[k]
+            op = self._open
+            if self._lit != 1 or op is None:
+                return
+            self._open = None
+            start, t0, opened_by, causes = op[0], op[1], op[3], op[4]
+            v1 = self._view()
+            t1 = v1[0]
+            rest = self._part(op, take, v1)
+            self._book(t1 - t0, rest)
+            self._count += 1
+            if self._kept is not None:
+                if len(self._kept) < DARK_KEPT:
+                    _add(causes, rest)
+                    self._kept.append({
+                        "start_ns": int(start * 1e9),
+                        "end_ns": int(t1 * 1e9),
+                        "opened_by": opened_by, "closed_by": seq,
+                        "causes": causes})
+                else:
+                    self._dropped += 1
+
+    def untook(self, seq=None) -> None:
+        """Batch `seq` (by default this thread's ambient `batch` id) lights
+        no launch: it came out empty or is served on the CPU."""
+        if seq is None:
+            seq = _batch_id()
+        with self._lock:
+            self._takes.pop(seq, None)
+
+    def unlit(self, seq=None) -> None:
+        """A launch's `device_execute` ended (its `block_until_ready`
+        returned or raised): opens a dark interval if none is lit now."""
+        if seq is None:
+            seq = _batch_id()
+        with self._lock:
+            self._lit -= 1
+            if self._lit == 0:
+                v = self._view()
+                self._open = [v[0], v[0], v, seq, {}]
+
+    def flush(self, metrics) -> None:
+        """Book the open interval's elapsed part, then move the totals
+        into the registry as sums and counts (`Histogram.add`)."""
+        with self._lock:
+            op = self._open
+            if op is not None:
+                v = self._view()
+                part = self._part(op, self._pending(), v)
+                self._book(v[0] - op[1], part)
+                _add(op[4], part)
+                op[1], op[2] = v[0], v
+            total, n = self._seconds, self._count
+            was, was_n = self._flushed_total
+            if total == was and n == was_n:
+                return
+            self._flushed_total = (total, n)
+            metrics.add("device.dark.seconds", total - was, n - was_n)
+            for k, s in self._causes.items():
+                f = self._flushed.get(k, 0.0)
+                if s != f:
+                    self._flushed[k] = s
+                    metrics.add(f"device.dark.{k}.seconds", s - f, n - was_n)
+
+    def keep(self) -> None:
+        """Start keeping closed intervals (a capture was armed)."""
+        with self._lock:
+            self._kept, self._dropped = [], 0
+
+    def release(self) -> Tuple[list, int]:
+        """Stop keeping: -> (the intervals kept, how many were not). An
+        interval still open comes last, up to now, with `closed_by` None:
+        the capture ends inside it. Nothing is booked here."""
+        with self._lock:
+            kept, self._kept = self._kept, None
+            if kept is None:
+                return [], self._dropped
+            op = self._open
+            if op is not None and len(kept) < DARK_KEPT:
+                v = self._view()
+                causes = dict(op[4])
+                _add(causes, self._part(op, self._pending(), v))
+                kept.append({
+                    "start_ns": int(op[0] * 1e9), "end_ns": int(v[0] * 1e9),
+                    "opened_by": op[3], "closed_by": None,
+                    "causes": causes})
+            return kept, self._dropped
+
+
+def _add(into: Dict[str, float], part: Dict[str, float]) -> None:
+    for k, s in part.items():
+        into[k] = into.get(k, 0.0) + s
+
+
+def _batch_id():
+    a = getattr(_tls, "acc", None)
+    return a.ids.get("batch") if a is not None else None
+
+
+_OWNER = frozenset(OWNER_SECTIONS)
+dark_ledger = DarkLedger()
+
+
 _flush_lock = threading.Lock()
 
 
@@ -529,6 +789,7 @@ def flush(metrics) -> None:
                     g[2] - _gc_flushed[2], g[3] - _gc_flushed[3],
                 )
             _gc_flushed[:] = g
+    dark_ledger.flush(metrics)
 
 
 def section_table(
@@ -571,7 +832,24 @@ def section_table(
             "gc_gen2_s": hsum("owner.gc.gen2.seconds")[0],
         },
         "stalls": list(budget.stalls) if budget is not None else [],
+        "dark": dark_table(metrics),
     }
+
+
+def dark_table(metrics) -> Dict[str, Any]:
+    """The REST `dark` block, as of the last flush: the dark intervals,
+    their seconds, and each cause's seconds and share of them."""
+    h = metrics.histogram("device.dark.seconds")
+    total, n = (h.sum, h.count) if h is not None else (0.0, 0)
+    causes = {}
+    for cause in DARK_CAUSES:
+        c = metrics.histogram(f"device.dark.{cause}.seconds")
+        if c is not None and c.sum:
+            causes[cause] = {
+                "seconds": c.sum,
+                "share": c.sum / total if total > 0 else None,
+            }
+    return {"intervals": n, "seconds": total, "causes": causes}
 
 
 class Profiler:
@@ -651,8 +929,14 @@ class Profiler:
             opts.python_tracer_level = 1 if python_tracer else 0
             opts.host_tracer_level = 1
             jax.profiler.start_trace(cap_dir, profiler_options=opts)
+            # the clock anchor: the annotation's start on the trace's clock
+            # and `perf_counter_ns` at the same moment map the dark
+            # intervals of `dark.json` onto the trace
+            clock_ns = time.perf_counter_ns()
+            _close(_annotate("emqx:clock", {"perf_counter_ns": clock_ns}))
             self.capture = {
                 "dir": cap_dir,
+                "clock_ns": clock_ns,
                 "started_at": time.time(),
                 "deadline": time.time() + dur,
                 "duration_s": dur,
@@ -660,6 +944,7 @@ class Profiler:
                 "python_tracer": bool(python_tracer),
             }
             info = dict(self.capture)
+        dark_ledger.keep()
         for listener in self.listeners:
             listener.capture_started()
         return info
@@ -681,15 +966,12 @@ class Profiler:
                 jax.profiler.stop_trace()
             except Exception as e:  # noqa: BLE001 — budget still settles
                 cap["error"] = str(e)
+            _write_dark(cap, *dark_ledger.release())
             entry = self._settle_locked(cap, reason)
             self._history.append(entry)
             del self._history[: -self._history_cap]
         if self.metrics is not None:
             self.metrics.inc("profile.captures")
-            self.metrics.observe(
-                "profile.capture.seconds", entry["seconds"]
-            )
-            self.metrics.observe("profile.capture.bytes", entry["bytes"])
         return entry
 
     def _settle_locked(self, cap, reason) -> Dict[str, Any]:
@@ -768,6 +1050,27 @@ class Profiler:
             "max_bytes": self.max_bytes,
             "cost_harvested": cost is not None,
         }
+
+
+def _write_dark(cap: Dict[str, Any], intervals: list, dropped: int) -> None:
+    """`dark.json` beside the capture's .xplane.pb: the dark intervals that
+    closed while it was armed (perf_counter ns) and the clock anchor."""
+    import glob
+    import json
+
+    found = glob.glob(
+        os.path.join(cap["dir"], "**", "*.xplane.pb"), recursive=True
+    )
+    path = os.path.join(
+        os.path.dirname(found[0]) if found else cap["dir"], "dark.json"
+    )
+    with open(path, "w") as f:
+        json.dump({
+            "clock": {"annotation": "emqx:clock",
+                      "perf_counter_ns": cap["clock_ns"]},
+            "intervals": intervals,
+            "dropped": dropped,
+        }, f)
 
 
 def _tree_bytes(path: str) -> int:
