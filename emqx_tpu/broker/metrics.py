@@ -521,6 +521,16 @@ declare("route.residual.filters", GAUGE,
         "distinct filters the shape index could not take (a 65th shape, "
         "a hash collision): the residual NFA engine matches them inside "
         "the same route step (`with_nfa`); set with route.shapes.active")
+declare("route.nfa.frontier_width", GAUGE,
+        "frontier lanes a row carries in the residual NFA walk: the "
+        "residual trie's exact bound on live states, rounded up to a "
+        "multiple of 8, at most matcher.frontier (32); 0 while no filter "
+        "is residual; set with route.shapes.active")
+declare("route.nfa.match_width", GAUGE,
+        "match columns the residual NFA walk appends to: the residual "
+        "trie's exact bound on the filters one topic matches, rounded up "
+        "to a multiple of 8, at most matcher.max_matches (64); 0 while no "
+        "filter is residual; set with route.shapes.active")
 declare("topics.count", GAUGE)
 declare("retained.count", GAUGE)
 declare("delayed.count", GAUGE)

@@ -1472,6 +1472,9 @@ class DeviceRouter:
         self._prep_key = None  # single-writer: loop
         self._prep_args = None  # single-writer: loop
         self._clean_streak = 0  # single-writer: loop
+        # the residual walk's widths and what they were sized from:
+        # (NFA table capacities, NfaBuilder.version, (frontier, matches))
+        self._nfa_walk = None  # single-writer: loop
 
     def _mk_bits_sync(self, sparse: bool):
         from emqx_tpu.ops.segments import DeviceSegmentManager
@@ -1692,12 +1695,19 @@ class DeviceRouter:
         shape_tables = self._shape_sync.sync(idx.shapes)
         with_nfa = idx.residual_count > 0
         nfa_tables = self._nfa_sync.sync(idx.nfa) if with_nfa else None
+        if with_nfa:
+            nfa_widths = self._nfa_widths(idx.nfa)
+        else:  # no walk in the program: the widths are never traced
+            nfa_widths = (self.config.frontier, self.config.max_matches)
         m_active = idx.shapes.m_active()
         if self.metrics is not None:
             self.metrics.gauge_set("route.shapes.active", m_active)
             self.metrics.gauge_set(
                 "route.residual.filters", idx.residual_count
             )
+            frontier, matches = nfa_widths if with_nfa else (0, 0)
+            self.metrics.gauge_set("route.nfa.frontier_width", frontier)
+            self.metrics.gauge_set("route.nfa.match_width", matches)
         if self.grouptab is not None and len(self.grouptab):
             self.grouptab.pack_fcap(idx.num_filters_capacity)
             fulls = self._group_sync.full_resyncs
@@ -1729,7 +1739,32 @@ class DeviceRouter:
             kg,
             sem_tables,
             sem_topk,
+            nfa_widths,
         )
+
+    def _nfa_widths(self, nfa):
+        """-> (frontier, matches): the residual walk's static widths for
+        this table, its exact bounds (`ops/nfa.walk_widths`) under the
+        config's budgets, which stay the ceilings. A width only grows
+        while the NFA tables keep their capacities, so a removal never
+        compiles a program; a capacity change compiles anyway, and the
+        widths are sized afresh."""
+        from emqx_tpu.ops.nfa import walk_widths
+
+        cap = (
+            len(nfa.arr_edge_node), len(nfa.arr_plus),
+            len(nfa.arr_vocab_sym),
+        )
+        last = self._nfa_walk
+        if last is not None and last[:2] == (cap, nfa.version):
+            return last[2]
+        widths = walk_widths(
+            nfa, self.config.frontier, self.config.max_matches
+        )
+        if last is not None and last[0] == cap:
+            widths = tuple(max(a, b) for a, b in zip(widths, last[2]))
+        self._nfa_walk = (cap, nfa.version, widths)
+        return widths
 
     # -- segment maintenance (ops/segments.SegmentCompactor) --------------
     def segment_status(self) -> Dict:
@@ -1921,6 +1956,7 @@ class DeviceRouter:
             kg,
             sem_tables,
             sem_topk,
+            (frontier, max_matches),
         ) = args
         B = len(topics)
         Bp = max(64, _next_pow2(B))
@@ -1991,14 +2027,15 @@ class DeviceRouter:
                 sem_tables=sem_tables, sem_topk=sem_topk, qv=qv,
                 rprogs=rprogs, rfeats=rfeats, rvalid=rvalid,
                 launch_open=launch_open,
+                nfa_widths=(frontier, max_matches),
             )
         step_kw = dict(
             m_active=m_active,
             with_nfa=with_nfa,
             salt=salt,
             max_levels=cfg.max_levels,
-            frontier=cfg.frontier,
-            max_matches=cfg.max_matches,
+            frontier=frontier,
+            max_matches=max_matches,
             probes=cfg.probes,
             with_groups=with_groups,
             share_strategy=self.share_strategy,
@@ -2021,7 +2058,7 @@ class DeviceRouter:
             )
             return self._readback(
                 out, B, too_long, with_groups, kslot, session=session,
-                launch_open=launch_open,
+                launch_open=launch_open, nfa_k=max_matches,
             )
         if retained is not None and retained.chunks:
             # one launch, one readback: the storm's chunk-0 match rides
@@ -2052,7 +2089,7 @@ class DeviceRouter:
             return self._readback(
                 out, B, too_long, with_groups, kslot,
                 retained=retained, extra_retained=extra,
-                launch_open=launch_open,
+                launch_open=launch_open, nfa_k=max_matches,
             )
         step = (
             shape_route_step_donated
@@ -2077,7 +2114,7 @@ class DeviceRouter:
         )
         return self._readback(
             out, B, too_long, with_groups, kslot,
-            launch_open=launch_open,
+            launch_open=launch_open, nfa_k=max_matches,
         )
 
     # a launch's outputs are sliced on the device to the live rows rounded
@@ -2149,7 +2186,7 @@ class DeviceRouter:
     def _readback(  # readback-site
         self, out, B, too_long, with_groups, kslot, mesh=False,
         retained=None, extra_retained=None, session=None,
-        launch_open=False,
+        launch_open=False, *, nfa_k,
     ):
         """Pull one batch's outputs to host -> `RouteResult`.
 
@@ -2217,8 +2254,8 @@ class DeviceRouter:
         flags = host["flags"] | too_long
         nfa_flagged = host.get("nfa_flagged")
         if nfa_flagged is not None and m is not None:
-            # with_nfa: the residual engine's columns come last
-            nfa = matched[:, -self.config.max_matches:]
+            # with_nfa: the residual engine's `nfa_k` columns come last
+            nfa = matched[:, -nfa_k:]
             if flags.any():
                 nfa = nfa[~flags]
             m.inc("route.nfa.matches", int(np.count_nonzero(nfa >= 0)))
@@ -2342,7 +2379,7 @@ class DeviceRouter:
         mat, lens, B, too_long, group_tables=None, ch=None, th=None,
         rand=None, kslot=0, retained=None, kg=0, sem_tables=None,
         sem_topk=0, qv=None, rprogs=(), rfeats=None, rvalid=None,
-        launch_open=False,
+        launch_open=False, *, nfa_widths,
     ):
         """SPMD serving: the batch rides dist_shape_route_step over the
         device mesh (SURVEY §2.4 TPU mapping; the multi-chip layout the
@@ -2385,8 +2422,8 @@ class DeviceRouter:
             m_active=m_active,
             salt=salt,
             max_levels=cfg.max_levels,
-            frontier=cfg.frontier,
-            max_matches=cfg.max_matches,
+            frontier=nfa_widths[0],
+            max_matches=nfa_widths[1],
             probes=cfg.probes,
             share_strategy=self.share_strategy,
             kslot=kslot,
@@ -2398,6 +2435,7 @@ class DeviceRouter:
         return self._readback(
             out, B, too_long, with_groups, kslot, mesh=True,
             launch_open=launch_open,
+            nfa_k=nfa_widths[1],
         )
 
     @staticmethod
@@ -2560,7 +2598,7 @@ class MeshServingRouter(DeviceRouter):
         mat, lens, B, too_long, group_tables=None, ch=None, th=None,
         rand=None, kslot=0, retained=None, kg=0, sem_tables=None,
         sem_topk=0, qv=None, rprogs=(), rfeats=None, rvalid=None,
-        launch_open=False,
+        launch_open=False, *, nfa_widths,
     ):
         """SPMD serving with optional fused retained storm: chunk 0 of a
         prepared `StormJob` rides the SAME sharded program + readback
@@ -2573,7 +2611,7 @@ class MeshServingRouter(DeviceRouter):
                 mat, lens, B, too_long, group_tables, ch, th, rand, kslot,
                 kg=kg, sem_tables=sem_tables, sem_topk=sem_topk, qv=qv,
                 rprogs=rprogs, rfeats=rfeats, rvalid=rvalid,
-                launch_open=launch_open,
+                launch_open=launch_open, nfa_widths=nfa_widths,
             )
         from emqx_tpu.parallel.mesh import (
             dist_fused_route_step,
@@ -2612,8 +2650,8 @@ class MeshServingRouter(DeviceRouter):
             ret_max_levels=retained.kwargs["max_levels"],
             ret_narrow=retained.kwargs["narrow"],
             max_levels=cfg.max_levels,
-            frontier=cfg.frontier,
-            max_matches=cfg.max_matches,
+            frontier=nfa_widths[0],
+            max_matches=nfa_widths[1],
             probes=cfg.probes,
             share_strategy=self.share_strategy,
             kslot=kslot,
@@ -2636,4 +2674,5 @@ class MeshServingRouter(DeviceRouter):
             out, B, too_long, with_groups, kslot, mesh=True,
             retained=retained, extra_retained=extra,
             launch_open=launch_open,
+            nfa_k=nfa_widths[1],
         )

@@ -628,3 +628,143 @@ class NfaBuilder:
             ent = self._vocab.get(w)
             syms[i] = ent[0] if ent is not None else -1
         return syms, len(ws), topic.startswith("$"), len(ws) > max_levels
+
+
+# the most edges `walk_bounds` follows before it gives up (the walk then
+# keeps its budgets): ~0.5 s of host time. BASELINE config 2's residual
+# trie (~1,600 filters over 18 shapes) takes ~150,000
+WALK_BOUND_WORK = 1 << 19
+
+
+def walk_bounds(nfa: NfaBuilder, max_work: Optional[int] = None):
+    """-> (frontier, matches) over every topic, of any depth: the most trie
+    nodes the device walk (matcher.batch_match_syms) holds live after one
+    of a topic's levels, and the most filter ids it appends for one topic;
+    None when the live sets reachable from the root hold more than
+    `max_work` (default WALK_BOUND_WORK) edges in all.
+
+    A topic's live set after a level is fixed by the set before it and the
+    word: the edge children of that word plus every `+` child. So the
+    bounds are a longest path over the live sets reachable from the root,
+    each set taken once whatever the topics that reach it: for each word
+    some node of the set has an edge for, and for a word no edge knows
+    (the `+` children alone: a subset of every other successor, so it only
+    counts where there is none). The walk appends the `#` filter of each
+    live node per consumed level, then the exact and `#` filters of the
+    last set. At the root a `$` word takes no wildcard and appends no `#`
+    filter, and a word without `$` never takes a `$` edge.
+
+    Nodes whose subtrees are equal (the same words, `+` children, exact
+    and `#` filters, all the way down) are one class: they stay live
+    together, so a set is a multiset of classes, and the many devices
+    below one shape of filters are one class, not one set each."""
+    kids: Dict[int, List[Tuple[int, int]]] = {}
+    for (node, sym), child in nfa._edges.items():
+        kids.setdefault(node, []).append((sym, child))
+    plus = nfa.arr_plus.tolist()
+    hashf = nfa.arr_hashf.tolist()
+    term = nfa.arr_term.tolist()
+    root = NfaBuilder.ROOT
+    order = [root]
+    for n in order:  # parents before children
+        order.extend(c for _, c in kids.get(n, ()))
+        if plus[n] >= 0:
+            order.append(plus[n])
+    # class -> (edges ((sym, class), ...), `+` child's class or -1,
+    # exact filters, `#` filters)
+    info: List[Tuple] = []
+    klass: Dict[Tuple, int] = {}
+    of: Dict[int, int] = {}
+    for n in reversed(order):
+        sig = (
+            tuple(sorted((s, of[c]) for s, c in kids.get(n, ()))),
+            of[plus[n]] if plus[n] >= 0 else -1,
+            int(term[n] >= 0),
+            int(hashf[n] >= 0),
+        )
+        k = klass.get(sig)
+        if k is None:
+            k = klass[sig] = len(info)
+            info.append(sig)
+        of[n] = k
+
+    work = WALK_BOUND_WORK if max_work is None else max_work
+
+    def successors(key, edges):
+        nonlocal work
+        pl = [info[k][1] for k in key if info[k][1] >= 0]
+        by_sym: Dict[int, List[int]] = {}
+        for k in key:
+            out = edges(k)
+            work -= len(out)
+            for sym, c in out:
+                by_sym.setdefault(sym, []).append(c)
+        if not by_sym:
+            return {tuple(sorted(pl))} if pl else set()
+        return {tuple(sorted(cs + pl)) for cs in by_sym.values()}
+
+    def all_edges(k):
+        return info[k][0]
+
+    # live set -> (most live nodes after any later level, most filters
+    # appended from this set on)
+    memo: Dict[Tuple[int, ...], Tuple[int, int]] = {}
+
+    def solve(start) -> bool:
+        stack = [(start, None)]
+        while stack:
+            key, succ = stack[-1]
+            if key in memo:
+                stack.pop()
+                continue
+            if succ is None:
+                succ = successors(key, all_edges)
+                stack[-1] = (key, succ)
+                if work < 0:
+                    return False
+                pending = [t for t in succ if t not in memo]
+                if pending:
+                    stack.extend((t, None) for t in pending)
+                    continue
+            stack.pop()
+            wide = max((max(len(t), memo[t][0]) for t in succ), default=0)
+            later = max((memo[t][1] for t in succ), default=0)
+            memo[key] = (
+                wide,
+                sum(info[k][3] for k in key)
+                + max(sum(info[k][2] for k in key), later),
+            )
+        return True
+
+    def dollar(sym):
+        return nfa._sym_words[sym].startswith("$")
+
+    top = of[root]
+    plain = successors(
+        (top,), lambda k: [e for e in info[k][0] if not dollar(e[0])]
+    )
+    dollars = {(c,) for sym, c in info[top][0] if dollar(sym)}
+    for t in plain | dollars:
+        if not solve(t):
+            return None
+    frontier = max(
+        (max(len(t), memo[t][0]) for t in plain | dollars), default=0
+    )
+    matches = max(
+        [info[top][3] + max((memo[t][1] for t in plain), default=0)]
+        + [memo[t][1] for t in dollars]
+    )
+    return frontier, matches
+
+
+def walk_widths(nfa: NfaBuilder, frontier: int, max_matches: int):
+    """-> (F, K): the device walk's frontier lanes and match columns for
+    this table, each its `walk_bounds` bound rounded up to a multiple of 8
+    and held to its budget (`frontier` / `max_matches`, the ceilings).
+    Under a ceiling no topic needs more, so the walk's answers, counts and
+    flags are those of a walk at the ceilings."""
+    bounds = walk_bounds(nfa)
+    if bounds is None:
+        return frontier, max_matches
+    f, k = (max(8, -(-b // 8) * 8) for b in bounds)
+    return min(frontier, f), min(max_matches, k)
