@@ -401,8 +401,11 @@ class Broker:
                 rk = self.shared.route_filter(group, real)
                 self.router.delete_route(rk)
                 if self.cluster is not None:
-                    self.cluster._replicate_delete(rk)
+                    # the leave ahead of the route's delete, which waits
+                    # for the filter's last local group or subscriber
                     self.cluster.shared_leave(real, group)
+                    if not self.has_local_subs(rk):
+                        self.cluster._replicate_delete(rk)
             elif removed and fid is not None:
                 gid = self.grouptab.gid_of(real, group)
                 g = self.shared.group(real, group)
@@ -435,7 +438,7 @@ class Broker:
         if not entry:
             del self._subs[real]
             self.router.delete_route(real)
-            if self.cluster is not None:
+            if self.cluster is not None and not self.shared.has_groups(real):
                 self.cluster._replicate_delete(real)
         self.metrics.gauge_set("subscriptions.count", self.subscription_count())
         return True
@@ -1115,6 +1118,7 @@ class Broker:
             self.metrics.inc("shared.picks", pick_stats[0])
             if pick_stats[1]:
                 self.metrics.inc("shared.picks.stale", pick_stats[1])
+        self._count_leadership()
         if fell_back:
             self.metrics.inc("messages.routed.device_fallback", fell_back)
         self.metrics.inc("messages.routed.device", len(msgs) - fell_back)
@@ -1318,6 +1322,22 @@ class Broker:
             rec.deliver(msg, n, start_ns=t_ns, remote=True)
         return n
 
+    def _count_leadership(self) -> None:
+        """The cluster leadership answers (`SharedSub._is_leader` with a
+        leader check set) into `shared.leader.led` / `.deferred`, where
+        the dispatch's own counters are added: once a device batch, beside
+        `shared.picks`, and once a message on the host path, beside
+        `messages.received`. An answer given after its batch settled (a
+        refused pick's failover) rides the next one."""
+        c = self.shared.leader_counts
+        if c[0] or c[1]:
+            led, deferred = c
+            c[0] = c[1] = 0
+            if led:
+                self.metrics.inc("shared.leader.led", led)
+            if deferred:
+                self.metrics.inc("shared.leader.deferred", deferred)
+
     def has_local_subs(self, route_key: str) -> bool:
         """Any local subscriber (plain or shared-group) on this filter?"""
         return bool(self._subs.get(route_key)) or self.shared.has_groups(
@@ -1363,6 +1383,7 @@ class Broker:
         self.metrics.observe("dispatch.fanout", n)
         if n:
             self.metrics.inc("messages.delivered", n)
+        self._count_leadership()
         return n
 
     def _deliver_one(self, sub: Subscriber, msg: Message, hand=None) -> int:

@@ -320,6 +320,21 @@ declare("shared.picks.stale", COUNTER,
         "filter no longer matched while the batch was in flight, or no "
         "member took the message (on a cluster node also: another node "
         "leads this message's pick)")
+declare("shared.leader.led", COUNTER,
+        "cluster leadership answers that dispatched: a group pick (or a "
+        "host pick's group) whose message this node leads, of a group "
+        "with members on other nodes too (SharedSub._is_leader with a "
+        "leader check set; added once a device batch, once a message on "
+        "the host path). Never moves without a cluster")
+declare("shared.leader.deferred", COUNTER,
+        "cluster leadership answers that left the message's pick to "
+        "another member node: the pick is counted in shared.picks and "
+        "shared.picks.stale and delivered nothing here (added as "
+        "shared.leader.led is)")
+declare("shared.cluster.pairs", GAUGE,
+        "(real filter, group) pairs this node's replica of the cluster's "
+        "$share membership holds (ClusterNode._shared_nodes), set where a "
+        "local or a peer's join or leave is applied")
 declare("grouptab.uploads", COUNTER,
         "whole re-uploads of the device's group arrays: a launch found "
         "GroupTable's epoch bumped (growth, or its op-log past "
@@ -455,7 +470,8 @@ declare("cluster.forward.confirm.seconds", HISTOGRAM,
 declare("cluster.route.batches", COUNTER,
         "`route` v2 apply_batch calls received from peers")
 declare("cluster.route.ops", COUNTER,
-        "(op, filter) pairs received in those batches")
+        "ops received in those batches: (op, filter) route pairs and, "
+        "from a route v3 peer, its $share joins and leaves")
 
 # worker fabric (transport/workers.py)
 declare("fabric.sess.crash_parked", COUNTER)
@@ -894,6 +910,7 @@ OWNER_SECTIONS: Tuple[str, ...] = (
     "cluster.forward.out",  # ClusterNode.forward_batch_remote: replica match, grouping, hand-off (entries: messages forwarded)
     "cluster.forward.in",   # the receiving half of a forward up to its dispatch (entries: messages)
     "cluster.route.apply",  # one applied slice of a peer's route batch
+    "cluster.shared.apply",  # a peer's $share joins / leaves applied (entries: ops)
 )
 SECTIONS: Tuple[str, ...] = OWNER_SECTIONS + (
     # the dispatch executor's threads

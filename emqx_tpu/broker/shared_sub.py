@@ -58,10 +58,18 @@ class SharedSub:
         # RPC (the reference picks among cluster-wide members,
         # emqx_shared_sub.erl:234-285)
         self.leader_check = None
+        # the leader check's answers since the broker last took them:
+        # [led, deferred] (counters shared.leader.*, one add a batch);
+        # written by the dispatching thread, the loop's in a served broker
+        self.leader_counts = [0, 0]
 
     def _is_leader(self, real: str, group: str, msg=None) -> bool:
         lc = self.leader_check
-        return True if lc is None else lc(real, group, msg)
+        if lc is None:
+            return True
+        led = lc(real, group, msg)
+        self.leader_counts[0 if led else 1] += 1
+        return led
 
     # -- membership -------------------------------------------------------
     def subscribe(self, group: str, real: str, sub) -> bool:

@@ -23,6 +23,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import concurrent.futures
+import itertools
 import logging
 import os
 import threading
@@ -160,7 +161,9 @@ class ClusterNode:
         # (real, group) -> set of nodes holding members; exactly one of
         # them dispatches each message (per-message rotation in
         # shared_leader) — a group spanning nodes delivers exactly once
-        # (emqx_shared_sub's cluster-wide mnesia member table)
+        # (emqx_shared_sub's cluster-wide mnesia member table). A peer's
+        # joins and leaves ride its route lane, in order with the routes
+        # they belong to (`shared_join`, `_apply_ops`)
         self._shared_nodes: Dict[Tuple[str, str], set] = {}
         # cached sorted candidate lists, invalidated on membership change
         self._shared_cands: Dict[Tuple[str, str], List[str]] = {}
@@ -256,10 +259,25 @@ class ClusterNode:
                 "dump": self.routes.dump,
             },
         )
-        # v2: one call carries a few thousand ordered (op, filter) pairs
+        # v2: one call carries a few thousand ordered (op, filter) pairs;
+        # v3: the same methods, and a batch may also carry `$share` group
+        # membership, ("join" | "leave", real filter, group), in order
+        # with the routes (a join behind its route's add, a leave ahead
+        # of its delete). Each table is spelled out: the BPAPI checker
+        # (tools/analysis, BP003) reads literal versions and tables
         self.rpc.registry.register(
             "route",
             2,
+            {
+                "add_route": self.routes.add_route,
+                "delete_route": self.routes.delete_route,
+                "dump": self.routes.dump,
+                "apply_batch": self._proto_route_apply_batch,
+            },
+        )
+        self.rpc.registry.register(
+            "route",
+            3,
             {
                 "add_route": self.routes.add_route,
                 "delete_route": self.routes.delete_route,
@@ -373,6 +391,7 @@ class ClusterNode:
                 if not nodes:
                     self._shared_nodes.pop(key, None)
             self._shared_cands.clear()
+            self._pairs_gauge()
             # shard re-own rides the same degrade ladder that declared
             # the node dead (heartbeat expiry / open breakers): the dead
             # owner's mesh slices move to rendezvous-chosen survivors,
@@ -425,6 +444,7 @@ class ClusterNode:
         for r, g, nodes in self.rpc.call(seed, "shared", "dump"):
             self._shared_nodes.setdefault((r, g), set()).update(nodes)
             self._shared_cands.pop((r, g), None)
+        self._pairs_gauge()
         for real, groups in self.broker.shared._table.items():
             for gname in groups:
                 self.shared_join(real, gname)
@@ -634,31 +654,59 @@ class ClusterNode:
                 time.sleep(delay)
                 delay = min(delay * 2.0, 1.0)
 
-    def _ship_routes(self, peer: str, ops: List[Tuple[str, str]]) -> None:
+    def _ship_routes(self, peer: str, ops: List[tuple]) -> None:
+        """One group of a route lane: (op, filter) route ops and (op,
+        real, group) membership ops, in the order they were put."""
         def send(patient):
-            if self.rpc.supported_version(peer, "route") >= 2:
+            version = self.rpc.supported_version(peer, "route")
+            if version >= 3:
                 return self.rpc.call_on(
                     peer, "route", "apply_batch", (self.name, ops),
                     CH_ROUTE, patient,
                 )
-            for op, filter_ in ops:  # a v1 peer: filter by filter
-                rpc_send = (
-                    self.rpc.call if T.wildcard(filter_) else self.rpc.cast
-                )
-                if op == "add":
-                    rpc_send(peer, "route", "add_route", filter_, self.name)
-                else:
-                    rpc_send(
-                        peer, "route", "delete_route", filter_, self.name
+            # an older peer: each run of routes as that version takes
+            # them, membership as one `shared` cast a pair
+            for width, run in itertools.groupby(ops, len):
+                run = list(run)
+                if width == 3:
+                    self._cast_membership(peer, run)
+                elif version == 2:
+                    self.rpc.call_on(
+                        peer, "route", "apply_batch", (self.name, run),
+                        CH_ROUTE, patient,
                     )
+                else:
+                    self._send_routes_v1(peer, run)
 
         try:
             self._lane_call(peer, send)
         except RpcError:
             pass  # peer down: membership GC will reconcile
 
+    def _send_routes_v1(self, peer: str, ops) -> None:
+        """Route ops to a `route` v1 peer, filter by filter: wildcards as
+        confirmed calls (maybe_trans, emqx_router.erl:118-121), exact
+        topics as ordered casts."""
+        for op, filter_ in ops:
+            rpc_send = self.rpc.call if T.wildcard(filter_) else self.rpc.cast
+            if op == "add":
+                rpc_send(peer, "route", "add_route", filter_, self.name)
+            else:
+                rpc_send(peer, "route", "delete_route", filter_, self.name)
+
+    def _cast_membership(self, peer: str, ops) -> None:
+        """Membership ops to a peer without `route` v3: `shared` casts."""
+        for op, real, group in ops:
+            if op == "join":
+                self.rpc.cast(peer, "shared", "join", real, group,
+                              self.name, key=real)
+            else:
+                self.rpc.cast(peer, "shared", "leave", real, group,
+                              self.name, key=real)
+
     def _proto_route_apply_batch(self, node: str, ops) -> object:
-        """Inbound `route` v2: `node`'s ordered (op, filter) pairs. On a
+        """Inbound `route` v2 / v3: `node`'s ordered ops, (op, filter)
+        routes and, from a v3 peer, (op, real, group) memberships. On a
         live app the batch is applied in slices of the event loop, each
         one take of the replica's lock and about ROUTE_SLICE_S long, with
         the loop's other work (SUBSCRIBEs, publishes) between two."""
@@ -668,7 +716,7 @@ class ClusterNode:
         if self._loop is not None and not self._loop.is_closed():
             return self._aapply_routes(node, ops)
         with _prof.section("cluster.route.apply"):
-            self.routes.apply_batch(ops, node)
+            self._apply_ops(ops, node)
         return len(ops)
 
     async def _aapply_routes(self, node: str, ops) -> int:
@@ -676,7 +724,7 @@ class ClusterNode:
         while i < len(ops):
             n = self._route_slice
             with _prof.section("cluster.route.apply") as sec:
-                self.routes.apply_batch(ops[i:i + n], node)
+                self._apply_ops(ops[i:i + n], node)
             i += n
             if sec.seconds > 2.0 * ROUTE_SLICE_S:
                 self._route_slice = max(ROUTE_SLICE_MIN, n // 2)
@@ -684,6 +732,17 @@ class ClusterNode:
                 self._route_slice = min(ROUTE_SLICE_MAX, n * 2)
             await asyncio.sleep(0)
         return len(ops)
+
+    def _apply_ops(self, ops, node: str) -> None:
+        """One slice of `node`'s route lane: each run of route ops into
+        the replica, each run of membership ops into `_shared_nodes`, in
+        the lane's order. Once the lane's batch is applied, a peer whose
+        replica holds a route holds the groups joined behind it too."""
+        for width, run in itertools.groupby(ops, len):
+            if width == 2:
+                self.routes.apply_batch(list(run), node)
+            else:
+                self._apply_shared(list(run), node)
 
     # -- mesh-shard ownership (scale-out serving) --------------------------
     def attach_mesh_slice(
@@ -870,25 +929,55 @@ class ClusterNode:
     # -- cluster-wide shared groups ----------------------------------------
     def shared_join(self, real: str, group: str) -> None:
         """First local member of (real, group): announce membership so
-        every node agrees on the group leader."""
-        self._shared_nodes.setdefault((real, group), set()).add(self.name)
-        self._shared_cands.pop((real, group), None)
-        self._shared_cast("join", real, group)
+        every node agrees on the group leader. The join goes into every
+        peer's route lane behind the route's add (the broker replicates
+        the route first)."""
+        self._shared_add(real, group, self.name)
+        self._pairs_gauge()
+        self._announce("join", real, group)
 
     def shared_leave(self, real: str, group: str) -> None:
-        self._proto_shared_leave(real, group, self.name)
-        self._shared_cast("leave", real, group)
+        """Last local member of (real, group) gone: announced ahead of
+        the route's delete, so no peer leaves this message's pick to a
+        node it no longer forwards to."""
+        self._shared_drop(real, group, self.name)
+        self._pairs_gauge()
+        self._announce("leave", real, group)
 
-    def _shared_cast(self, method: str, real: str, group: str) -> None:
-        def one(p):
-            self.rpc.cast(p, "shared", method, real, group, self.name,
-                          key=real)
-
+    def _announce(self, op: str, real: str, group: str) -> None:
+        item = (op, real, group)
         for p in self.membership.peers():
-            if self._repl_pool is not None:
-                self._pool_submit(self._repl_pool, one, p)
-            else:
-                one(p)
+            self._lane_put("route", p, item)
+
+    def _shared_add(self, real: str, group: str, node: str) -> None:
+        self._shared_nodes.setdefault((real, group), set()).add(node)
+        self._shared_cands.pop((real, group), None)
+
+    def _shared_drop(self, real: str, group: str, node: str) -> None:
+        s = self._shared_nodes.get((real, group))
+        if s is not None:
+            s.discard(node)
+            if not s:
+                self._shared_nodes.pop((real, group), None)
+        self._shared_cands.pop((real, group), None)
+
+    def _pairs_gauge(self) -> None:
+        self.broker.metrics.gauge_set(
+            "shared.cluster.pairs", len(self._shared_nodes)
+        )
+
+    def _apply_shared(self, ops, node: str) -> None:
+        """A peer's joins and leaves, (op, real, group) in order: section
+        `cluster.shared.apply`, one entry an op, whichever path carried
+        them (a route lane's batch, or a `shared` cast)."""
+        with _prof.section("cluster.shared.apply") as sec:
+            sec.n = len(ops)
+            for op, real, group in ops:
+                if op == "join":
+                    self._shared_add(real, group, node)
+                else:
+                    self._shared_drop(real, group, node)
+            self._pairs_gauge()
 
     def shared_leader(self, real: str, group: str, msg=None) -> bool:
         """Pick the dispatching node for (real, group) per MESSAGE
@@ -927,16 +1016,10 @@ class ClusterNode:
         return self.name == cands[key % len(cands)]
 
     def _proto_shared_join(self, real: str, group: str, node: str) -> None:
-        self._shared_nodes.setdefault((real, group), set()).add(node)
-        self._shared_cands.pop((real, group), None)
+        self._apply_shared((("join", real, group),), node)
 
     def _proto_shared_leave(self, real: str, group: str, node: str) -> None:
-        s = self._shared_nodes.get((real, group))
-        if s is not None:
-            s.discard(node)
-            if not s:
-                self._shared_nodes.pop((real, group), None)
-        self._shared_cands.pop((real, group), None)
+        self._apply_shared((("leave", real, group),), node)
 
     def _proto_shared_dump(self):
         return [

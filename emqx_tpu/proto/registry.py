@@ -77,7 +77,8 @@ BPAPI = {
     "broker": {1: ("forward", "forward_batch"),
                2: ("forward", "forward_batch", "forward_lane")},
     "route": {1: ("add_route", "delete_route", "dump"),
-              2: ("add_route", "delete_route", "dump", "apply_batch")},
+              2: ("add_route", "delete_route", "dump", "apply_batch"),
+              3: ("add_route", "delete_route", "dump", "apply_batch")},
     "cm": {1: ("insert_channel", "delete_channel", "lookup_channel",
                "discard")},
     "conf": {1: ("append", "receive_apply", "entries_after")},
@@ -293,7 +294,7 @@ register(
     "rpc envelope ops: (\"rpc\", kind, ...) tuples",
 )
 register(
-    "cluster.bpapi", 2, "proto", BPAPI,
+    "cluster.bpapi", 3, "proto", BPAPI,
     "emqx_tpu/cluster/node.py:_register_protos",
     "frozen BPAPI proto tables: api -> version -> methods",
 )
